@@ -305,7 +305,7 @@ EOF
 echo "== perf-regression gate (non-fatal; perf/regress.py vs BENCH_r*.json) =="
 # quick reduced bench on the CPU backend, graded against the committed
 # trajectory with a generous tolerance — warnings only, never fails the check
-FSDR_FORCE_CPU=1 JAX_PLATFORMS=cpu python perf/regress.py --run --quick || \
+JAX_PLATFORMS=cpu python perf/regress.py --run --quick || \
     echo "WARNING: perf-regression gate could not be graded (non-fatal)"
 
 echo "== python suite =="

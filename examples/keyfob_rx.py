@@ -34,7 +34,6 @@ from futuresdr_tpu.blocks import Apply, FileSink, Fir, SeifyBuilder, VectorSink,
     VectorSource
 from futuresdr_tpu.dsp import firdes
 from futuresdr_tpu.models.misc import ook_demodulate, ook_modulate
-from futuresdr_tpu.utils.backend import ensure_backend
 
 
 def key_bits(code: int, n_bits: int) -> np.ndarray:
@@ -89,7 +88,6 @@ def main(argv=None):
     p.add_argument("--carrier", type=float, default=20e3,
                    help="carrier offset inside the capture")
     a = p.parse_args(argv)
-    ensure_backend()
 
     if a.mode == "tx":
         run_tx(a.out or "keyfob_burst.cf32", a.code, a.bits, a.rate,

@@ -4,18 +4,17 @@
 One logical stream is TIME-SHARDED across every device on the mesh: each shard
 filters its slice (halo samples ride ``ppermute`` from the left neighbour, so
 the FIR is exact across shard edges and frame edges), FFTs locally, and the
-|x|² spectra come back still sharded. On real hardware the halo crosses ICI;
-here an 8-device virtual CPU mesh demonstrates the identical program
-(``XLA_FLAGS=--xla_force_host_platform_device_count=8`` is set below).
+|x|² spectra come back still sharded. Runs on the attached devices (the halo
+crosses ICI); ``--virtual-mesh`` runs the identical program on virtual CPU
+devices instead.
 
 Reference role: this is the distribution story the reference delegates to
 ZMQ/TCP blocks between processes (``examples/zeromq``), re-designed as ONE
 sharded XLA program over the mesh (SURVEY §2.7 sequence parallelism).
 
-Run: ``python examples/sharded_spectrum.py [--devices 8] [--frames 32]``
+Run: ``python examples/sharded_spectrum.py [--devices 4] [--frames 32]``
 """
 import argparse
-import os
 import sys
 import time
 
@@ -23,31 +22,30 @@ sys.path.insert(0, ".")
 sys.path.insert(0, "..")
 
 
-def main():
+def main(argv=None):
+    """Returns the last frame's spectra as the (still sharded) device array."""
     p = argparse.ArgumentParser()
-    p.add_argument("--devices", type=int, default=8)
+    p.add_argument("--devices", type=int, default=0,
+                   help="mesh size (default: every attached device)")
     p.add_argument("--frames", type=int, default=32)
     p.add_argument("--fft", type=int, default=1024)
     p.add_argument("--frame-size", type=int, default=1 << 18)
-    a = p.parse_args()
-
-    # virtual mesh BEFORE jax init (no-op when the flag is already set)
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = \
-            f"{flags} --xla_force_host_platform_device_count={a.devices}".strip()
+    p.add_argument("--virtual-mesh", action="store_true",
+                   help="run on --devices virtual CPU devices (default 8) "
+                        "instead of the attached chips")
+    a = p.parse_args(argv)
 
     import jax
-    from futuresdr_tpu.tpu.instance import force_cpu_platform
-    force_cpu_platform()
-    import jax.numpy as jnp
     import numpy as np
     from futuresdr_tpu.dsp import firdes
     from futuresdr_tpu.parallel import (NamedSharding, P, make_mesh,
-                                        sp_fir_fft_mag2_stream)
+                                        sp_fir_fft_mag2_stream,
+                                        virtual_cpu_mesh)
+    if a.virtual_mesh:
+        virtual_cpu_mesh(a.devices or 8)
 
-    n_dev = min(a.devices, len(jax.devices()))
-    mesh = make_mesh(("sp",), shape=(n_dev,), devices=jax.devices()[:n_dev])
+    n_dev = a.devices or len(jax.devices())
+    mesh = make_mesh(("sp",), shape=(n_dev,))       # refuses a short mesh
     taps = firdes.lowpass(0.2, 64).astype(np.float32)
     fn, init_carry = sp_fir_fft_mag2_stream(taps, a.fft, mesh)
     jfn = jax.jit(fn, donate_argnums=(0,))
@@ -77,6 +75,7 @@ def main():
           f"({a.frames * n / dt / 1e6 / n_dev:.1f} per shard)")
     print(f"spectra: {spec.shape[0]} x {a.fft} bins, "
           f"peak bin power {spec.max():.1f}")
+    return y
 
 
 if __name__ == "__main__":

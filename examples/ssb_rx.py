@@ -33,7 +33,6 @@ import numpy as np
 from futuresdr_tpu import Flowgraph, Runtime
 from futuresdr_tpu.blocks import Agc, Apply, SeifyBuilder, VectorSink, WavSink, \
     XlatingFir
-from futuresdr_tpu.utils.backend import ensure_backend
 
 
 def sideband_taps(fs: float, sideband: str, audio_bw: float,
@@ -77,7 +76,6 @@ def main(argv=None):
     p.add_argument("--audio", action="store_true",
                    help="play via the soundcard (AudioSink) instead of a WAV")
     a = p.parse_args(argv)
-    ensure_backend()
 
     synthesized = a.input is None
     tmp_path = None

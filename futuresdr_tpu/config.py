@@ -9,10 +9,7 @@ from __future__ import annotations
 
 import os
 
-try:
-    import tomllib                      # Python >= 3.11
-except ImportError:                     # pragma: no cover - py3.10 fallback
-    import tomli as tomllib
+import tomllib
 from dataclasses import dataclass, field
 from pathlib import Path
 from typing import Any, Optional

@@ -46,7 +46,7 @@ class LoraParams:
     #   Default-ON to match the reference's receiver binaries, which hardwire
     #   `build_lora_rx_soft_decoding` (`examples/lora/src/bin/rx.rs:65`,
     #   `rx_meshtastic.rs:76`, `rx_all_channels_eu.rs:156`); set False for the
-    #   ~10%-faster hard path (documented opt-out, perf/RESULTS_r4.md)
+    #   ~10%-faster hard path (documented opt-out)
 
     def __post_init__(self):
         if not 5 <= self.sf <= 12:

@@ -168,13 +168,9 @@ def viterbi_decode_m17(llrs: np.ndarray, n_bits: int) -> np.ndarray:
     n_steps = min(len(llrs) // 2, n_bits)
     prev_s, prev_b, bm0, bm1 = _M17_PREV
     if n_steps >= 512:
-        try:
-            from ...ops.viterbi import backend_ready, scan_viterbi
-            if backend_ready():
-                return scan_viterbi(np.asarray(llrs, np.float32), n_bits,
-                                    prev_s, prev_b, bm0, bm1)
-        except Exception:   # pragma: no cover
-            pass
+        from ...ops.viterbi import scan_viterbi
+        return scan_viterbi(np.asarray(llrs, np.float32), n_bits,
+                            prev_s, prev_b, bm0, bm1)
     lam = llrs[:2 * n_steps].reshape(n_steps, 2).astype(np.float64)
     metrics = np.full(_NS, -1e18)
     metrics[0] = 0.0

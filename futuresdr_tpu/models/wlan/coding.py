@@ -189,7 +189,8 @@ def viterbi_decode(llrs: np.ndarray, n_bits: int) -> np.ndarray:
     Terminated trellis (encoder assumed flushed with ≥6 tail zeros within n_bits).
     Dispatch order: the native C++ ACS loop when the toolchain is available
     (bit-identical, ~25× the fallbacks; ``FSDR_NO_NATIVE=1`` disables); else the
-    XLA scan decoder for long frames on a live backend; else the numpy trellis.
+    XLA scan decoder for frames of at least ``_SCAN_THRESHOLD`` steps; else the
+    numpy trellis.
     """
     n_steps = min(len(llrs) // 2, n_bits)
     lib = _native_lib()
@@ -204,13 +205,9 @@ def viterbi_decode(llrs: np.ndarray, n_bits: int) -> np.ndarray:
         if rc == 0:
             return out[:n_bits]
     if n_steps >= _SCAN_THRESHOLD:
-        try:
-            from ...ops.viterbi import backend_ready, scan_viterbi
-            if backend_ready():
-                return scan_viterbi(np.asarray(llrs, np.float32), n_bits,
-                                    _PREV_S, _PREV_B, _BM0, _BM1)
-        except Exception:   # pragma: no cover - jax unavailable/backend issues
-            pass
+        from ...ops.viterbi import scan_viterbi
+        return scan_viterbi(np.asarray(llrs, np.float32), n_bits,
+                            _PREV_S, _PREV_B, _BM0, _BM1)
     lam = llrs[:2 * n_steps].reshape(n_steps, 2).astype(np.float64)
     metrics = np.full(_NSTATES, -1e18)
     metrics[0] = 0.0

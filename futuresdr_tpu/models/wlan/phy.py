@@ -163,24 +163,10 @@ def _prepare_frame(samples: np.ndarray, lts_start: int, cfo: float):
     if data_start + SYM_LEN > len(samples):
         return None
     head = samples[lts_start:data_start + SYM_LEN]
-    use_jax = False
-    try:
-        from ...ops.viterbi import backend_ready
-        use_jax = backend_ready()
-    except Exception:       # pragma: no cover
-        pass
-    if use_jax:
-        # channel estimate + SIGNAL demap in one jit call (XLA residency of the
-        # frame head; CFO applied in-trace with the lts_start phase reference)
-        from .jax_demod import demod_head_jax
-        H, sig_llrs = demod_head_jax(head, cfo)
-    else:
-        if cfo != 0.0:
-            head = head * np.exp(-1j * cfo * np.arange(len(head)))
-        H = ofdm.estimate_channel(head, 0)
-        spec = ofdm.ofdm_demodulate_symbols(head[128:], 1)
-        eq = ofdm.equalize(spec, H, symbol_offset=0)
-        sig_llrs = ofdm.demap_llrs(eq.reshape(-1), "bpsk")
+    # channel estimate + SIGNAL demap in one jit call (XLA residency of the
+    # frame head; CFO applied in-trace with the lts_start phase reference)
+    from .jax_demod import demod_head_jax
+    H, sig_llrs = demod_head_jax(head, cfo)
     sig_bits = coding.viterbi_decode(coding.deinterleave(sig_llrs, 48, 1), 24)
     parsed = _parse_signal(sig_bits)
     if parsed is None:
@@ -193,7 +179,7 @@ def _prepare_frame(samples: np.ndarray, lts_start: int, cfo: float):
         return None
     off = data_start + SYM_LEN
     body = samples[off:off + n_sym * SYM_LEN]
-    if use_jax and n_sym >= 8:
+    if n_sym >= 8:
         # the whole body demod (CFO, batched FFT, equalize, CPE, demap) in one jit
         from .jax_demod import demod_body_jax
         llrs = demod_body_jax(body, H, n_sym, 1, cfo, off - lts_start, mcs.modulation)
@@ -261,15 +247,10 @@ def decode_stream_batch(samples: np.ndarray) -> List[DecodedFrame]:
             preps.append(p)
     if not preps:
         return []
-    try:
-        from ...ops.viterbi import backend_ready, scan_viterbi_batch
-        if not backend_ready():
-            raise RuntimeError("no jax backend")
-        from .coding import _PREV_S, _PREV_B, _BM0, _BM1
-        bits_list = scan_viterbi_batch([p[0] for p in preps], [p[1] for p in preps],
-                                       _PREV_S, _PREV_B, _BM0, _BM1)
-    except Exception:
-        bits_list = [coding.viterbi_decode(p[0], p[1]) for p in preps]
+    from ...ops.viterbi import scan_viterbi_batch
+    from .coding import _PREV_S, _PREV_B, _BM0, _BM1
+    bits_list = scan_viterbi_batch([p[0] for p in preps], [p[1] for p in preps],
+                                   _PREV_S, _PREV_B, _BM0, _BM1)
     # the seed check needs the Viterbi output, so the batch path applies the
     # span/dedup policy AFTER decoding (same semantics as decode_stream: only
     # seed_ok frames claim; detections inside a claimed span are dropped)

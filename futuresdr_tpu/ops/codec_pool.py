@@ -11,8 +11,8 @@ thread pool turns the three-lane overlap (H2D ∥ compute ∥ D2H) into five:
 Two separate lanes, deliberately: DECODE tasks block on the D2H landing
 (under a fake link that is a modeled wire-time sleep), so sharing one
 executor would let parked decodes starve encodes and idle the up-link.
-Workers are process-global (like the ``fsdr-d2h`` fetch pool) and live for
-the process; threads are named ``fsdr-codec-enc*`` / ``fsdr-codec-dec*``.
+Workers are process-global and live for the process; threads are named
+``fsdr-codec-enc*`` / ``fsdr-codec-dec*``.
 
 ORDER is the caller's contract, not the pool's: the kernel drains its staged
 and in-flight deques oldest-first and joins each future in sequence, so

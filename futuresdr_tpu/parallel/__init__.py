@@ -7,14 +7,16 @@ exchange (sequence parallelism), channel-sharded filterbanks, and dp/fsdp-sharde
 training for the in-flowgraph ML path.
 """
 
-from .mesh import make_mesh, factor_devices, shard_params, P, NamedSharding
+from .mesh import (make_mesh, factor_devices, shard_params, virtual_cpu_mesh, P,
+                   NamedSharding)
 from .stream_sp import (sp_fir, sp_fir_fft_mag2, sp_fir_stream,
                         sp_fir_fft_mag2_stream, sp_channelizer, sp_channelizer_a2a,
                         sp_dechirp_scan)
 from .pipeline_pp import make_pp_pipeline
 from . import multihost
 
-__all__ = ["make_mesh", "factor_devices", "shard_params", "P", "NamedSharding",
+__all__ = ["make_mesh", "factor_devices", "shard_params", "virtual_cpu_mesh",
+           "P", "NamedSharding",
            "sp_fir", "sp_fir_fft_mag2", "sp_fir_stream", "sp_fir_fft_mag2_stream",
            "sp_channelizer", "sp_channelizer_a2a", "sp_dechirp_scan",
            "make_pp_pipeline", "multihost"]

@@ -7,13 +7,32 @@ transport blocks between hosts. The TPU-native scale-out is SPMD over an ICI mes
 
 from __future__ import annotations
 
+import os
+import re
 from typing import Optional, Sequence, Tuple
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-__all__ = ["make_mesh", "factor_devices", "shard_params", "P", "NamedSharding"]
+__all__ = ["make_mesh", "factor_devices", "shard_params", "virtual_cpu_mesh",
+           "P", "NamedSharding"]
+
+
+def virtual_cpu_mesh(n_devices: int) -> None:
+    """Opt this process into ``n_devices`` VIRTUAL CPU devices instead of the
+    attached accelerator — for scripts whose point is a mesh wider than the
+    hardware (sharding dry runs, CI). Must run before jax's first backend use:
+    the device-count flag and the platform choice only act at init. Timings
+    taken on such a mesh are not device metrics."""
+    flags = os.environ.get("XLA_FLAGS", "")
+    want = f"--xla_force_host_platform_device_count={int(n_devices)}"
+    if "xla_force_host_platform_device_count" in flags:
+        flags = re.sub(r"--?xla_force_host_platform_device_count=\d+", want, flags)
+    else:
+        flags = (flags + " " + want).strip()
+    os.environ["XLA_FLAGS"] = flags
+    jax.config.update("jax_platforms", "cpu")
 
 
 def factor_devices(n: int, n_axes: int = 2) -> Tuple[int, ...]:
@@ -59,6 +78,8 @@ def make_mesh(axis_names: Sequence[str], shape: Optional[Sequence[int]] = None,
     sub-mesh (e.g. a 1-device reference mesh next to the full one) is a
     deliberate, documented pattern (``__graft_entry__.dryrun_multichip``).
     """
+    from ..tpu.instance import ensure_compile_cache
+    ensure_compile_cache()      # parallel/ and shard/ reach the device here
     devices = list(devices if devices is not None else jax.devices())
     if shape is None:
         shape = factor_devices(len(devices), len(axis_names))

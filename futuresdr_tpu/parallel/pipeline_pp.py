@@ -33,16 +33,10 @@ def make_pp_pipeline(apply_stage: Callable, n_stages: int, n_micro: int,
     - ``micro_x``: ``[n_micro, ...]`` microbatches (replicated); returns
       ``[n_micro, ...]`` outputs of the final stage (replicated).
     """
-    import inspect
-
     import jax
     import jax.numpy as jnp
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-
-    try:
-        from jax import shard_map          # jax ≥ 0.7 stable API
-    except ImportError:                    # pragma: no cover
-        from jax.experimental.shard_map import shard_map
 
     assert mesh.shape[axis] == n_stages, \
         f"mesh axis {axis} has {mesh.shape[axis]} devices, need {n_stages}"
@@ -76,10 +70,5 @@ def make_pp_pipeline(apply_stage: Callable, n_stages: int, n_micro: int,
         # only the last stage holds real outputs; psum replicates them to all
         return jax.lax.psum(outs, axis)
 
-    kwargs = {}
-    if "check_vma" in inspect.signature(shard_map).parameters:
-        kwargs["check_vma"] = False
-    elif "check_rep" in inspect.signature(shard_map).parameters:  # pragma: no cover
-        kwargs["check_rep"] = False       # pre-0.7 name for the same check
     return shard_map(body, mesh=mesh, in_specs=(P(axis), P()),
-                     out_specs=P(), **kwargs)
+                     out_specs=P(), check_vma=False)

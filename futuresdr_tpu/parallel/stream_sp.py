@@ -16,12 +16,8 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:
-    from jax import shard_map          # jax ≥ 0.7 stable API
-except ImportError:                    # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 
 __all__ = ["sp_fir", "sp_fir_fft_mag2", "sp_fir_stream", "sp_fir_fft_mag2_stream",
            "sp_channelizer", "sp_channelizer_a2a", "sp_dechirp_scan"]

@@ -6,8 +6,9 @@ window is contiguous regardless of the wrap position — work windows are never 
 portable :mod:`.ring` fallback. Index arithmetic (produce/consume/space) lives in C++ atomics
 (``native/ringbuf.cpp``), so the data-plane accounting is lock-free exactly as in the reference.
 
-Falls back transparently: :func:`available` reports whether the native library loaded; the
-flowgraph default buffer is set accordingly at import time (see ``runtime/__init__``).
+The library is built from ``native/*.cpp`` on the host that runs it (:func:`load_native`);
+``FSDR_NO_NATIVE=1`` selects the portable :mod:`.ring` instead, and :func:`available` reports
+which of the two the flowgraph default buffer was set to at import (``runtime/__init__``).
 """
 
 from __future__ import annotations
@@ -37,26 +38,29 @@ _lib = None
 
 
 def load_native() -> Optional[ctypes.CDLL]:
-    """Load (building if necessary) the native library; returns None when unavailable."""
+    """Build (``make -C native``) and load the native library.
+
+    The portable Python ring is chosen by ``FSDR_NO_NATIVE=1`` — the only way
+    this returns None — or not at all: a failed build or load RAISES, because a
+    checkout without a toolchain would otherwise run a different, slower host
+    path that nothing reports. ``make`` always runs: a no-op when up to date,
+    and the Makefile's build stamp (compiler + flags + the ISA ``-march=native``
+    resolves to on THIS host) forces a rebuild of objects carried over from
+    another machine, whose instructions this CPU may not have."""
     global _lib
     if _lib is not None:
         return _lib
-    so = os.path.join(_NATIVE_DIR, "libfsdr_native.so")
-    # always run make: incremental no-op when up to date, and a pre-existing .so
-    # from before a new source file (e.g. mm.cpp) was added gets its symbols
+    if os.environ.get("FSDR_NO_NATIVE"):
+        return None
     try:
         subprocess.run(["make", "-C", _NATIVE_DIR], check=True,
-                       capture_output=True, timeout=120)
-    except Exception as e:
-        if not os.path.exists(so):
-            log.warning("native build failed (%r); using portable ring buffer", e)
-            return None
-        log.warning("native rebuild failed (%r); using existing %s", e, so)
-    try:
-        lib = ctypes.CDLL(so)
-    except OSError as e:
-        log.warning("native load failed (%r); using portable ring buffer", e)
-        return None
+                       capture_output=True, text=True, timeout=300)
+    except subprocess.CalledProcessError as e:
+        raise RuntimeError(
+            f"native build failed (make -C {_NATIVE_DIR}, exit {e.returncode}); "
+            f"set FSDR_NO_NATIVE=1 to run on the portable Python ring:\n"
+            f"{(e.stderr or e.stdout or '').strip()[-2000:]}") from e
+    lib = ctypes.CDLL(os.path.join(_NATIVE_DIR, "libfsdr_native.so"))
     lib.fsdr_dbuf_create.restype = ctypes.c_void_p
     lib.fsdr_dbuf_create.argtypes = [ctypes.c_size_t]
     lib.fsdr_dbuf_destroy.argtypes = [ctypes.c_void_p]

@@ -33,7 +33,7 @@ from typing import List, Optional, Sequence, Tuple
 __all__ = ["Log2Hist", "log2_bounds", "DEFAULT_LO_EXP", "DEFAULT_HI_EXP"]
 
 #: default bucket range: 2^-20 s (~0.95 µs) … 2^7 s (128 s) — the span from a
-#: single jitted dispatch to a wedged tunnel RPC, in factor-of-2 steps
+#: single jitted dispatch to a wedged transfer, in factor-of-2 steps
 DEFAULT_LO_EXP = -20
 DEFAULT_HI_EXP = 7
 

@@ -388,15 +388,7 @@ class ProfilePlane:
 
     def _peaks(self) -> Optional[dict]:
         from ..utils.roofline import detect_peaks
-        try:
-            import jax
-            backend = jax.default_backend()
-        except Exception:                           # noqa: BLE001
-            backend = None
-        try:
-            return detect_peaks(backend)
-        except Exception:                           # noqa: BLE001
-            return None
+        return detect_peaks()
 
     def update_live_gauges(self, min_interval: float = 0.25) -> None:
         """Refresh ``fsdr_mfu``/``fsdr_hbm_util`` from each program's unit

@@ -591,7 +591,7 @@ class TpuKernel(Kernel):
         # the contract the synchronous path always had — and still get the
         # pooled D2H-landing/decode lane. (Offloading their encode would
         # force a ring-exit copy the sync path never paid; measured a net
-        # loss at small frames, perf/HOSTPATH_AB_r14.md.)
+        # loss at small frames on the CPU backend's fake link.)
         self._encode_offload = self._codec_pool is not None and \
             self.wire.encode_may_alias(self.pipeline.in_dtype)
         # H2D staging read-ahead BEYOND the in-flight budget: at steady state

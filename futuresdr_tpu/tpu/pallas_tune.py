@@ -16,8 +16,10 @@ Sweep contract (docs/tpu_notes.md "Pallas autotune plane"):
 - the defaults are ALWAYS in the candidate set, and win ties within timer
   noise — a recorded winner is never a regression against the hand-picked
   shapes;
-- a candidate that fails to compile or run is skipped with a warning, never
-  fatal (an odd shape on a future Mosaic revision must not wedge a launch);
+- a NON-default candidate that fails to compile or run is skipped with a
+  warning (an odd shape may exceed VMEM); the DEFAULT block failing is an
+  error — it is the shape every untuned launch uses, and a sweep that
+  skipped it would hide a kernel Mosaic refuses;
 - on CPU the kernels run in interpret mode, so the measured ranking is a
   functional smoke of the sweep loop, not a performance statement — the cache
   key (:func:`device_key` → ``"cpu"``) keeps those picks away from real chips.
@@ -140,7 +142,8 @@ def sweep_blocks(kernels: Optional[Sequence[str]] = None,
     ``matrix[kernel][block] = best-of-reps seconds`` (the full sweep, for
     the artifact tables). Timing is min-of-``reps`` after a warm-up call
     that also pays compilation; a candidate that raises is dropped with a
-    warning. The default block wins any tie within :data:`_TIE_MARGIN`."""
+    warning — unless it is the kernel's default block, whose failure
+    propagates. The default block wins any tie within :data:`_TIE_MARGIN`."""
     names = tuple(kernels) if kernels else tuple(CANDIDATE_BLOCKS)
     data = _workload(frame)
     winners: Dict[str, int] = {}
@@ -161,9 +164,9 @@ def sweep_blocks(kernels: Optional[Sequence[str]] = None,
                 best = min(_timed(fn) for _ in range(max(1, int(reps))))
                 times[b] = best
             except Exception as e:             # Mosaic reject, OOM, …
+                if b == default:
+                    raise
                 log.warning("pallas sweep %s block=%d failed: %r", kn, b, e)
-        if not times:
-            continue
         best_b = min(times, key=times.get)
         if (default in times and best_b != default
                 and times[default] * _TIE_MARGIN <= times[best_b]):

@@ -142,9 +142,9 @@ class PpKernel(Kernel):
 
     def _stage(self, frame: np.ndarray, valid: Optional[int] = None,
                handle=None) -> None:
-        # wire-encoded parts are plain reals/ints — the complex-pair shim's
-        # broken-tunnel rule (ops/xfer.py) is satisfied by construction; the
-        # complex frame is formed in-trace by the wired prolog
+        # wire-encoded parts are plain reals/ints (the pair layout of
+        # ops/xfer.py by construction); the complex frame is formed in-trace
+        # by the wired prolog
         t0 = _trace.now() if _trace.enabled else 0
         parts = self.wire.encode_host(frame)
         if t0:

@@ -3,8 +3,7 @@
 from .trace import LatencyProbeSource, LatencyProbeSink, latency_stats
 from .checkpoint import (save_pytree, load_pytree, save_flowgraph_state,
                          load_flowgraph_state)
-from .backend import ensure_backend
 
 __all__ = ["LatencyProbeSource", "LatencyProbeSink", "latency_stats",
            "save_pytree", "load_pytree", "save_flowgraph_state",
-           "load_flowgraph_state", "ensure_backend"]
+           "load_flowgraph_state"]

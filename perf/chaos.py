@@ -74,7 +74,7 @@ def _assert_no_leaked_threads(before, label):
         gc.collect()
         leaked = [t for t in threading.enumerate()
                   if t not in before and t.is_alive() and not t.daemon
-                  and not t.name.startswith(("fsdr-d2h", "fsdr-codec"))]
+                  and not t.name.startswith("fsdr-codec")]
         if not leaked:
             return
         if time.monotonic() > deadline:

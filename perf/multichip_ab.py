@@ -2,9 +2,9 @@
 """perf/multichip_ab — scaling curve of the mesh-sharded device plane.
 
 Measures the DATA-sharded fused program (``futuresdr_tpu/shard``) at
-D ∈ {1, 2, 4, 8} on the current mesh (CI: the virtual 8-device CPU mesh —
-``--xla_force_host_platform_device_count=8`` is forced before jax init when
-the caller didn't set it), in both postures:
+D ∈ {1, 2, 4, 8} on a VIRTUAL 8-device CPU mesh (``parallel.virtual_cpu_mesh``,
+chosen plainly at start-up: the curve needs more devices than one host holds,
+and its timings are host timings, never device metrics), in both postures:
 
 * **resident** — device-resident input redispatched per group (the compute
   plane alone: carries chain on-device, only the sink gather leaves);
@@ -47,7 +47,6 @@ Usage:
 import argparse
 import json
 import os
-import re
 import sys
 import time
 
@@ -56,21 +55,6 @@ sys.path.insert(0, _ROOT)
 
 SMOKE_FLOOR = 0.8          # scaling fraction of the achievable ceiling
 DMAX = 8
-
-
-def _force_virtual_mesh(n: int) -> None:
-    """Ensure >= n devices exist BEFORE jax initializes (the
-    ``__graft_entry__.dryrun_multichip`` pattern): on the CPU platform the
-    virtual-device flag only acts pre-init, so this module must be run as
-    a fresh process (check.sh does)."""
-    flags = os.environ.get("XLA_FLAGS", "")
-    want = f"--xla_force_host_platform_device_count={n}"
-    if "xla_force_host_platform_device_count" in flags:
-        flags = re.sub(r"--?xla_force_host_platform_device_count=\d+",
-                       want, flags)
-    else:
-        flags = (flags + " " + want).strip()
-    os.environ["XLA_FLAGS"] = flags
 
 
 def _chain():
@@ -281,8 +265,10 @@ def main(argv=None) -> int:
                          "early-exit once the floor clears)")
     a = ap.parse_args(argv)
 
-    _force_virtual_mesh(DMAX)
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # this harness IS the virtual-mesh run: it needs DMAX devices, more than
+    # any one host holds, so its timings are never device metrics
+    from futuresdr_tpu.parallel import virtual_cpu_mesh
+    virtual_cpu_mesh(DMAX)
     os.environ.setdefault("FUTURESDR_TPU_AUTOTUNE_CACHE_DIR", "off")
     import jax
     backend = jax.default_backend()

@@ -14,7 +14,6 @@ program).
 """
 
 import argparse
-import os
 import sys
 import time
 
@@ -24,7 +23,10 @@ sys.path.insert(0, "..")
 
 def main():
     p = argparse.ArgumentParser()
-    p.add_argument("--devices", type=int, default=8)
+    p.add_argument("--virtual-mesh", action="store_true",
+                   help="run on max(--stages) virtual CPU devices instead of "
+                        "the attached chips (stage counts beyond the devices "
+                        "are skipped)")
     p.add_argument("--stages", type=int, nargs="+", default=[4, 8])
     p.add_argument("--micro", type=int, nargs="+", default=[2, 8, 32])
     p.add_argument("--width", type=int, default=256)
@@ -34,18 +36,13 @@ def main():
                    help="also run PpKernel through the actor runtime")
     a = p.parse_args()
 
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = \
-            f"{flags} --xla_force_host_platform_device_count={a.devices}".strip()
-
     import jax
-    from futuresdr_tpu.tpu.instance import force_cpu_platform
-    force_cpu_platform()
     import jax.numpy as jnp
     import numpy as np
     from futuresdr_tpu.parallel import (NamedSharding, P, make_mesh,
-                                        make_pp_pipeline)
+                                        make_pp_pipeline, virtual_cpu_mesh)
+    if a.virtual_mesh:
+        virtual_cpu_mesh(max(a.stages))
 
     print("stages,micro,ideal_eff,msamples_per_sec")
     rng = np.random.default_rng(0)

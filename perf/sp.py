@@ -2,16 +2,16 @@
 """perf/sp — sequence-parallel stream-op scaling probe.
 
 Measures the halo-exchange ops (`parallel.stream_sp`) per mesh size: sp_fir,
-the fused sp_fir_fft_mag2 chain, and sp_dechirp_scan. On the virtual CPU mesh
-the numbers characterize overhead (one ppermute per frame vs local compute);
-on real chips the same probe shows ICI scaling. Rates are measured with a
+the fused sp_fir_fft_mag2 chain, and sp_dechirp_scan. Runs on the attached
+devices (mesh sizes beyond them are skipped), where it shows ICI scaling;
+``--virtual-mesh`` runs it on virtual CPU devices instead, where the numbers
+only characterize overhead (one ppermute per frame vs local compute). Rates are measured with a
 jitted steady-state loop after a warmup compile.
 
 CSV: ``op,devices,frame,msamples_per_sec``.
 """
 
 import argparse
-import os
 import sys
 import time
 
@@ -27,19 +27,18 @@ def main():
     p.add_argument("--fft", type=int, default=2048)
     p.add_argument("--sf", type=int, default=7)
     p.add_argument("--reps", type=int, default=5)
+    p.add_argument("--virtual-mesh", action="store_true",
+                   help="run on max(--devices) virtual CPU devices instead of "
+                        "the attached chips (overhead characterization only)")
     a = p.parse_args()
 
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (f"{flags} --xla_force_host_platform_device_"
-                                   f"count={max(a.devices)}".strip())
-
     import jax
-    from futuresdr_tpu.tpu.instance import force_cpu_platform
-    force_cpu_platform()
     import numpy as np
     from futuresdr_tpu.parallel import (NamedSharding, P, make_mesh, sp_fir,
-                                        sp_fir_fft_mag2, sp_dechirp_scan)
+                                        sp_fir_fft_mag2, sp_dechirp_scan,
+                                        virtual_cpu_mesh)
+    if a.virtual_mesh:
+        virtual_cpu_mesh(max(a.devices))
 
     print("op,devices,frame,msamples_per_sec")
     rng = np.random.default_rng(0)

@@ -18,10 +18,18 @@ def test_run_marginal_positive_rate():
     assert rate > 0
 
 
-def test_pipeline_roofline_accounting():
+def _pin_peaks(monkeypatch):
+    """A known denominator on the CPU host: the config override (the v5e
+    table figures), so the mfu/bound fields are exercised without a chip."""
+    from futuresdr_tpu.config import config
+    monkeypatch.setattr(config(), "peak_flops", 197e12)
+    monkeypatch.setattr(config(), "peak_hbm_gbps", 819.0)
+
+
+def test_pipeline_roofline_accounting(monkeypatch):
     """utils/roofline: XLA cost analysis per fused prefix; stage numbers are
     differences, totals match the full program, and rate_sps fills in the
-    achieved-flops fields (mfu only on backends with a known peak)."""
+    achieved-flops fields (mfu only with a known peak)."""
     import numpy as np
     from futuresdr_tpu.dsp import firdes
     from futuresdr_tpu.ops import fft_stage, fir_stage, mag2_stage
@@ -38,12 +46,13 @@ def test_pipeline_roofline_accounting():
     assert abs(total - r["flops_per_sample"]) < 1e-6
     assert r["achieved_flops"] == 1e6 * r["flops_per_sample"]
     assert "mfu" not in r                        # no public CPU peak
+    _pin_peaks(monkeypatch)
     r2 = pipeline_roofline(stages, np.complex64, 1 << 16, rate_sps=1e9,
-                           backend="tpu")
+                           backend="cpu")
     assert 0 < r2["mfu"] < 1 and "bound" in r2["stages"][0]
 
 
-def test_roofline_decimating_stage():
+def test_roofline_decimating_stage(monkeypatch):
     """A decimating FIR's roofline attribution: the per-stage prefix math
     holds through a rate change (the prefix output shrinks by the decimation
     factor), and the downstream stage is charged at its own (reduced) rate —
@@ -55,7 +64,8 @@ def test_roofline_decimating_stage():
 
     taps = firdes.lowpass(0.1, 64).astype(np.float32)
     stages = [fir_stage(taps, decim=4, name="decim4"), mag2_stage()]
-    r = pipeline_roofline(stages, np.complex64, 1 << 16, backend="tpu")
+    _pin_peaks(monkeypatch)
+    r = pipeline_roofline(stages, np.complex64, 1 << 16, backend="cpu")
     assert [s["name"] for s in r["stages"]] == ["decim4", "mag2"]
     assert all(s["flops_per_sample"] > 0 for s in r["stages"])
     assert r["stages"][0]["bytes_per_sample"] > 0
@@ -72,7 +82,7 @@ def test_roofline_decimating_stage():
     assert r["stages"][0]["bound"] in ("hbm", "compute")
 
 
-def test_graph_roofline_fanout_per_node():
+def test_graph_roofline_fanout_per_node(monkeypatch):
     """graph_roofline on a FanoutPipeline: one node per producer/branch,
     per-node differences sum to the full program's totals, and rate_sps
     fills the achieved/mfu fields exactly like the linear form."""
@@ -87,7 +97,8 @@ def test_graph_roofline_fanout_per_node():
     fo = FanoutPipeline([fir_stage(taps, name="prod")],
                         [[mag2_stage()], [fir_stage(t2, decim=4, name="b1")]],
                         np.complex64)
-    r = graph_roofline(fo, 1 << 14, rate_sps=1e6, backend="tpu")
+    _pin_peaks(monkeypatch)
+    r = graph_roofline(fo, 1 << 14, rate_sps=1e6, backend="cpu")
     assert [(n["name"], n["inputs"]) for n in r["nodes"]] == \
         [("prod", []), ("mag2", [0]), ("b1", [0])]
     total = sum(n["flops_per_sample"] for n in r["nodes"])

@@ -810,11 +810,11 @@ def test_detect_peaks_dtype_keying(monkeypatch):
     from futuresdr_tpu.utils.roofline import detect_peaks, dtype_peak_flops
     monkeypatch.setattr(config(), "peak_flops", 200e12)
     monkeypatch.setattr(config(), "peak_hbm_gbps", 800.0)
-    base = detect_peaks("cpu")
+    base = detect_peaks()
     assert base["flops"] == 200e12              # back-compat: tabled bf16 peak
-    f32 = detect_peaks("cpu", dtype="f32")
+    f32 = detect_peaks(dtype="f32")
     assert f32["flops"] == 100e12 and f32["dtype"] == "f32"
-    bf16 = detect_peaks("cpu", dtype="bf16")
+    bf16 = detect_peaks(dtype="bf16")
     assert bf16["flops"] == 200e12
     assert dtype_peak_flops(base, "f32") == 100e12
     assert dtype_peak_flops(base, None) == 200e12

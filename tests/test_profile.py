@@ -310,7 +310,7 @@ def test_detect_peaks_config_override(monkeypatch):
     from futuresdr_tpu.utils.roofline import detect_peaks
     monkeypatch.setattr(config(), "peak_flops", 5e12)
     monkeypatch.setattr(config(), "peak_hbm_gbps", 123.0)
-    p = detect_peaks("cpu")
+    p = detect_peaks()
     assert p == {"flops": 5e12, "hbm_bytes": 123e9, "chip": "config"}
 
 
@@ -327,18 +327,17 @@ def test_detect_peaks_device_kind(monkeypatch):
     # known chip kinds map to the public table
     monkeypatch.setattr(jax, "devices",
                         lambda *a: [_Dev("tpu", "TPU v5 lite")])
-    p = roofline.detect_peaks("tpu")
+    p = roofline.detect_peaks()
     assert p["chip"] == "v5e" and p["flops"] == 197e12
     monkeypatch.setattr(jax, "devices", lambda *a: [_Dev("tpu", "TPU v4")])
     assert roofline.detect_peaks()["chip"] == "v4"
     # UNKNOWN accelerator: degrade to flops/bytes-only, never a wrong
-    # denominator — even when the backend label would map
+    # denominator
     monkeypatch.setattr(jax, "devices", lambda *a: [_Dev("tpu", "TPU v99")])
-    assert roofline.detect_peaks("tpu") is None
-    # a cpu host asking about the "tpu" label keeps the historical mapping
+    assert roofline.detect_peaks() is None
+    # a cpu host has no peak: a label never stands in for a live device
     monkeypatch.setattr(jax, "devices", lambda *a: [_Dev("cpu", "cpu")])
-    assert roofline.detect_peaks("tpu")["chip"] == "v5e"
-    assert roofline.detect_peaks("cpu") is None
+    assert roofline.detect_peaks() is None
 
 
 def test_kind_to_chip_mapping():

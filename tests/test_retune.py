@@ -349,6 +349,31 @@ def test_xlating_fir_stage_matches_unfolded_chain():
         pB.update_stage(cb, "tuner", taps=t2[:64])
 
 
+def test_complex_retune_compiles_nothing_under_the_pair_shim(monkeypatch):
+    """On an accelerator complex parameters ride the pair shim, whose join is a
+    jitted program. A retune must reuse the program init_carry compiled — the
+    first chip run caught ``update`` handing its committed device to
+    ``to_device``, which re-lowered the join inside the ctrl handler. The shim
+    is forced on here, as it is off-CPU."""
+    import jax
+
+    from futuresdr_tpu.ops import xfer
+    from futuresdr_tpu.ops.stages import xlating_fir_stage
+
+    monkeypatch.setattr(xfer, "split_complex_platform", lambda platform: True)
+    taps = firdes.lowpass(0.1, 128).astype(np.float32)
+    pipe = Pipeline([xlating_fir_stage(taps, -0.3, 4, name="tuner"),
+                     fir_stage(taps[:32] * (1 + 0.5j), name="cfir")],
+                    np.complex64)
+    carry = jax.device_put(pipe.init_carry(), jax.devices()[0])   # committed
+    join, _ = xfer._jits()
+    before = join._cache_size()
+    carry = pipe.update_stage(carry, "tuner", phase_inc=-0.9)
+    carry = pipe.update_stage(carry, "tuner", taps=taps[::-1].copy())
+    carry = pipe.update_stage(carry, "cfir", taps=taps[:32] * (1 - 0.5j))
+    assert join._cache_size() == before
+
+
 def test_xlating_taps_update_preserves_exact_theta():
     """Round-4 advisory: update(taps=...) without phase_inc must rebuild the
     complex weights with the EXACT translation theta, not a value re-derived
