@@ -166,6 +166,7 @@ def pallas_fir(x: jnp.ndarray, taps, block: Optional[int] = None,
                      bf16=(precision == "bf16"))
     return pl.pallas_call(
         kernel,
+        name="pallas_fir",
         grid=(grid,),
         in_specs=[
             pl.BlockSpec((block,), lambda i: (i,)),        # prev (offset by the pad)
@@ -307,6 +308,7 @@ def pallas_pfb(rows: jnp.ndarray, taps_kn, block: Optional[int] = None,
     kern = partial(_pfb_kernel, n_taps=K, block=bt, bf16=bf16)
     out_r, out_i = pl.pallas_call(
         kern,
+        name="pallas_pfb",
         grid=(grid,),
         in_specs=[
             pl.BlockSpec((bt, N), lambda i: (i, 0)),       # prev rows (re)
@@ -394,6 +396,7 @@ def pallas_poly_fir(rows: jnp.ndarray, W, block: Optional[int] = None,
         out_shape = jax.ShapeDtypeStruct((nq_pad,), jnp.float32)
     y = pl.pallas_call(
         kern,
+        name="pallas_poly_fir",
         grid=(grid,),
         in_specs=[
             pl.BlockSpec((bq, D), lambda i: (i, 0)),       # prev rows
@@ -529,6 +532,7 @@ def pallas_fir_fft(hist: jnp.ndarray, x: jnp.ndarray, taps, n_fft: int,
                    bf16=(precision == "bf16"))
     out_r, out_i = pl.pallas_call(
         kern,
+        name="pallas_fir_fft",
         grid=(R_pad // B, n_fft // tn),          # column tiles innermost
         in_specs=[
             pl.BlockSpec((B, n_fft), lambda i, j: (i, 0)),      # prev rows (re)
@@ -606,6 +610,7 @@ def pallas_rotator(x: jnp.ndarray, ph0, inc,
     kern = partial(_rotator_kernel, block=block)
     out_r, out_i = pl.pallas_call(
         kern,
+        name="pallas_rotator",
         grid=(rows // block,),
         in_specs=[
             pl.BlockSpec((block, _LANES), lambda i: (i, 0)),
@@ -687,6 +692,7 @@ def pallas_quad_demod(prev, x: jnp.ndarray, gain,
     kern = partial(_quad_demod_kernel, block=block)
     y = pl.pallas_call(
         kern,
+        name="pallas_quad_demod",
         grid=(n_pad // tile,),
         in_specs=[
             pl.BlockSpec((block, _LANES), lambda i: (i, 0)),      # prev tile

@@ -228,7 +228,10 @@ class Pipeline:
             def run(carries, x):
                 new_c = []
                 for s, c in zip(stages, carries):
-                    c, x = s.fn(c, x)
+                    # metadata only: the stage's name on every op it lowers
+                    # to, for the device trace (docs/observability.md)
+                    with jax.named_scope(s.name):
+                        c, x = s.fn(c, x)
                     new_c.append(c)
                 return tuple(new_c), x
 
@@ -274,8 +277,11 @@ class Pipeline:
             in_dt, w = self.in_dtype, wire
 
             def run(carries, *parts):
-                carries, y = inner(carries, w.decode_jax(parts, in_dt))
-                return carries, w.encode_jax(y)
+                with jax.named_scope("wire_decode"):
+                    x = w.decode_jax(parts, in_dt)
+                carries, y = inner(carries, x)
+                with jax.named_scope("wire_encode"):
+                    return carries, w.encode_jax(y)
 
             if k == 1:
                 self._wired_fns[key] = run
@@ -306,7 +312,9 @@ class Pipeline:
             lay = packed
 
             def run_packed(carries, buf):
-                return inner(carries, *lay.unpack_jax(buf))
+                with jax.named_scope("unpack"):
+                    parts = lay.unpack_jax(buf)
+                return inner(carries, *parts)
 
             self._wired_fns[key] = run_packed
         return self._wired_fns[key]
@@ -572,10 +580,13 @@ class FanoutPipeline:
             in_dt, w = self.in_dtype, wire
 
             def run(carries, *parts):
-                carries, ys = inner(carries, w.decode_jax(parts, in_dt))
+                with jax.named_scope("wire_decode"):
+                    x = w.decode_jax(parts, in_dt)
+                carries, ys = inner(carries, x)
                 flat = []
-                for y in ys:
-                    flat.extend(w.encode_jax(y))
+                with jax.named_scope("wire_encode"):
+                    for y in ys:
+                        flat.extend(w.encode_jax(y))
                 return carries, tuple(flat)
 
             if k == 1:
@@ -818,7 +829,8 @@ class DagPipeline:
                     else:
                         v = tuple(vals[j] for j in inputs)
                     for si, s in enumerate(stages):
-                        c, v = s.fn(carries[off + si], v)
+                        with jax.named_scope(s.name):
+                            c, v = s.fn(carries[off + si], v)
                         new_c[off + si] = c
                     vals[i] = v
                 return tuple(new_c), tuple(vals[s] for s in sinks)

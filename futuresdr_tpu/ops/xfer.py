@@ -20,6 +20,7 @@ exists to measure the hot path it sits on.
 
 from __future__ import annotations
 
+import queue as _queue
 import random as _random
 import threading
 import time
@@ -35,7 +36,7 @@ __all__ = ["to_device", "to_host", "start_host_transfer", "start_device_transfer
            "start_device_transfer_parts", "start_host_transfer_parts",
            "split_complex_platform", "set_fake_link", "fake_link",
            "TransferError", "FakeLinkFault", "classify_transfer_error",
-           "PackedLayout"]
+           "PackedLayout", "watch", "H2DGroup"]
 
 log = logger("ops.xfer")
 _trace = _trace_recorder()
@@ -58,8 +59,10 @@ _XFER_STARTS = _prom.counter(
 # on like the counters. Under the fake link the observed duration clamps to
 # the modeled wire window (true occupancy); on real backends it is the
 # stage→finish() DWELL as the drain loop experiences it, which includes any
-# read-ahead queue wait — a latency signal, not a pure wire-time measurement
-# (same semantics as the H2D/D2H trace spans, docs/observability.md)
+# read-ahead queue wait — a latency signal, not a wire-time measurement. The
+# H2D/D2H trace spans ARE the wire time (the readiness watcher below stamps
+# their ends); the histogram cannot be, because it is observed with the
+# recorder off too, when no thread waits for a transfer
 _XFER_HIST = _prom.histogram(
     "fsdr_xfer_seconds",
     "host-device transfer duration, start to landing (fake link: modeled "
@@ -196,6 +199,155 @@ def _span_bounds_ns(t0_ns: int, service: float, deadline: float) -> tuple:
         if t0_ns < sv:
             start = min(sv, end)
     return start, end
+
+
+# ---------------------------------------------------------------------------
+# the readiness watcher: span ends that the launching thread cannot stamp
+# ---------------------------------------------------------------------------
+# "The arrays are resident" (H2D) and "the outputs are ready" (program, the
+# start of D2H) are instants only ``block_until_ready`` reveals, and a
+# launching thread that blocked on it would change the schedule it measures.
+# A daemon thread blocks instead, one per LANE: a watcher is a FIFO, and is
+# exact only where things become ready in the order they were queued. Uploads
+# land in order and outputs become ready in order, but an upload staged ahead
+# lands while an older program still runs: one FIFO for both would stamp that
+# upload late by the program's remaining time. A watcher exists only while
+# the recorder is enabled: ``watch`` starts it, it ends itself once the
+# recorder is off and its queue is empty, and between items it holds no
+# reference to an array.
+
+_watch_lock = threading.Lock()
+_watchers: dict = {}                        # lane -> queue, while one runs
+_WATCH_POLL_S = 0.2                         # how soon it notices "off"
+
+
+def watch(arrays, name: Optional[str], t0_ns: int,
+          args: Optional[dict] = None, then=None, lane: str = "out") -> None:
+    """Complete the ``cat="tpu"`` span ``name`` from ``t0_ns`` to the instant
+    every array of ``arrays`` is ready, stamped on the lane's watcher thread
+    (``lane``: ``"out"`` for program outputs, ``"h2d"`` for uploaded parts);
+    ``then(ready_ns)`` (optional) runs there right after. Call only under
+    ``if _trace.enabled``. Never watch a donated argument: an array deleted
+    before the watcher reaches it yields no span, raises nothing and counts
+    in ``SpanRecorder.unwatched``."""
+    with _watch_lock:
+        q = _watchers.get(lane)
+        if q is None:
+            q = _watchers[lane] = _queue.SimpleQueue()
+            threading.Thread(target=_watch_loop, args=(lane, q),
+                             name=f"fsdr-xfer-watch-{lane}",
+                             daemon=True).start()
+        q.put((tuple(arrays), name, t0_ns, args, then))
+
+
+def _watch_loop(lane: str, q) -> None:
+    while True:
+        try:
+            arrays, name, t0_ns, args, then = q.get(timeout=_WATCH_POLL_S)
+        except _queue.Empty:
+            with _watch_lock:       # puts hold it too: no item is stranded
+                if not _trace.enabled and q.empty():
+                    del _watchers[lane]
+                    return
+            continue
+        try:
+            for a in arrays:
+                wait = getattr(a, "block_until_ready", None)
+                if wait is not None:        # host data is ready as it is
+                    wait()
+        except Exception:           # donated or deleted before we got here
+            _trace.unwatched += 1
+        else:
+            ready = time.perf_counter_ns()
+            if name is not None:
+                _trace.complete("tpu", name, t0_ns, end_ns=ready, args=args)
+            if then is not None:
+                then(ready)
+        arrays = then = None        # hold nothing while waiting for the next
+
+
+class _Landing:
+    """A real link's ``D2H`` span: outputs ready → bytes on the host. The two
+    ends are seen by two threads (the watcher; whoever calls ``finish()``),
+    in either order; the second one to stamp completes the span."""
+
+    __slots__ = ("args", "ready_ns", "landed_ns", "_open")
+
+    def __init__(self, arrays, args: dict):
+        self.args = args
+        self.ready_ns = self.landed_ns = 0
+        self._open = [True]
+        watch(arrays, None, 0, then=self.ready)
+
+    def ready(self, t_ns: int) -> None:
+        self.ready_ns = t_ns
+        self._complete()
+
+    def landed(self) -> None:
+        self.landed_ns = time.perf_counter_ns()
+        self._complete()
+
+    def _complete(self) -> None:
+        if self.ready_ns and self.landed_ns:
+            try:
+                self._open.pop()    # atomic: exactly one side wins
+            except IndexError:
+                return
+            # bytes cannot land before they are ready: a watcher that woke
+            # after the finishing thread stamped late, not the device
+            _trace.complete("tpu", "D2H", min(self.ready_ns, self.landed_ns),
+                            end_ns=self.landed_ns, args=self.args)
+
+
+def _span_args(nbytes: int, seq) -> dict:
+    return {"bytes": nbytes} if seq is None else {"bytes": nbytes, "seq": seq}
+
+
+def _d2h_landing(arrays, nbytes: int, seq, deadline: float):
+    """The ``D2H`` span of a transfer just started on a real link, or None:
+    recorder off, or a fake link (``_d2h_observe`` records its window)."""
+    if deadline or not _trace.enabled:
+        return None
+    return _Landing(arrays, _span_args(nbytes, seq))
+
+
+def _d2h_observe(t0: int, service: float, deadline: float, nbytes: int,
+                 seq) -> None:
+    """A finished D2H's histogram sample and, under a fake link, its span
+    (the modelled wire window)."""
+    s, e = _span_bounds_ns(t0, service, deadline)
+    _D2H_HIST.observe((e - s) * 1e-9)
+    if deadline and _trace.enabled:
+        _trace.complete("tpu", "D2H", s, end_ns=e,
+                        args=_span_args(nbytes, seq))
+
+
+class H2DGroup:
+    """ONE ``h2d_put`` + ``H2D`` span pair for several transfer starts (a
+    serving dispatch group's four puts): pass it as ``group=`` to each start,
+    then :meth:`close` once they have all returned. Create only under
+    ``if _trace.enabled``. Under a fake link each start keeps its own
+    modelled ``H2D`` window and the group records only ``h2d_put``."""
+
+    __slots__ = ("seq", "t0_ns", "arrays", "nbytes")
+
+    def __init__(self, seq=None):
+        self.seq = seq
+        self.t0_ns = time.perf_counter_ns()
+        self.arrays: list = []
+        self.nbytes = 0
+
+    def add(self, arrays, nbytes: int) -> None:
+        self.arrays.extend(arrays)
+        self.nbytes += nbytes
+
+    def close(self) -> None:
+        args = _span_args(self.nbytes, self.seq)
+        _trace.complete("tpu", "h2d_put", self.t0_ns, args=args)
+        if self.arrays:
+            watch(self.arrays, "H2D", self.t0_ns, args, lane="h2d")
+            self.arrays = []
+
 
 _join_jit = None
 _split_jit = None
@@ -375,11 +527,18 @@ def _device_platform(device=None) -> str:
     return jax.default_backend()
 
 
-def start_device_transfer_parts(parts, device=None):
+def start_device_transfer_parts(parts, device=None, seq=None, group=None):
     """Begin a NON-blocking H2D of pre-encoded wire parts (``ops/wire.py``
     layouts — plain real/int numpy arrays, never complex); returns a zero-arg
     ``finish()`` that blocks until the payload is device-resident and yields
     the tuple of device arrays.
+
+    Spans (recorder on): ``h2d_put`` brackets the ``device_put`` calls on this
+    thread; ``H2D`` runs from the first of them to the instant the arrays are
+    resident, stamped by the watcher. ``seq`` (the dispatch group's sequence
+    number) rides both; ``group`` (an :class:`H2DGroup`) folds this start into
+    the group's one pair instead. Under a fake link ``H2D`` is the modelled
+    wire window, completed by ``finish()``.
 
     This is the H2D symmetric of :func:`start_host_transfer` — the primitive
     that lets a drain loop keep H2D(t+1) on the wire while frame t computes
@@ -400,18 +559,27 @@ def start_device_transfer_parts(parts, device=None):
         _check_injected("h2d")
         return tuple(jax.device_put(p, device) for p in host)
 
+    t_put = time.perf_counter_ns() if _trace.enabled and group is None else 0
     devs = _with_retry("h2d", attempt)
     # the wire is reserved AFTER the attempt succeeds: faulted attempts spend
     # backoff wall-clock, not modeled wire occupancy
     service, deadline = _reserve("h2d", nbytes)
     t0 = time.perf_counter_ns()
+    if t_put:
+        args = _span_args(nbytes, seq)
+        _trace.complete("tpu", "h2d_put", t_put, end_ns=t0, args=args)
+        if not deadline:
+            watch(devs, "H2D", t_put, args, lane="h2d")
+    elif group is not None and not deadline:
+        group.add(devs, nbytes)
 
     def finish():
         _wait_deadline(deadline)
         s, e = _span_bounds_ns(t0, service, deadline)
         _H2D_HIST.observe((e - s) * 1e-9)
-        if _trace.enabled:
-            _trace.complete("tpu", "H2D", s, end_ns=e, args={"bytes": nbytes})
+        if deadline and _trace.enabled:
+            _trace.complete("tpu", "H2D", s, end_ns=e,
+                            args=_span_args(nbytes, seq))
         return devs
 
     # modeled wire window (service start, landing deadline) — zeros without a
@@ -527,10 +695,11 @@ class PackedLayout:
         return tuple(parts)
 
 
-def start_device_transfer(arr, device=None):
+def start_device_transfer(arr, device=None, seq=None, group=None):
     """Begin a NON-blocking H2D of one host array (complex rides the pair shim);
     returns ``finish() -> device array``. :func:`to_device` is this with an
-    immediate finish."""
+    immediate finish. ``seq``/``group``: the span arguments of
+    :func:`start_device_transfer_parts`."""
     import jax
 
     if isinstance(arr, jax.Array):
@@ -543,7 +712,7 @@ def start_device_transfer(arr, device=None):
             split_complex_platform(_device_platform(device)):
         from .wire import _pairs_view
         pairs = _pairs_view(a)   # the ONE copy of the regression-locked trick
-        put = start_device_transfer_parts((pairs,), device)
+        put = start_device_transfer_parts((pairs,), device, seq, group)
         join, _ = _jits()
 
         def finish():
@@ -552,7 +721,7 @@ def start_device_transfer(arr, device=None):
 
         finish._wire = getattr(put, "_wire", None)
         return finish
-    put = start_device_transfer_parts((a,), device)
+    put = start_device_transfer_parts((a,), device, seq, group)
 
     def finish():
         (x,) = put()
@@ -572,12 +741,18 @@ def to_host(arr) -> np.ndarray:
     return start_host_transfer(arr)()
 
 
-def start_host_transfer(arr, _instrument: bool = True):
+def start_host_transfer(arr, _instrument: bool = True, seq=None):
     """Begin a NON-blocking D2H of ``arr``; returns a zero-arg ``finish()`` that
     blocks until the copy lands and yields the numpy array.
     ``_instrument=False`` (module-private) suppresses the per-call telemetry so
     :func:`start_host_transfer_parts` can bill one frame's parts as ONE
     transfer — symmetric with the H2D side, which reserves per frame.
+
+    The ``D2H`` span (recorder on) runs from the instant ``arr`` is READY on
+    the device, stamped by the watcher, to the instant its bytes are on the
+    host, stamped here in ``finish()`` — not from ``copy_to_host_async``,
+    which is called before the program has run. ``seq`` rides its args. Under
+    a fake link it is the modelled wire window.
 
     This is how a drain loop overlaps transfers: start transfers for every
     completed frame first, then finish them oldest-first — frame t+1's D2H rides
@@ -621,18 +796,18 @@ def start_host_transfer(arr, _instrument: bool = True):
             fr, fi = _with_retry("d2h", attempt)
             service, deadline = _reserve("d2h", nbytes)
             t0 = time.perf_counter_ns() if _instrument else 0
+            landing = _d2h_landing((r, i), nbytes, seq, deadline) \
+                if t0 else None
 
             def finish():
                 out = np.empty(r.shape, dtype=dt)
                 out.real = fr()
                 out.imag = fi()
+                if landing is not None:
+                    landing.landed()
                 _wait_deadline(deadline)
                 if t0:
-                    s, e = _span_bounds_ns(t0, service, deadline)
-                    _D2H_HIST.observe((e - s) * 1e-9)
-                    if _trace.enabled:
-                        _trace.complete("tpu", "D2H", s, end_ns=e,
-                                        args={"bytes": nbytes})
+                    _d2h_observe(t0, service, deadline, nbytes, seq)
                 return out
 
             finish._wire = (service, deadline)
@@ -650,23 +825,22 @@ def start_host_transfer(arr, _instrument: bool = True):
     fetch = _with_retry("d2h", attempt)
     service, deadline = _reserve("d2h", nbytes)
     t0 = time.perf_counter_ns() if _instrument else 0
+    landing = _d2h_landing((arr,), nbytes, seq, deadline) if t0 else None
 
     def finish():
         out = fetch()
+        if landing is not None:
+            landing.landed()
         _wait_deadline(deadline)
         if t0:
-            s, e = _span_bounds_ns(t0, service, deadline)
-            _D2H_HIST.observe((e - s) * 1e-9)
-            if _trace.enabled:
-                _trace.complete("tpu", "D2H", s, end_ns=e,
-                                args={"bytes": nbytes})
+            _d2h_observe(t0, service, deadline, nbytes, seq)
         return out
 
     finish._wire = (service, deadline)
     return finish
 
 
-def start_host_transfer_parts(parts):
+def start_host_transfer_parts(parts, seq=None):
     """Begin a NON-blocking D2H of a tuple of wire parts (a jitted epilog's
     output, ``ops/wire.py``); returns ``finish() -> tuple of np arrays``.
     Every part's transfer starts immediately, so in-flight frames' payloads
@@ -675,22 +849,23 @@ def start_host_transfer_parts(parts):
     Telemetry bills the WHOLE frame as one D2H transfer/span (symmetric with
     :func:`start_device_transfer_parts`): per-part billing would make the
     d2h counters and lane span counts scale with the wire's part count
-    instead of the frame count."""
+    instead of the frame count. The ``D2H`` span is
+    :func:`start_host_transfer`'s: parts ready → every part on the host."""
     fins = [start_host_transfer(p, _instrument=False) for p in parts]
     nbytes = sum(int(getattr(p, "nbytes", 0)) for p in parts)
     _XFER_BYTES.inc(nbytes, direction="d2h")
     _XFER_TRANSFERS.inc(direction="d2h")
     t0 = time.perf_counter_ns()
+    wires = [getattr(f, "_wire", (0.0, 0.0)) for f in fins]
+    service = min((s for s, _ in wires if s), default=0.0)
+    deadline = max((d for _, d in wires), default=0.0)
+    landing = _d2h_landing(parts, nbytes, seq, deadline)
 
     def finish():
         out = tuple(f() for f in fins)
-        wires = [getattr(f, "_wire", (0.0, 0.0)) for f in fins]
-        service = min((s for s, _ in wires if s), default=0.0)
-        deadline = max((d for _, d in wires), default=0.0)
-        s, e = _span_bounds_ns(t0, service, deadline)
-        _D2H_HIST.observe((e - s) * 1e-9)
-        if _trace.enabled:
-            _trace.complete("tpu", "D2H", s, end_ns=e, args={"bytes": nbytes})
+        if landing is not None:
+            landing.landed()
+        _d2h_observe(t0, service, deadline, nbytes, seq)
         return out
 
     return finish

@@ -225,12 +225,16 @@ def build_slot_program(pipeline, capacity: int, k: int = 1):
 
     if int(k) <= 1:
         def step(pages, page_map, fresh, x, active):
-            carries = gather(pages, page_map, fresh)
+            with jax.named_scope("serve_gather"):
+                carries = gather(pages, page_map, fresh)
             new_c, y = masked_lane_step(carries, x, active)
-            return scatter(pages, page_map, new_c), (y if multi else (y,))
+            with jax.named_scope("serve_scatter"):
+                pages = scatter(pages, page_map, new_c)
+            return pages, (y if multi else (y,))
     else:
         def step(pages, page_map, fresh, x, active):
-            carries = gather(pages, page_map, fresh)
+            with jax.named_scope("serve_gather"):
+                carries = gather(pages, page_map, fresh)
 
             def body(c, xa):
                 xk, ak = xa
@@ -244,7 +248,9 @@ def build_slot_program(pipeline, capacity: int, k: int = 1):
                 outs = tuple(jnp.moveaxis(yj, 0, 1) for yj in ys)
             else:
                 outs = (jnp.moveaxis(ys, 0, 1),)
-            return scatter(pages, page_map, carries), outs
+            with jax.named_scope("serve_scatter"):
+                pages = scatter(pages, page_map, carries)
+            return pages, outs
 
     return jax.jit(step, donate_argnums=())
 
@@ -258,11 +264,11 @@ class _DispatchGroup:
 
     __slots__ = ("capacity", "k", "lanes", "n_frames", "batch", "active",
                  "fresh", "page_map", "fresh_lanes", "step_tids", "t_step",
-                 "new_pages", "fins", "wire")
+                 "seq", "new_pages", "fins", "wire")
 
     def __init__(self, capacity: int, k: int, lanes: list, batch, active,
                  fresh, page_map, fresh_lanes: frozenset, step_tids: list,
-                 t_step: int):
+                 t_step: int, seq: int):
         self.capacity = capacity
         self.k = k
         self.lanes = lanes            # (session, lane, popped, tids) tuples
@@ -274,6 +280,7 @@ class _DispatchGroup:
         self.fresh_lanes = fresh_lanes
         self.step_tids = step_tids
         self.t_step = t_step
+        self.seq = seq                # the engine's step number: joins spans
         self.new_pages = None         # set by launch
         self.fins = None              # pending D2H finishes, one per sink
         self.wire = None              # H2D (service, deadline) wire window
@@ -882,8 +889,14 @@ class ServeEngine:
         # the refresh reads only lock-free surfaces
         if _fleet._tick_state is not None:
             _fleet.tick()
+        t_lk = _trace.now() if _trace.enabled else 0
         with self._step_lock:
+            t_held = _trace.now() if t_lk else 0
             g = self._assemble()
+            if t_lk and g is not None:
+                # (an idle tick records nothing)
+                _trace.complete("serve", "lock_wait", t_lk, end_ns=t_held,
+                                args={"lock": "step", "seq": g.seq})
             if g is None:
                 self._drain_inflight(0)
                 with self._lock:
@@ -929,6 +942,7 @@ class ServeEngine:
         the lane→page permutation and the fresh-lane vector, and CLEAR the
         fresh bits — the launch materializes those lanes' template pages
         (rollback restores the bits). Returns None on an idle step."""
+        t_lk = _trace.now() if _trace.enabled else 0
         with self._lock:
             C = self.table.capacity
             K = self._k_eff
@@ -939,7 +953,7 @@ class ServeEngine:
             # lanes are emitted by the async transfer finishes themselves
             # (ops/xfer.py), so the doctor's interval-union lanes show the
             # REAL wire concurrency of the overlapped step
-            t_step = _trace.now() if _trace.enabled else 0
+            t_step = _trace.now() if t_lk else 0
             t_enc = t_step
             # idle frame-time ticks (no lane has pending input — the common
             # case for a pump loop ticking at frame rate) must cost nothing:
@@ -993,9 +1007,21 @@ class ServeEngine:
             if not lanes:
                 return None
             if t_enc:
-                _trace.complete("tpu", "encode", t_enc,
+                # submit() and the REST handlers hold `_lock` too, and
+                # t_step started only once it was held
+                _trace.complete("serve", "lock_wait", t_lk, end_ns=t_step,
+                                args={"lock": "state", "seq": self.steps})
+                t_pop = _trace.now()
+                _trace.complete("tpu", "encode", t_enc, end_ns=t_pop,
                                 args={"sessions": len(lanes),
-                                      "capacity": C})
+                                      "capacity": C, "seq": self.steps})
+                # how long this step's frames sat in `pending`: from the
+                # oldest one's submit stamp to the pop, and the mean over all
+                subs = [t for _s, _l, popped, _t in lanes for _f, t in popped]
+                _trace.complete(
+                    "serve", "queue_wait", min(subs), end_ns=t_pop,
+                    args={"seq": self.steps, "frames": len(subs),
+                          "mean_ms": (t_pop - sum(subs) / len(subs)) * 1e-6})
             if step_tids:
                 lin = _lineage.tracer()
                 for tid in step_tids:
@@ -1010,7 +1036,7 @@ class ServeEngine:
             g = _DispatchGroup(
                 C, K, lanes, batch, active, fresh,
                 np.asarray(self.table.page_of_lane, dtype=np.int32),
-                frozenset(self._fresh_lanes), step_tids, t_step)
+                frozenset(self._fresh_lanes), step_tids, t_step, self.steps)
             self._fresh_lanes.clear()
             return g
 
@@ -1022,10 +1048,14 @@ class ServeEngine:
         chain exactly as it was for the rollback path."""
         C, K = g.capacity, g.k
         prog = self._program(C, K)
-        fx = self._start_h2d(g.batch, shard=True)
-        fa = self._start_h2d(g.active, shard=True)
-        fm = self._start_h2d(g.page_map, shard=False)
-        ff = self._start_h2d(g.fresh, shard=False)
+        # one h2d_put + H2D span pair for the group's four puts
+        grp = xfer.H2DGroup(g.seq) if _trace.enabled else None
+        fx = self._start_h2d(g.batch, True, grp)
+        fa = self._start_h2d(g.active, True, grp)
+        fm = self._start_h2d(g.page_map, False, grp)
+        ff = self._start_h2d(g.fresh, False, grp)
+        if grp is not None:
+            grp.close()
         x, act = fx(), fa()
         pmap, fresh = fm(), ff()
         g.wire = getattr(fx, "_wire", None)
@@ -1049,18 +1079,22 @@ class ServeEngine:
                 new_pages, outs = prog(self._head_pages, pmap, fresh, x, act)
             self._warmed.add(key)
         if t0:
-            _trace.complete("tpu", "compute", t0,
-                            args={"capacity": C,
-                                  "active_lanes": len(g.lanes)})
+            # `compute` is the enqueue call; `program` beside it ends when
+            # the OUTPUTS are ready (the watcher's stamp). The page pool is
+            # the next call's input and is never watched
+            args = {"capacity": C, "active_lanes": len(g.lanes),
+                    "seq": g.seq}
+            _trace.complete("tpu", "compute", t0, args=args)
+            xfer.watch(outs, "program", t0, args)
         if g.step_tids:
             lin = _lineage.tracer()
             for tid in g.step_tids:
                 lin.stamp(tid, "dispatch")
-        g.fins = [xfer.start_host_transfer(o) for o in outs]
+        g.fins = [xfer.start_host_transfer(o, seq=g.seq) for o in outs]
         g.new_pages = new_pages
         self._head_pages = new_pages
 
-    def _start_h2d(self, arr: np.ndarray, shard: bool):
+    def _start_h2d(self, arr: np.ndarray, shard: bool, group=None):
         """Start one async H2D for a group launch; returns a finish thunk.
         Unsharded buckets ride ``xfer.start_device_transfer``, whose finish
         models/measures the wire window (the ``_wire`` attribute feeding
@@ -1071,8 +1105,10 @@ class ServeEngine:
             import jax
             v = jax.device_put(arr, self._slot_sharding if shard
                                else self._replicated_sharding)
+            if group is not None:
+                group.add((v,), arr.nbytes)
             return lambda: v
-        return xfer.start_device_transfer(arr, self.inst.device)
+        return xfer.start_device_transfer(arr, self.inst.device, group=group)
 
     def _drain_inflight(self, keep: int) -> None:
         """Commit in-flight groups oldest-first until at most ``keep``
@@ -1086,6 +1122,7 @@ class ServeEngine:
             if keep:
                 self._flight.note_limited()
             g = self._inflight[0]
+            t0 = _trace.now() if _trace.enabled else 0
             try:
                 host = [np.asarray(f()) for f in g.fins]
             except Exception:
@@ -1093,6 +1130,11 @@ class ServeEngine:
                 self._inflight.clear()
                 self._rollback(doomed, reset_head=True)
                 raise
+            if t0:
+                # this thread blocked until the results were on the host:
+                # the upload's tail + the program + the download, which the
+                # watcher's H2D / program / D2H spans divide
+                _trace.complete("tpu", "d2h_wait", t0, args={"seq": g.seq})
             self._inflight.popleft()
             self._commit(g, host)
 
@@ -1123,9 +1165,13 @@ class ServeEngine:
         flight (closed/retired, or its lane re-bound) is skipped: there is
         nobody to deliver to."""
         end = time.perf_counter_ns()
-        t_dec = _trace.now() if _trace.enabled else 0
+        t_lk = _trace.now() if _trace.enabled else 0
         K = g.k
         with self._lock:
+            t_dec = _trace.now() if t_lk else 0
+            if t_lk:
+                _trace.complete("serve", "lock_wait", t_lk, end_ns=t_dec,
+                                args={"lock": "state", "seq": g.seq})
             self._pages = g.new_pages
             self.dispatches += 1
             dispatched = 0
@@ -1171,13 +1217,13 @@ class ServeEngine:
             self._prof.dispatch(dispatched, t=time.monotonic())
         if t_dec:
             _trace.complete("tpu", "decode", t_dec,
-                            args={"frames": dispatched})
+                            args={"frames": dispatched, "seq": g.seq})
         if g.t_step:
             _trace.complete("serve", "serve_step", g.t_step,
                             args={"sessions": len(g.lanes),
                                   "active_lanes": len(g.lanes),
                                   "frames": dispatched,
-                                  "capacity": g.capacity})
+                                  "capacity": g.capacity, "seq": g.seq})
 
     # -- lane-addressed retunes ------------------------------------------------
     def retune(self, sid: str, stage, **params) -> Session:

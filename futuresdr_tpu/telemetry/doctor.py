@@ -808,6 +808,13 @@ class Doctor:
         """
         evs = list(spans.drain() if events is None else events)
         lane_iv = {n: spans.intervals(evs, name=n, cat="tpu") for n in LANES}
+        # the compute lane is the DEVICE's: `program` spans (the call → its
+        # outputs ready, stamped by ops/xfer.py's watcher) where the events
+        # hold any. `compute` brackets only the enqueue call on an
+        # accelerator, and stands in where no watcher ran
+        program_iv = spans.intervals(evs, name="program", cat="tpu")
+        if program_iv:
+            lane_iv["compute"] = program_iv
         blocks: Dict[str, list] = {}
         for e in evs:
             if e.cat == "block" and e.dur_ns is not None:

@@ -31,8 +31,7 @@ plane"):
   ``t=time.monotonic()`` group stamp — and
   :meth:`ProfilePlane.update_live_gauges`
   turns the windowed unit rate into always-on ``fsdr_mfu{program}`` /
-  ``fsdr_hbm_util{program}`` gauges (plus Perfetto counter tracks when the
-  span recorder is enabled). The "unit" is whatever the registrar says its
+  ``fsdr_hbm_util{program}`` gauges. The "unit" is whatever the registrar says its
   cost covers: one dispatch group for the streamed kernels (the wired
   megabatch program, K frames per unit), one session-frame (lane) for the
   serving engine. Peaks come from ``utils/roofline.detect_peaks`` —
@@ -48,7 +47,7 @@ import time
 from collections import deque
 from typing import Dict, List, Optional
 
-from . import prom, spans
+from . import prom
 
 __all__ = [
     "ProfilePlane", "plane", "register", "compiling", "record_compile",
@@ -398,7 +397,6 @@ class ProfilePlane:
         whose cost is not materialized, and chips without a known peak,
         simply publish nothing — degradation, not a wrong denominator."""
         peaks = self._peaks()
-        rec = spans.recorder()
         now = time.monotonic()
         for p in self.programs():
             units = p.units               # single reader of the window state
@@ -431,12 +429,6 @@ class ProfilePlane:
                 MFU_DEVICE.set(p.mfu, program=p.name, device=p.device)
                 HBM_UTIL_DEVICE.set(p.hbm_util, program=p.name,
                                     device=p.device)
-            if rec.enabled:
-                # Perfetto counter tracks next to the lane spans
-                tag = p.name if p.device is None \
-                    else f"{p.name}@dev{p.device}"
-                rec.counter(f"mfu:{tag}", p.mfu)
-                rec.counter(f"hbm_util:{tag}", p.hbm_util)
 
     # -- snapshots -------------------------------------------------------------
     def roofline_report(self) -> dict:

@@ -140,6 +140,9 @@ class SpanRecorder:
         self._rings: List[_ThreadRing] = []
         self._reg_lock = threading.Lock()
         self.dropped = 0          # accumulated across drains
+        #: spans the readiness watcher (ops/xfer.py) could not complete: the
+        #: watched array was donated or deleted before it was reached
+        self.unwatched = 0
 
     #: registry bound: beyond this many per-thread rings the oldest DEAD
     #: threads' rings are evicted (their events counted as dropped) — so a
@@ -197,16 +200,6 @@ class SpanRecorder:
             return
         self._ring().push((time.perf_counter_ns(), None, cat, name, args))
 
-    def counter(self, name: str, value: float, cat: str = "counter") -> None:
-        """Record one Perfetto counter-track sample (``"C"`` phase on
-        export) — the profile plane's live MFU/HBM-util gauges ride these
-        next to the lane spans so utilization is plottable against the
-        trace timeline."""
-        if not self.enabled:
-            return
-        self._ring().push((time.perf_counter_ns(), None, cat, name,
-                           {"value": float(value)}))
-
     def span(self, cat: str, name: str, **args):
         """Context manager form for non-hot-path spans."""
         if not self.enabled:
@@ -261,13 +254,6 @@ class SpanRecorder:
         seen_tids: Dict[int, str] = {}
         for e in evs:
             seen_tids.setdefault(e.tid, e.thread)
-            if e.cat == "counter":
-                # counter-track sample (SpanRecorder.counter): Perfetto draws
-                # these as a per-name value track, pid-scoped
-                trace.append({"ph": "C", "pid": pid, "tid": e.tid,
-                              "ts": (e.t0_ns - epoch) / 1e3,
-                              "name": e.name, "args": e.args or {}})
-                continue
             d = {"ph": "X" if e.dur_ns is not None else "i",
                  "pid": pid, "tid": e.tid,
                  "ts": (e.t0_ns - epoch) / 1e3,   # Chrome wants microseconds

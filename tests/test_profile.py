@@ -13,7 +13,6 @@ import pytest
 
 from futuresdr_tpu.telemetry import doctor as doc
 from futuresdr_tpu.telemetry import profile
-from futuresdr_tpu.telemetry.spans import SpanRecorder
 
 
 # ---------------------------------------------------------------------------
@@ -350,24 +349,6 @@ def test_kind_to_chip_mapping():
     assert _kind_to_chip("TPU v3") == "v3"
     assert _kind_to_chip("TPU v2") == "v2"
     assert _kind_to_chip("Quantum Accelerator Mk1") is None
-
-
-# ---------------------------------------------------------------------------
-# Perfetto counter tracks
-# ---------------------------------------------------------------------------
-
-def test_span_counter_exports_as_counter_phase():
-    rec = SpanRecorder(capacity=64, enabled=True)
-    rec.counter("mfu:t-prog", 0.25)
-    doc_json = rec.chrome_trace()
-    c = [e for e in doc_json["traceEvents"] if e.get("ph") == "C"]
-    assert len(c) == 1
-    assert c[0]["name"] == "mfu:t-prog"
-    assert c[0]["args"] == {"value": 0.25}
-    # disabled recorder records nothing
-    rec2 = SpanRecorder(capacity=64, enabled=False)
-    rec2.counter("mfu:x", 1.0)
-    assert rec2.drain() == []
 
 
 # ---------------------------------------------------------------------------

@@ -146,7 +146,12 @@ def test_d2h_parts_billed_as_one_transfer(tracing):
     out = xfer.start_host_transfer_parts(parts)()
     assert len(out) == 2
     assert xfer._XFER_TRANSFERS.get(direction="d2h") == before + 1
-    d2h = [e for e in tracing.drain() if e.name == "D2H"]
+    # the span's start (parts ready) is stamped by ops/xfer.py's watcher
+    # thread: give it a moment
+    d2h, deadline = [], time.monotonic() + 2.0
+    while not d2h and time.monotonic() < deadline:
+        d2h += [e for e in tracing.drain() if e.name == "D2H"]
+        time.sleep(0.005)
     assert len(d2h) == 1 and d2h[0].args["bytes"] == 512
 
 
@@ -449,7 +454,8 @@ def test_telemetry_disabled_overhead_null_rand(monkeypatch):
     sample draw billed as a fifth (frame-lineage tracing at the default
     stride must ride inside the same budget as well), and the fleet plane's
     per-step tick billed as a sixth (the cross-host plane off by default
-    must be one falsy check).
+    must be one falsy check), and the span-timeline sites of both launch
+    paths billed as a seventh (ten guard pairs per frame or serving step).
 
     The per-work-call cost of the disabled telemetry path (the `if
     rec.enabled:` guard, the ns-clock reads the loop already paid
@@ -567,6 +573,50 @@ def test_telemetry_disabled_overhead_null_rand(monkeypatch):
             if fleet_mod._tick_state is not None:  # pragma: no cover
                 fleet_mod.tick()
 
+    # span-timeline sites (tpu/kernel_block.py, serve/engine.py, ops/xfer.py):
+    # one frame's way through either launch path passes about ten
+    # `t0 = now() if enabled else 0` … `if t0:` guard pairs (stage, h2d_put,
+    # h2d_wait, compute/program, d2h_wait, emit, frame; lock_wait ×3,
+    # queue_wait, h2d group, d2h_wait in serving) — a SEVENTH per-call hook
+    # class. Unlike the work/park hooks, which every block pays on every
+    # call, only the device kernel's own calls pass these: billed at TEN
+    # pairs per work call of ONE of the chain's six blocks — still a
+    # conservative over-count (the real rate is per FRAME or serving step,
+    # a millisecond or more, against this chain's ~20 µs per call)
+    chain_blocks = 6                    # source, head, 3 × CopyRand, sink
+    def timeline_hook():
+        for _ in range(n):
+            t0 = rec.now() if rec.enabled else 0
+            if t0:                               # pragma: no cover
+                rec.complete("tpu", "x", t0)
+            t1 = rec.now() if rec.enabled else 0
+            if t1:                               # pragma: no cover
+                rec.complete("tpu", "x", t1)
+            t2 = rec.now() if rec.enabled else 0
+            if t2:                               # pragma: no cover
+                rec.complete("tpu", "x", t2)
+            t3 = rec.now() if rec.enabled else 0
+            if t3:                               # pragma: no cover
+                rec.complete("tpu", "x", t3)
+            t4 = rec.now() if rec.enabled else 0
+            if t4:                               # pragma: no cover
+                rec.complete("tpu", "x", t4)
+            t5 = rec.now() if rec.enabled else 0
+            if t5:                               # pragma: no cover
+                rec.complete("tpu", "x", t5)
+            t6 = rec.now() if rec.enabled else 0
+            if t6:                               # pragma: no cover
+                rec.complete("tpu", "x", t6)
+            t7 = rec.now() if rec.enabled else 0
+            if t7:                               # pragma: no cover
+                rec.complete("tpu", "x", t7)
+            t8 = rec.now() if rec.enabled else 0
+            if t8:                               # pragma: no cover
+                rec.complete("tpu", "x", t8)
+            t9 = rec.now() if rec.enabled else 0
+            if t9:                               # pragma: no cover
+                rec.complete("tpu", "x", t9)
+
     # paired trials: hook micro-costs and the chain rate are measured back to
     # back INSIDE each trial, and the gate takes the best trial — a transient
     # load spike that inflates only one side of one trial (the structural
@@ -584,6 +634,7 @@ def test_telemetry_disabled_overhead_null_rand(monkeypatch):
         work_ns, park_ns, ckpt_ns, prof_ns, lin_ns, fleet_ns = \
             best_of(work_hook), best_of(park_hook), best_of(ckpt_hook), \
             best_of(prof_hook), best_of(lineage_hook), best_of(fleet_hook)
+        tl_ns = best_of(timeline_hook) / chain_blocks
         # the chain's real call rate, measured with the watchdog running at
         # its DEFAULT interval (1 Hz sampling lands in `elapsed`, not per
         # call)
@@ -594,18 +645,19 @@ def test_telemetry_disabled_overhead_null_rand(monkeypatch):
         finally:
             doc.disable()
         overhead = calls * (work_ns + park_ns + ckpt_ns + prof_ns
-                            + lin_ns + fleet_ns) * 1e-9 / elapsed
+                            + lin_ns + fleet_ns + tl_ns) * 1e-9 / elapsed
         trials.append((overhead, work_ns, park_ns, ckpt_ns, prof_ns,
-                       lin_ns, fleet_ns, calls, elapsed))
+                       lin_ns, fleet_ns, tl_ns, calls, elapsed))
         if overhead <= 0.03:
             break
     (overhead, work_ns, park_ns, ckpt_ns, prof_ns, lin_ns, fleet_ns,
-     calls, elapsed) = min(trials)
+     tl_ns, calls, elapsed) = min(trials)
     ltr.clear()
     assert overhead <= 0.03, (
         f"telemetry-disabled hooks cost {overhead * 100:.2f}% of the "
         f"null_rand chain ({calls} work calls, {work_ns:.0f}+{park_ns:.0f}"
-        f"+{ckpt_ns:.0f}+{prof_ns:.0f}+{lin_ns:.0f}+{fleet_ns:.0f} ns/hook, "
+        f"+{ckpt_ns:.0f}+{prof_ns:.0f}+{lin_ns:.0f}+{fleet_ns:.0f}"
+        f"+{tl_ns:.0f} ns/hook, "
         f"{elapsed:.3f}s elapsed; best of {len(trials)} paired trials)")
 
 
