@@ -80,7 +80,9 @@ class Stage:
     #   right per-dtype chip peak (utils/roofline.detect_peaks)
     route: Optional[Tuple[Optional[str], Optional[str], Optional[str]]] = None
     #   (impl, fft_impl, precision) — the builder's per-call-site selection for
-    #   kernel-backed stages (fir/fft/channelizer). LTI merging preserves pins
+    #   kernel-backed stages (fir/fft/channelizer); the decimating FIRs' matvec
+    #   route names the row width that compiled in the impl slot ("rows128",
+    #   _row_width). LTI merging preserves pins
     #   only when both sides agree (a pin must never be silently dropped), the
     #   cost-cache marker includes it (two same-shape stages on different
     #   routes compile different-cost programs), and
@@ -974,9 +976,10 @@ def fir_stage(taps, decim: int = 1, fft_len: int = 8192, name: str = "fir",
     Polyphase decimation (``decim > 1``): computing the full-rate convolution and
     slicing ``y[::D]`` wastes (D-1)/D of the FLOPs. The decimated output is
     ``y[q] = Σ_t taps[t] · x[q·D − t]`` — windows of ``ntaps`` samples at stride D,
-    which (like :func:`resample_stage`'s poly path) are STATIC slices of a row-concat
-    matrix, contracted against the reversed taps in one MXU einsum: ntaps/D MACs per
-    input sample, and the stage's frame multiple drops from lcm(hop, D) to D.
+    which (like :func:`resample_stage`'s poly path) are shifted views of the input
+    reshaped into MXU-sized rows, each contracted against a band matrix of the taps
+    (:func:`_poly_decim_fir_stage`); the stage's frame multiple drops from
+    lcm(hop, D) to D.
     Matches ``decimate == true`` FIR cores (``futuredsp/fir.rs:31``) re-designed for
     the MXU rather than translated.
 
@@ -1196,12 +1199,74 @@ def _int8_shifted_matvec(rows, W, m: int, nq: int):
     return acc.astype(jnp.float32) * (sx * sw)
 
 
+_MXU_EDGE = 128     # contraction width that fills a v5e MXU pass
+
+
+def _row_width(D: int) -> int:
+    """Row width ``R = g·D`` of the shifted-row factorization for decimation ``D``
+    (see :func:`_shifted_matvec`). Rows of width D contract over D: at D = 4 the
+    FM tuner's 128 taps were 33 complex ``[16375, 4]·[4]`` matvecs per lane, which
+    XLA:TPU lowers to 99 VPU multiply-reduces over a minor dimension that fills 4
+    of 128 lanes (v5e, [64, 65500]: 13.3 ms). D >= 64 is already MXU-shaped (the
+    audio resampler's ``[nq, 125]·[125, 24]``) and keeps its rows; smaller D takes
+    the smallest multiple of D that is >= 128, the frame's tail zero-padded to a
+    whole row. The chip A/B of that stage (v5e, PERF.md section 6, PR 25): R = 128
+    0.36 ms, 256 0.45, 100 (divides the frame; three terms, K unaligned) 0.92,
+    500 (divides it; 131 rows, 4x the operations) 1.3-1.6, so the rule does not
+    look for a divisor of the frame."""
+    return D if 2 * D >= _MXU_EDGE else D * -(-_MXU_EDGE // D)
+
+
+def _band_index(m: int, D: int, R: int) -> np.ndarray:
+    """Static index table of :func:`_band_weights`: ``idx[a, s, o]`` is the flat
+    position in ``W[m+1, D]`` of the weight that row shift ``a``, column ``s`` of
+    an R-wide row contributes to that row's output ``o``, or ``(m+1)·D`` (a zero
+    appended to the flat weights) where no tap falls."""
+    g = R // D
+    a = np.arange(-(-m // g) + 1)[:, None, None]
+    s = np.arange(R)[None, :, None]
+    o = np.arange(g)[None, None, :]
+    u = a * R - s                       # row offset a·R − s = (a'−o)·D − s'
+    q = -(-u // D)
+    ap, sp = o + q, q * D - u
+    return np.where((ap >= 0) & (ap <= m), ap * D + sp, (m + 1) * D).astype(np.int32)
+
+
+def _band_weights(W, m: int, R: int):
+    """Re-block the carried D-wide weights ``W[m+1, D(, I)]`` into the band matrix
+    ``A[(m_R+1)·R, g(·I)]`` over rows of width ``R = g·D``, ``m_R = ceil(m/g)``:
+    each R-wide row yields g outputs (× I phases), and block ``a`` of A is
+    ``A[a][s, o] = W[a', s']`` with ``a'·D − s' = a·R + o·D − s`` — for a plain
+    decimator ``A[a][s, o] = c[a·R + D·o − s]``, zero outside the taps. Built IN
+    THE TRACE from the carry through a static index table (as ``fir_stage``'s
+    int8 rung builds its band matrix), so the carry tree stays the D-wide one:
+    retunes, tap swaps, page gather/scatter and persisted carries see no change,
+    and a per-lane ``W`` under ``vmap`` gives per-lane bands. Returned with a
+    leading axis of one: the weights of a single term of :func:`_shifted_matvec`."""
+    tail = W.shape[2:]
+    idx = _band_index(m, W.shape[1], R)
+    Wz = jnp.concatenate([W.reshape((-1,) + tail), jnp.zeros((1,) + tail, W.dtype)])
+    return Wz[idx].reshape(1, idx.shape[0] * R, -1)
+
+
 def _shifted_matvec(ext: jnp.ndarray, W, m: int, nq: int,
                     precision: Optional[str] = None):
-    """``y = Σ_{r=0..m} rows[m−r : m−r+nq] @ W[r]`` with ``rows = ext.reshape(-1, D)``
-    (a view — nothing materialized). The shared accumulation of the shifted-row
-    polyphase factorization (_poly_decim_fir_stage / resample_stage /
-    xlating_fir_stage); HIGHEST precision by default so no TPU bf16 passes sneak
+    """``y = Σ_{r=0..m} rows[m−r : m−r+nq] @ W[r]`` with ``rows = ext.reshape(-1, D)``:
+    the shared accumulation of the shifted-row polyphase factorization
+    (_poly_decim_fir_stage / resample_stage / xlating_fir_stage), computed over
+    rows of width :func:`_row_width` ``R = g·D``. The identity holds for any such
+    R — ``Y[j] = Σ_{a=0..m_R} rows_R[j + m_R − a] @ A[a]`` with the band blocks
+    of :func:`_band_weights` — and R sets the matmul's shape. Where R > D the
+    ``m_R + 1`` shifted views stand side by side and the sum is ONE matmul of
+    contraction ``(m_R+1)·R`` (128 taps at D = 4: one ``[512, 256]·[256, 32]``
+    complex matmul per lane instead of 33 four-wide matvecs); ``ext`` is
+    zero-padded in front to ``m_R`` whole rows and behind to a whole last row.
+    One matmul and not a sum of ``m_R + 1``: on the v5e they time the same, and a
+    stage that ends in the matmul itself hands XLA:CPU no add to fuse into the
+    consumer (with it, ``mag2`` after the stage rounded differently fused and
+    alone: tests/test_devchain.py's bit-equality of fused and actor paths).
+
+    HIGHEST precision by default so no TPU bf16 passes sneak
     in. ``precision="bf16"`` (the interior-precision policy, ops/precision.py)
     casts REAL operands to bfloat16 with float32 accumulation — the native MXU
     pass on TPU, the identical quantization on CPU; complex operands (no bf16
@@ -1210,7 +1275,19 @@ def _shifted_matvec(ext: jnp.ndarray, W, m: int, nq: int,
     the lower hooks guard that) quantizes through :func:`_int8_shifted_matvec`,
     complex streams per re/im plane."""
     from functools import partial as _partial
-    D = W.shape[-2] if W.ndim == 3 else W.shape[-1]
+    D = W.shape[1]
+    R = _row_width(D)
+    if R != D:
+        g = R // D
+        mR, nqR = -(-m // g), -(-nq // g)
+        front = mR * R - m * D
+        rows = jnp.pad(ext, (front, (mR + nqR) * R - front - ext.shape[0])
+                       ).reshape(-1, R)
+        win = jnp.concatenate([rows[mR - a:mR - a + nqR] for a in range(mR + 1)],
+                              axis=1)
+        y = _shifted_matvec(win.reshape(-1), _band_weights(W, m, R), 0, nqR,
+                            precision)
+        return y.reshape((nqR * g,) + W.shape[2:])[:nq]
     rows = ext.reshape(-1, D)
     if precision == "int8" and not jnp.iscomplexobj(W):
         if jnp.iscomplexobj(rows):
@@ -1251,18 +1328,19 @@ def _poly_decim_weights(taps: np.ndarray, D: int, m: int) -> np.ndarray:
 def _poly_decim_fir_stage(taps: np.ndarray, decim: int, fft_len: int,
                           name: str, impl: str,
                           precision: Optional[str] = None) -> Stage:
-    """Decimating FIR as m+1 shifted matvecs over the stride-D row matrix.
+    """Decimating FIR by the shifted-row polyphase factorization.
 
     ``y[q] = Σ_t taps[t] · x[q·D − t]``. Decompose ``t = r·D − s``: with
     ``rows[j, s] = ext[j·D + s]`` (a RESHAPE of the input — no copy),
     ``y[q] = Σ_{r=0..m} rows[q+m−r] · W[r]`` where ``W[r, s] = taps[r·D − s]``.
-    Each term is a [n/D, D]·[D] matvec on a static slice of ``rows`` — ntaps/D
-    MACs per input with NO materialized window matrix. The previous einsum form
-    concatenated an (m+1)·D-wide window matrix first ((m+1)× the input in HBM
-    writes); dropping it is ~10× on the CPU backend for the FM channel filter
-    (128 taps, D=16) and strictly less HBM traffic on TPU (VERDICT r3 weak 2).
-    The weight matrix rides the carry, so it is donation-safe and hot-swappable
-    exactly like the OS path's frequency-domain ``Hc``.
+    That D-wide ``W`` is what the CARRY holds (donation-safe and hot-swappable
+    exactly like the OS path's frequency-domain ``Hc``); the accumulation itself
+    runs over rows of width ``R = g·D`` (:func:`_row_width`), for which the same
+    identity holds with band matrices built in the trace from ``W``
+    (:func:`_shifted_matvec`, :func:`_band_weights`): one matmul of contraction
+    ``(ceil(m/g) + 1)·R``, each row yielding g outputs (why not rows of D, and
+    the chip numbers: :func:`_row_width`).
+    ``Stage.route[0]`` names the row width that compiled (``"rows128"``).
 
     ``impl="pallas"`` routes REAL weight matrices through the fused
     FIR→decimate kernel (``pallas_kernels.pallas_poly_fir``): the same
@@ -1350,7 +1428,8 @@ def _poly_decim_fir_stage(taps: np.ndarray, decim: int, fft_len: int,
                  lower=_lower,
                  compute_dtype=(precision if precision in ("bf16", "int8")
                                 else "f32"),
-                 route=(impl, None, precision))
+                 route=("pallas" if impl == "pallas" else f"rows{_row_width(D)}",
+                        None, precision))
 
 
 def resample_stage(interp: int, decim: int, taps=None, fft_len: int = 8192,
@@ -1411,6 +1490,8 @@ def resample_stage(interp: int, decim: int, taps=None, fft_len: int = 8192,
     # previous einsum stacked I per-group window matrices — I·Kmax/D× the input
     # in HBM writes; 48 groups for the audio resampler). Cost stays T/D MACs per
     # input vs the zero-stuffed form's I× inflated FFT frames, with no scatter.
+    # D >= 64 (the FM audio resampler's 125) keeps these rows; a smaller D is
+    # re-blocked to rows of _row_width(D) inside _shifted_matvec.
     T = len(taps)
     Kmax = -(-T // I)                   # taps per phase
     ftaps = taps.astype(np.float32)
@@ -1603,10 +1684,14 @@ def xlating_fir_stage(taps, phase_inc: float, decim: int,
         y[q] = Σ_t h[t]·e^{jθ(qD−t)}·x[qD−t]
              = e^{jθDq} · Σ_t (h[t]e^{-jθt}) · x[qD−t]
 
-    so the filter runs with complex taps ``h[t]e^{-jθt}`` via the shifted-matvec
+    so the filter runs with complex taps ``h[t]e^{-jθt}`` via the shifted-row
     polyphase form (:func:`_poly_decim_fir_stage`), and only a residual rotator
     at the DECIMATED rate remains — D× fewer rotations than rotating the input
-    (VERDICT r3 weak-item 2: the FM front end's full-rate tuner pass).
+    (VERDICT r3 weak-item 2: the FM front end's full-rate tuner pass). The taps
+    are per listener, so under ``ServeEngine``'s ``vmap`` every lane builds its own
+    band matrix from its carried ``W`` (:func:`_band_weights`); for the FM tuner
+    (128 taps, D = 4, rows of 128) that is one ``[512, 256]·[256, 32]`` complex
+    matmul per lane and frame. ``Stage.route[0]`` names the row width.
 
     Retune keeps the exact rotator grammar: ``update(phase_inc=θ')`` rebuilds
     the carried weight matrix AND the residual increment in one carry swap (no
@@ -1684,7 +1769,8 @@ def xlating_fir_stage(taps, phase_inc: float, decim: int,
         W = _param_to_device(_weights(nbase, theta), dev)
         return (W, base, ph0, inc_d, th_hi, th_lo, hist)
 
-    return Stage(fn, init_carry, Fraction(1, D), None, D, name, update=update)
+    return Stage(fn, init_carry, Fraction(1, D), None, D, name, update=update,
+                 route=(f"rows{_row_width(D)}", None, None))
 
 
 def rotator_stage(phase_inc: float, name: str = "rotator",
