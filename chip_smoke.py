@@ -18,6 +18,10 @@ another XLA program, never by bit-identity):
   pallas    every kernel of ops/pallas_kernels.py compiled by Mosaic at the
             shapes the repo uses, matched against its XLA route; then the
             default-on channelizer_stage(impl="auto") through a flowgraph
+  wlan_rx   apps.wlan_rx.build_flowgraph(use_tpu=True): a seeded capture of 8
+            frames of 802.11a/g packets, all eight rates; payloads against
+            what was sent, record entries against models/wlan/reference.py
+            (numpy float64), and one frame's LLRs pulled back from the device
   multichip (only with --devices N > 1) the spectrum chain data-sharded over
             N devices and examples/sharded_spectrum.py, matched against the
             single-device run, every device holding a shard
@@ -818,6 +822,151 @@ def phase_pallas(ctx: Ctx) -> None:
 
 
 # ---------------------------------------------------------------------------
+# phase: wlan_rx
+# ---------------------------------------------------------------------------
+
+#: rehearsal sizes of the receiver (the chip runs the shipped defaults)
+_WLAN_SMALL = dict(frame_size=16384, carry_len=12288, max_psdu=400,
+                   cand_slots=32, lanes=16)
+#: LLRs of the device program against the float64 reference: float32 DFT,
+#: division and pilot phase read 1.1e-5 on the CPU (tests/test_wlan_rx_stages
+#: .py); the chip's own sine, cosine and reciprocal are allowed ten times the
+#: test's limit. The program is probed AS SHIPPED (its matmuls name their own
+#: precision); on the chip the same probe with the matmuls at the device's
+#: default is the control and has to miss the limit.
+_WLAN_LLR_TOL = 1.5e-3
+#: a record's mean |LLR| against the reference's, relative (the benchmark's
+#: limit, benchmark/configs/wlan_rx_20msps.json, has the readings)
+_WLAN_LLR_MEAN_RTOL = 3e-4
+
+
+def _wlan_capture(rng, n: int, lengths) -> tuple:
+    """Packets one after another, rates in turn, gaps of SIFS or DIFS +
+    backoff, CFO within +-100 kHz, SNR 12 dB + 3 dB per coded bit + U(0, 6)
+    over a fixed noise floor: ``(samples, [psdu])``."""
+    from futuresdr_tpu.models.wlan import MCS_TABLE, Mac, encode_frame
+    n0 = 1e-4
+    x = (rng.standard_normal(n) + 1j * rng.standard_normal(n)) * np.sqrt(n0 / 2)
+    mac, sent, pos = Mac(), [], 0
+    rates = list(MCS_TABLE)
+    while True:
+        pos += 320 if rng.random() < 0.35 else 680 + 180 * int(rng.integers(16))
+        rate = rates[len(sent) % 8]
+        lo, hi = lengths[int(rng.integers(len(lengths)))]
+        psdu = mac.frame(bytes(rng.integers(0, 256, int(rng.integers(lo, hi)) - 28,
+                                            dtype=np.uint8)))
+        s = encode_frame(psdu, rate, int(rng.integers(1, 128))).astype(np.complex128)
+        if pos + len(s) + 400 > n:
+            return x.astype(np.complex64), sent
+        snr_db = 12.0 + 3.0 * MCS_TABLE[rate].n_bpsc + rng.uniform(0, 6)
+        gain = np.sqrt(10 ** (snr_db / 10) * n0 / (52 / 4096))
+        cfo = 2 * np.pi * rng.uniform(-1e5, 1e5) / 20e6
+        x[pos:pos + len(s)] += gain * s * np.exp(
+            1j * (cfo * np.arange(len(s)) + rng.uniform(0, 2 * np.pi)))
+        sent.append(psdu)
+        pos += len(s)
+
+
+def phase_wlan_rx(ctx: Ctx) -> None:
+    import jax
+    import jax.numpy as jnp
+
+    from futuresdr_tpu import Runtime
+    from futuresdr_tpu.apps.wlan_rx import build_flowgraph
+    from futuresdr_tpu.blocks import VectorSource
+    from futuresdr_tpu.config import config
+    from futuresdr_tpu.models.wlan import MCS_TABLE, payload_from_mpdu
+    from futuresdr_tpu.models.wlan import reference as ref
+    from futuresdr_tpu.models.wlan import rx_stages
+
+    t0, mark = time.perf_counter(), ctx.meter.mark()
+    sizes = dict(_WLAN_SMALL) if ctx.rehearse else {}
+    frame = sizes.get("frame_size", config().tpu_frame_size)
+    carry = sizes.get("carry_len", ref.CARRY_LEN)
+    n_frames = 8
+    x, sent = _wlan_capture(
+        np.random.default_rng([ctx.seed, 26]), n_frames * frame,
+        [(28, 128), (129, 400)] if ctx.rehearse
+        else [(28, 128), (129, 600), (1000, 1534)])
+    fg, kernel, rx = build_flowgraph(VectorSource(x), use_tpu=True, **sizes)
+    Runtime().run(fg)
+    m = kernel.extra_metrics()
+    check(kernel.inst.platform == ctx.device.platform, "kernel on wrong platform")
+    check(m["frames_dispatched"] == n_frames and m["frame_size"] == frame,
+          f"dispatched {m['frames_dispatched']} frames of {m['frame_size']}")
+    check(rx.frames == [payload_from_mpdu(p) for p in sent],
+          f"{len(rx.frames)} payloads with a good FCS, {len(sent)} sent")
+    totals = rx.extra_metrics()
+    check(totals["overflow"] == 0 and totals["fcs_bad"] == 0, f"totals {totals}")
+
+    # record entries against the float64 reference, frame by frame
+    frames = x.reshape(n_frames, frame)
+    n_ref = n_frames if ctx.rehearse else 3
+    want = [p for j in range(n_ref) for p in ref.receive_frame(
+        frames[j], frames[j - 1] if j else None, carry)[0]]
+    cfo_err = snr_err = mean_err = 0.0
+    for got, w in zip(rx.packets, want):
+        check((got["lts_start"], got["rate"], got["length"], got["psdu"])
+              == (w.lts_start, w.rate, w.length, w.psdu),
+              f"entry at {got['lts_start']} differs from the reference's "
+              f"at {w.lts_start}")
+        cfo_err = max(cfo_err, abs(got["cfo"] - w.cfo))
+        snr_err = max(snr_err, abs(got["snr_db"] - w.snr_db))
+        mean_err = max(mean_err, abs(got["llr_mean"] - w.llr_mean) / w.llr_mean)
+    check(len(rx.packets) >= len(want) > 0, "fewer entries than the reference")
+    check(cfo_err <= 2e-6 and snr_err <= 0.05 and mean_err <= _WLAN_LLR_MEAN_RTOL,
+          f"CFO off by {cfo_err:.3g} rad/sample, LTS SNR by {snr_err:.3g} dB, "
+          f"mean |LLR| by {mean_err:.3g} of itself")
+
+    # one frame's LLRs pulled back from the device (frame 1 behind frame 0's
+    # carry) and held to the reference's: the program as shipped, then, on
+    # the chip, the control
+    hist = frames[0][-carry:]
+    ref_pkts, _ = ref.receive_window(np.concatenate([hist, frames[1]]), 0,
+                                     keep_trace=True)
+
+    def llr_error(stage) -> tuple:
+        _, _, taps = jax.jit(stage.fn.probe)(
+            jnp.stack([jnp.real(hist), jnp.imag(hist)]), jnp.asarray(frames[1]))
+        taps = {k: np.asarray(v) for k, v in taps.items()}
+        err, n = 0.0, 0
+        for w in ref_pkts[:12]:
+            lane = next((i for i in range(len(taps["slot"])) if taps["lane_ok"][i]
+                         and taps["lts"][taps["slot"][i]] == w.lts_start), None)
+            check(lane is not None, f"no lane for the packet at {w.lts_start}")
+            rows = slice(taps["lane_to"][lane] - len(w.trace["eq"]),
+                         taps["lane_to"][lane])
+            nb = MCS_TABLE[ref.RATES[w.rate]].n_bpsc
+            llr = taps["llr"][rows].reshape(-1, 6, 48)[:, :nb] \
+                .transpose(0, 2, 1).reshape(-1)
+            err = max(err, float(np.max(np.abs(llr - w.trace["llrs"]))))
+            n += len(llr)
+        return err, n
+
+    llr_err, n_llr = llr_error(kernel.pipeline.stages[0])
+    check(n_llr > 0 and llr_err <= _WLAN_LLR_TOL,
+          f"LLRs off by {llr_err:.3g} over {n_llr} values")
+    llr_err_default = None
+    if ctx.on_tpu:                  # the CPU's default precision is float32
+        rx_stages._PRECISION = None
+        try:
+            llr_err_default, _ = llr_error(rx_stages.wlan_rx_stages(
+                **{k: v for k, v in sizes.items() if k != "frame_size"})[0])
+        finally:
+            rx_stages._PRECISION = "highest"
+        check(llr_err_default > 3 * _WLAN_LLR_TOL,
+              f"the control (matmuls at the device's default precision) reads "
+              f"{llr_err_default:.3g}: the LLR check would not catch it")
+    ctx.emit("wlan_rx", mark, t0, samples=len(x), frame_size=frame,
+             wire=m["wire"], packets_sent=len(sent),
+             packets_emitted=totals["psdus"], entries_checked=len(want),
+             cfo_err_max=cfo_err, snr_err_max_db=snr_err,
+             llr_mean_err_max_rel=mean_err, llr_err_max=llr_err,
+             llr_err_default_precision=llr_err_default,
+             llr_tolerance=_WLAN_LLR_TOL, llrs_checked=n_llr)
+
+
+# ---------------------------------------------------------------------------
 # phase: multichip (only when asked: --devices N > 1)
 # ---------------------------------------------------------------------------
 
@@ -915,7 +1064,7 @@ def main(argv=None) -> int:
     ctx = Ctx(args)
     phases = {"streamed": phase_streamed, "fm_app": phase_fm_app,
               "serve": phase_serve, "pallas": phase_pallas,
-              "multichip": phase_multichip}
+              "wlan_rx": phase_wlan_rx, "multichip": phase_multichip}
     full = [p for p in phases if p != "multichip" or args.devices > 1]
     want = [p for p in args.phases.split(",") if p] or full
     skipped = [p for p in full if p not in want]

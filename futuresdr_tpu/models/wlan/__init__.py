@@ -10,11 +10,12 @@ from .consts import MCS_TABLE, Mcs
 from .phy import (encode_frame, decode_frame, decode_stream, decode_stream_batch,
                   DecodedFrame)
 from .mac import Mac, mpdu_from_payload, payload_from_mpdu
-from .blocks import WlanEncoder, WlanDecoder
+from .blocks import WlanEncoder, WlanDecoder, WlanRecords
 from .channels import channel_to_freq, freq_to_channel, parse_channel
 from . import coding, ofdm
 
 __all__ = ["MCS_TABLE", "Mcs", "encode_frame", "decode_frame", "decode_stream",
            "decode_stream_batch", "DecodedFrame", "Mac", "mpdu_from_payload",
-           "payload_from_mpdu", "WlanEncoder", "WlanDecoder", "coding", "ofdm",
+           "payload_from_mpdu", "WlanEncoder", "WlanDecoder", "WlanRecords", "coding",
+           "ofdm",
            "channel_to_freq", "freq_to_channel", "parse_channel"]

@@ -18,7 +18,7 @@ from ...types import Pmt
 from . import phy
 from .mac import Mac
 
-__all__ = ["WlanEncoder", "WlanDecoder"]
+__all__ = ["WlanEncoder", "WlanDecoder", "WlanRecords"]
 
 
 class WlanEncoder(Kernel):
@@ -125,4 +125,53 @@ class WlanDecoder(Kernel):
         self._seen_abs = {a for a in self._seen_abs if a >= self._tail_abs - self.OVERLAP}
         self.input.consume(n)
         if self.input.finished() and self.input.available() == 0:
+            io.finished = True
+
+
+class WlanRecords(Kernel):
+    """Record blocks of ``rx_stages`` (one per device frame, ``block_words``
+    int32 each) → payload messages on ``rx``: the host end of the on-device
+    receiver. Checks each PSDU's FCS (``mac.payload_from_mpdu``) and keeps
+    the totals it sees anyway as its metrics for the REST plane."""
+
+    def __init__(self, block_words: int, use_mac: bool = True):
+        super().__init__()
+        from .rx_stages import parse_records    # jax: not at package import
+        self._parse = parse_records
+        self.block_words = int(block_words)
+        self.mac = Mac() if use_mac else None
+        self.frames = []           # payloads (PSDUs without MAC), in order
+        self.packets = []          # every record entry parsed, FCS good or not
+        self.totals = {"frames": 0, "psdus": 0, "fcs_bad": 0, "overflow": 0}
+        self.input = self.add_stream_input("in", np.int32,
+                                           min_items=self.block_words)
+        self.add_message_output("rx")
+
+    def extra_metrics(self) -> dict:
+        return dict(self.totals)
+
+    async def work(self, io, mio, meta):
+        inp = self.input.slice()
+        n = len(inp) // self.block_words
+        for i in range(n):
+            head, packets = self._parse(
+                inp[i * self.block_words:(i + 1) * self.block_words])
+            self.totals["frames"] += 1
+            self.totals["overflow"] += head.get("wlan_overflow", 0)
+            for pkt in packets:
+                self.packets.append(pkt)
+                payload = self.mac.deframe(pkt["psdu"]) if self.mac \
+                    else pkt["psdu"]
+                if payload is None:
+                    self.totals["fcs_bad"] += 1
+                    continue
+                self.totals["psdus"] += 1
+                self.frames.append(payload)
+                mio.post("rx", Pmt.blob(payload))
+        if n:
+            self.input.consume(n * self.block_words)
+        if self.input.finished() and \
+                self.input.available() < self.block_words:
+            # what is left is the cut block of a last partial frame
+            self.input.consume(self.input.available())
             io.finished = True

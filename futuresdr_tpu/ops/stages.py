@@ -88,6 +88,11 @@ class Stage:
     #   routes compile different-cost programs), and
     #   ops/precision.pallas_stage_count resolves pallas routing from it
 
+    counters: Optional[Callable[[np.ndarray], dict]] = None
+    #   on a pipeline's LAST stage: a few integers read from one landed output
+    #   frame (a record header), which TpuKernel adds to the ``emit`` span's
+    #   args while the span recorder is on; never called when it is off
+
     def __repr__(self):
         return f"Stage({self.name}, ratio={self.ratio})"
 

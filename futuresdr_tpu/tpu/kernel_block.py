@@ -423,6 +423,9 @@ class TpuKernel(Kernel):
     #: TpuDagKernel narrows it (see its override).
     _donate = True
 
+    #: the last stage's ``counters`` of the group being emitted (tracing only)
+    _emit_args: Optional[dict] = None
+
     def __init__(self, stages: Sequence[Stage], in_dtype,
                  frame_size: Optional[int] = None,
                  inst: Optional[TpuInstance] = None,
@@ -1789,6 +1792,9 @@ class TpuKernel(Kernel):
         end = time.perf_counter_ns()
         if _trace.enabled:
             self._emitting = (seq, t_ins)    # closed by _emit's last item
+            counters = self.pipeline.stages[-1].counters
+            if counters is not None:     # what the program found in the frame
+                self._emit_args = counters(result)
         if self._e2e_hist is not None:
             # per-frame end-to-end latency: ring exit → decoded host result
             # (encode + H2D queue/wire + compute + D2H + decode; the doctor's
@@ -1823,9 +1829,11 @@ class TpuKernel(Kernel):
         rest = emit_with_tags(output, data, tags)
         n = len(data) - (0 if rest[0] is None else len(rest[0]))
         em = self._emitting
-        _trace.complete("tpu", "emit", t0,
-                        args={"seq": em[0] if em else None,
-                              "bytes": n * data.itemsize})
+        args = {"seq": em[0] if em else None, "bytes": n * data.itemsize}
+        if self._emit_args:
+            args.update(self._emit_args)
+            self._emit_args = None
+        _trace.complete("tpu", "emit", t0, args=args)
         return rest
 
     def _close_frames(self) -> None:

@@ -20,43 +20,97 @@ from futuresdr_tpu.models.wlan import encode_frame, decode_stream, decode_stream
 
 
 def run_device_resident(bucket: int, modulation: str, k_pair) -> tuple:
-    """The OFDM demod hot loop (CFO → batched FFT64 → equalize → CPE → max-log
-    demap, ``models/wlan/jax_demod.py``) carry-chained over HBM-resident symbol
-    frames, scan-marginal methodology (BASELINE target #4; reference hot loop:
-    ``examples/wlan/src/bin/loopback.rs:60-95`` / ``perf/wlan/rx.rs``)."""
+    """The whole on-device receiver (``models/wlan/rx_stages.py``: detect →
+    align → SIGNAL → demod → Viterbi → records) through ``Pipeline``,
+    carry-chained over an HBM-resident frame of ``bucket`` symbols' worth of
+    air holding packets of ``modulation``, scan-marginal methodology
+    (BASELINE target #4; reference: ``perf/wlan/rx.rs``)."""
     import jax
-    from futuresdr_tpu.models.wlan.consts import PILOT_POLARITY, SYM_LEN
-    from futuresdr_tpu.models.wlan.jax_demod import _compiled
+    from futuresdr_tpu.models.wlan.consts import MCS_TABLE, SYM_LEN
+    from futuresdr_tpu.models.wlan.rx_stages import wlan_rx_stages
+    from futuresdr_tpu.ops.stages import Pipeline
     from futuresdr_tpu.ops.xfer import to_device
     from futuresdr_tpu.utils.measure import run_marginal_retry, scaled_k_pair
 
-    run, consts = _compiled(modulation, bucket)  # noqa: SLF001 — perf probes the hot loop directly
-    rng = np.random.default_rng(21)
     frame = bucket * SYM_LEN
-    # scan-window scaling (utils/measure.scaled_k_pair): scan windows of tens
-    # of ms sit inside per-dispatch jitter; the shared floor conditions the
-    # marginal on every backend
+    mcs = next(n for n, m in MCS_TABLE.items() if m.modulation == modulation)
+    rng = np.random.default_rng(21)
+    mac, parts, n = Mac(), [], 0
+    while True:                                 # 200-byte packets at SIFS
+        burst = encode_frame(
+            mac.frame(bytes(rng.integers(0, 256, 172, dtype=np.uint8))), mcs)
+        if n + len(burst) + 320 > frame:
+            break
+        parts += [burst, np.zeros(320, np.complex64)]
+        n += len(burst) + 320
+    host = np.concatenate(parts + [np.zeros(frame - n, np.complex64)])
+    host = (host + 1e-3 * (rng.standard_normal(frame)
+                           + 1j * rng.standard_normal(frame))).astype(np.complex64)
+    pipe = Pipeline(wlan_rx_stages(carry_len=8192, max_psdu=256, cand_slots=32,
+                                   lanes=16), np.complex64)
     k_pair = scaled_k_pair(k_pair, frame, jax.default_backend())
-    host = (rng.standard_normal(frame)
-            + 1j * rng.standard_normal(frame)).astype(np.complex64)
-    H = (rng.standard_normal(64) + 1j * rng.standard_normal(64)).astype(np.complex64)
-    H[np.abs(H) < 0.3] = 1.0                      # keep the equalizer well-conditioned
-    pol = PILOT_POLARITY[np.arange(bucket) % len(PILOT_POLARITY)].astype(np.float32)
-    mask = np.ones(bucket, np.float32)
-    dH, dpol, dmask = to_device(H), to_device(pol), to_device(mask)
-    dconsts = tuple(to_device(np.asarray(c)) for c in consts)
-    cfo, ph0 = np.float32(1e-4), np.float32(0.0)
-
-    # dH rides in the scan CARRY, not the closure: a device array captured as a
-    # jit closure constant forces a host readback at MLIR-embedding time.
-    # Arguments and carries never take that path.
-    def step(carry, body):
-        return carry, run(body, carry, dpol, dmask, cfo, ph0, *dconsts)
-
-    carry0 = dH
-    x = to_device(host)
-    rate = run_marginal_retry(step, carry0, x, k_pair) / 1e6
+    rate = run_marginal_retry(pipe.fn(), pipe.init_carry(), to_device(host),
+                              k_pair) / 1e6
     return rate, frame
+
+
+def run_viterbi_core(unrolls=(1, 4, 8, 16, 32), lanes: int = 128,
+                     max_psdu: int = 4095, live: int = 45,
+                     longest: int = 12294) -> None:
+    """Stage A/B of the Viterbi alone at the lane and step counts of the
+    benchmark's ``wlan_rx_sat`` cell: ``live`` of ``lanes`` lanes hold
+    packets, the longest ``longest`` steps. Uncut (``viterbi_core``, a packet
+    a lane) per ``UNROLL``: microseconds per step of the longest packet,
+    forward pass and traceback together; then cut into blocks
+    (``viterbi_blocks``, piece slots as the receiver sizes them) per ``BLOCK``
+    and ``CHUNK``: microseconds per step of the passes' serial length. The
+    module's constants are patched for the sweep and put back."""
+    import jax
+    import jax.numpy as jnp
+    from futuresdr_tpu.models.wlan import coding
+    from futuresdr_tpu.ops import viterbi
+
+    tables = (coding._PREV_S, coding._PREV_B, coding._BM0, coding._BM1)
+    T = 16 + 8 * max_psdu + 6
+    rng = np.random.default_rng(3)
+    steps = np.zeros(lanes, np.int32)
+    steps[:live] = rng.integers(246, longest, live)
+    steps[0] = longest
+    llr = jnp.asarray(rng.standard_normal((T, 2, lanes)).astype(np.float32))
+
+    def median_ms(run, *args) -> float:
+        run(*args).block_until_ready()
+        times = []
+        for _ in range(5):
+            t0 = time.perf_counter()
+            run(*args).block_until_ready()
+            times.append(time.perf_counter() - t0)
+        return sorted(times)[2] * 1e3
+
+    shipped = (viterbi.UNROLL, viterbi.BLOCK, viterbi.CHUNK)
+    print("backend,form,unroll,lanes,serial_steps,ms_per_call,us_per_step")
+    try:
+        for u in unrolls:
+            viterbi.UNROLL = u
+            ms = median_ms(jax.jit(lambda a, b: viterbi.viterbi_core(a, b, *tables)),
+                           llr, jnp.asarray(steps))
+            print(f"{jax.default_backend()},uncut,{u},{lanes},{longest},{ms:.3f},"
+                  f"{ms * 1e3 / longest:.3f}", flush=True)
+        viterbi.UNROLL = shipped[0]
+        stream = jnp.transpose(llr, (1, 2, 0))                     # [2, L, T]
+        for block, chunk in ((1024, 1024), (1024, 512), (1024, 128),
+                             (512, 1024), (2048, 1024)):
+            viterbi.BLOCK, viterbi.CHUNK = block, chunk
+            slots = viterbi.piece_slots(-(-4664 * 216 // block) + lanes)
+            ms = median_ms(jax.jit(lambda a, b: viterbi.viterbi_blocks(
+                a, b, *tables, n_blocks=slots)), stream, jnp.asarray(steps))
+            pieces = int(np.sum(-(-steps // block)))
+            serial = -(-pieces // chunk) * (block + 2 * viterbi.OVERLAP)
+            print(f"{jax.default_backend()},blocks_{block}_x{chunk},{shipped[0]},"
+                  f"{pieces}_of_{slots},{serial},{ms:.3f},{ms * 1e3 / serial:.3f}",
+                  flush=True)
+    finally:
+        viterbi.UNROLL, viterbi.BLOCK, viterbi.CHUNK = shipped
 
 
 def main():
@@ -69,10 +123,16 @@ def main():
     p.add_argument("--batch", action="store_true",
                    help="batched Viterbi (one lax.scan for all frames)")
     p.add_argument("--device-resident", action="store_true",
-                   help="scan-marginal OFDM demod hot loop on the device")
+                   help="scan-marginal rate of the on-device receiver")
     p.add_argument("--bucket", type=int, default=1024,
                    help="symbols per device frame (device-resident mode)")
+    p.add_argument("--viterbi-core", action="store_true",
+                   help="time ops/viterbi.viterbi_core and viterbi_blocks alone")
     a = p.parse_args()
+
+    if a.viterbi_core:
+        run_viterbi_core()
+        return
 
     if a.device_resident:
         from futuresdr_tpu.tpu.instance import instance
