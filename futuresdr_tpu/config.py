@@ -36,7 +36,7 @@ class Config:
     # Telemetry (telemetry/spans.py): span recording off by default — the
     # metrics registry (telemetry/prom.py) is always on, spans are opt-in.
     trace: bool = False                    # FUTURESDR_TPU_TRACE=1 records spans
-    trace_ring: int = 1 << 16              # per-thread span ring capacity
+    trace_ring: int = 1 << 18              # per-thread span ring capacity
     # Flowgraph doctor (telemetry/doctor.py): the watchdog thread is opt-in;
     # the latency histograms it reads are always on (metrics-plane contract).
     doctor: bool = False                   # FUTURESDR_TPU_DOCTOR=1 starts the

@@ -36,7 +36,7 @@ __all__ = ["to_device", "to_host", "start_host_transfer", "start_device_transfer
            "start_device_transfer_parts", "start_host_transfer_parts",
            "split_complex_platform", "set_fake_link", "fake_link",
            "TransferError", "FakeLinkFault", "classify_transfer_error",
-           "PackedLayout", "watch", "H2DGroup"]
+           "PackedLayout", "watch", "H2DGroup", "wire_part", "join_parts"]
 
 log = logger("ops.xfer")
 _trace = _trace_recorder()
@@ -324,25 +324,28 @@ def _d2h_observe(t0: int, service: float, deadline: float, nbytes: int,
 
 class H2DGroup:
     """ONE ``h2d_put`` + ``H2D`` span pair for several transfer starts (a
-    serving dispatch group's four puts): pass it as ``group=`` to each start,
-    then :meth:`close` once they have all returned. Create only under
-    ``if _trace.enabled``. Under a fake link each start keeps its own
+    serving dispatch group's lane groups and its three small vectors): create
+    it right before the first start, pass it as ``group=`` to each, then
+    :meth:`close` once they have all returned; ``bytes`` is what the starts
+    really put on the link, ``extra`` rides both spans' args. Create only
+    under ``if _trace.enabled``. Under a fake link each start keeps its own
     modelled ``H2D`` window and the group records only ``h2d_put``."""
 
-    __slots__ = ("seq", "t0_ns", "arrays", "nbytes")
+    __slots__ = ("seq", "t0_ns", "arrays", "nbytes", "extra")
 
-    def __init__(self, seq=None):
+    def __init__(self, seq=None, **extra):
         self.seq = seq
         self.t0_ns = time.perf_counter_ns()
         self.arrays: list = []
         self.nbytes = 0
+        self.extra = extra
 
     def add(self, arrays, nbytes: int) -> None:
         self.arrays.extend(arrays)
         self.nbytes += nbytes
 
     def close(self) -> None:
-        args = _span_args(self.nbytes, self.seq)
+        args = {**_span_args(self.nbytes, self.seq), **self.extra}
         _trace.complete("tpu", "h2d_put", self.t0_ns, args=args)
         if self.arrays:
             watch(self.arrays, "H2D", self.t0_ns, args, lane="h2d")
@@ -361,6 +364,49 @@ def _jits():
         _join_jit = jax.jit(lambda p: jax.lax.complex(p[..., 0], p[..., 1]))
         _split_jit = jax.jit(lambda x: (x.real, x.imag))
     return _join_jit, _split_jit
+
+
+_join_parts_jits: dict = {}
+
+
+def _ships_pairs(dtype, device=None) -> bool:
+    """Does an array of ``dtype`` cross to ``device`` as float32 pairs?"""
+    return np.issubdtype(np.dtype(dtype), np.complexfloating) and \
+        split_complex_platform(_device_platform(device))
+
+
+def wire_part(arr: np.ndarray, device=None) -> np.ndarray:
+    """One host array as it crosses the link, for
+    :func:`start_device_transfer_parts`: a complex array's float32 pairs (a
+    zero-copy view) where pairs are shipped, else the array itself. The
+    device side of several such parts is :func:`join_parts`."""
+    if _ships_pairs(arr.dtype, device):
+        from .wire import _pairs_view
+        return _pairs_view(arr)
+    return arr
+
+
+def join_parts(parts, dtype, device=None):
+    """The device array whose leading axis is cut into ``parts`` (device
+    arrays put as :func:`wire_part` of ``dtype`` arrays): ONE jitted program
+    concatenates them and forms the complex values from the pairs — the
+    several-part form of :func:`start_device_transfer`'s join. Which parts
+    were just uploaded and which were resident changes no shape, so it
+    compiles once per part count and shape."""
+    pairs = _ships_pairs(dtype, device)
+    if len(parts) == 1 and not pairs:
+        return parts[0]
+    join = _join_parts_jits.get(pairs)
+    if join is None:
+        import jax
+        import jax.numpy as jnp
+
+        def join(ps):
+            x = ps[0] if len(ps) == 1 else jnp.concatenate(ps, axis=0)
+            return jax.lax.complex(x[..., 0], x[..., 1]) if pairs else x
+
+        join = _join_parts_jits[pairs] = jax.jit(join)
+    return join(tuple(parts))
 
 
 class _FakeLink:
@@ -708,11 +754,10 @@ def start_device_transfer(arr, device=None, seq=None, group=None):
         x = jax.device_put(arr, device) if device is not None else arr
         return lambda: x
     a = np.asarray(arr)
-    if np.issubdtype(a.dtype, np.complexfloating) and \
-            split_complex_platform(_device_platform(device)):
-        from .wire import _pairs_view
-        pairs = _pairs_view(a)   # the ONE copy of the regression-locked trick
-        put = start_device_transfer_parts((pairs,), device, seq, group)
+    if _ships_pairs(a.dtype, device):
+        # wire_part: the ONE copy of the regression-locked pairs-view trick
+        put = start_device_transfer_parts((wire_part(a, device),), device,
+                                          seq, group)
         join, _ = _jits()
 
         def finish():

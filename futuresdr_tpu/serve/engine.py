@@ -26,8 +26,9 @@ with a leading session axis:
   join lands at its own frame cursor MID-megabatch as a page-map edit, a
   leave parks the page, and eviction reads one page, never a restack;
 * an OVERLAPPED step: the dispatch group launched at step t rides async
-  ``start_device_transfer`` H2D and ``start_host_transfer`` D2H finishes,
-  governed by the streamed path's
+  H2D starts (its input in LANE GROUPS, each filled and put on the wire
+  while the next is filled; a group in which no lane rides is not shipped)
+  and ``start_host_transfer`` D2H finishes, governed by the streamed path's
   :class:`~futuresdr_tpu.tpu.kernel_block.CreditController`, so
   H2D(t+1) ∥ compute(t) ∥ D2H(t−1) holds for serving exactly as for the
   streamed kernel — committed carries advance ONLY after a group's D2H
@@ -49,7 +50,8 @@ with a leading session axis:
   that slot — siblings keep their lanes and their bit-exact outputs.
 
 Masking semantics: inactive lanes still ride through the vmapped program
-(their input rows are zeros), but their computed carries are DISCARDED by a
+(their input rows are zeros, or whatever their lane group's staging array
+last held), but their computed carries are DISCARDED by a
 ``where(active, new, old)`` merge inside the jitted program — a stalled
 lane's filter history and oscillator phase are bit-frozen until its next
 real frame, and an active lane's carry is exactly what the standalone
@@ -104,6 +106,11 @@ _DISPATCHES = _prom.counter(
     "fsdr_serve_dispatches_total",
     "batched serving dispatches (one per step with >= 1 active lane)",
     ("app",))
+_LANES_SHIPPED = _prom.counter(
+    "fsdr_serve_lanes_shipped_total",
+    "input lanes uploaded by serving dispatches (lane groups shipped x "
+    "lanes per group; the riding lanes are fsdr_serve_frames_total)",
+    ("app",))
 _RETIRED = _prom.counter(
     "fsdr_serve_retired_total",
     "sessions retired by a per-session fault (slot-isolated)",
@@ -131,6 +138,15 @@ _RESUMED = _prom.counter(
     "fsdr_serve_resumed_total",
     "sessions re-admitted from durable snapshots by a fresh incarnation",
     ("app", "tenant"))
+
+
+#: lanes per upload group of a step's input (docs/serving.md "The overlapped
+#: step"): small enough that a group's bytes cross while the next is filled and
+#: that a few riding lanes ship little else, large enough that the starts do
+#: not outweigh the bytes. Chosen on the chip among 1, 4, 8, 16, 32 and 64 at
+#: 64 lanes of 524 KB (PERF.md section 6, PR 27): 16 serves a full bucket
+#: fastest, 8 is 9 % slower there and a tenth quicker for a few riders
+LANE_GROUP = 16
 
 
 def default_buckets() -> tuple:
@@ -262,18 +278,18 @@ class _DispatchGroup:
     finishes. Committed oldest-first; a failed drain rolls the whole chain
     back (every younger group derived its pages from this one's output)."""
 
-    __slots__ = ("capacity", "k", "lanes", "n_frames", "batch", "active",
+    __slots__ = ("capacity", "k", "lanes", "n_frames", "active",
                  "fresh", "page_map", "fresh_lanes", "step_tids", "t_step",
-                 "seq", "new_pages", "fins", "wire")
+                 "seq", "new_pages", "fins", "wire", "staging",
+                 "lanes_shipped", "groups_shipped")
 
-    def __init__(self, capacity: int, k: int, lanes: list, batch, active,
+    def __init__(self, capacity: int, k: int, lanes: list, active,
                  fresh, page_map, fresh_lanes: frozenset, step_tids: list,
                  t_step: int, seq: int):
         self.capacity = capacity
         self.k = k
         self.lanes = lanes            # (session, lane, popped, tids) tuples
         self.n_frames = sum(len(p) for _s, _l, p, _t in lanes)
-        self.batch = batch
         self.active = active
         self.fresh = fresh
         self.page_map = page_map
@@ -284,6 +300,9 @@ class _DispatchGroup:
         self.new_pages = None         # set by launch
         self.fins = None              # pending D2H finishes, one per sink
         self.wire = None              # H2D (service, deadline) wire window
+        self.staging = None           # the host staging set it shipped from
+        self.lanes_shipped = 0        # set by launch: what crossed the link
+        self.groups_shipped = 0
 
 
 class ServeEngine:
@@ -414,6 +433,17 @@ class ServeEngine:
         self.steps = 0                    # step() calls (incl. idle)
         self.dispatches = 0               # steps that launched the program
         self.frames = 0                   # session-frames dispatched
+        self.lanes_shipped = 0            # input lanes uploaded (committed)
+        self.groups_shipped = 0           # lane groups uploaded (committed)
+        #: host staging sets not in use, per (capacity, k): one array per
+        #: lane group (made on the group's first ride, then reused). A
+        #: launched group HOLDS its set until it commits or rolls back: the
+        #: link reads a staging array after ``device_put`` returns, so it may
+        #: be rewritten only once its outputs are on the host
+        self._staging: Dict[tuple, list] = {}
+        #: device-resident zero input of one lane group per (lanes, k): what
+        #: a group in which no lane rides passes to the join
+        self._zero_parts: Dict[tuple, object] = {}
         self._gauge_cache: Dict[tuple, object] = {}
         # profile plane (telemetry/profile.py): capacities whose first
         # dispatch (the real jit compile — build_slot_program only wraps)
@@ -641,6 +671,7 @@ class ServeEngine:
             self._pages = jax.device_put(self._pages,
                                          self._slot_sharding)
         self._head_pages = self._pages
+        self._staging.clear()             # the smaller bucket never comes back
         self.table.grow(cap)
         self.credits.set_total(self._queue_frames * cap)
         log.info("%s: page pool grew %d -> %d (active %d)", self.app, cur,
@@ -860,8 +891,9 @@ class ServeEngine:
 
     def step(self) -> int:
         """One frame-time dispatch: every active lane with pending frames
-        rides ONE vmapped program call — one H2D of the stacked batch, one
-        dispatch, one D2H per sink, regardless of the active session count.
+        rides ONE vmapped program call — the input uploaded in lane groups
+        (only those in which a lane rides), one dispatch, one D2H per sink,
+        regardless of the active session count.
         ``frames_per_dispatch > 1`` additionally megabatches up to k queued
         frames PER LANE through the in-program scan, ragged per lane (a
         session with fewer queued frames masks its tail; a JOINING session
@@ -872,8 +904,9 @@ class ServeEngine:
         launched here is committed only once its D2H lands; with
         ``serve_inflight > 1`` up to that many groups ride concurrently,
         so H2D(t+1) ∥ compute(t) ∥ D2H(t−1). The state lock is held only
-        for batch assembly and commit bookkeeping — never across the
-        compile, the transfers, or the program call — so /metrics,
+        to pop the riding frames and for commit bookkeeping — never across
+        the copies, the compile, the transfers, or the program call — so
+        ``submit()``, /metrics,
         ``health()`` and ``describe()`` answer mid-step.
 
         Returns the number of session-frames LAUNCHED this step. An idle
@@ -938,37 +971,28 @@ class ServeEngine:
 
     def _assemble(self) -> Optional[_DispatchGroup]:
         """Build this step's dispatch group under the state lock: pop up to
-        K pending frames per occupied lane into the stacked batch, snapshot
-        the lane→page permutation and the fresh-lane vector, and CLEAR the
-        fresh bits — the launch materializes those lanes' template pages
-        (rollback restores the bits). Returns None on an idle step."""
+        K pending frames per occupied lane (references: the copies are the
+        launch's, outside this lock), snapshot the lane→page permutation
+        and the fresh-lane vector, and CLEAR the fresh bits — the launch
+        materializes those lanes' template pages (rollback restores the
+        bits). Returns None on an idle step."""
         t_lk = _trace.now() if _trace.enabled else 0
         with self._lock:
             C = self.table.capacity
             K = self._k_eff
             fplan = _faults.plan()
             lanes: List[tuple] = []   # (session, lane, popped, tids)
-            # serving-plane spans (docs/serving.md "Observability"): the
-            # batch assembly is the serving path's encode lane; the H2D/D2H
-            # lanes are emitted by the async transfer finishes themselves
-            # (ops/xfer.py), so the doctor's interval-union lanes show the
-            # REAL wire concurrency of the overlapped step
             t_step = _trace.now() if t_lk else 0
-            t_enc = t_step
             # idle frame-time ticks (no lane has pending input — the common
             # case for a pump loop ticking at frame rate) must cost nothing:
-            # the batch/mask arrays allocate lazily on the first busy lane
-            batch = None
+            # the mask allocates lazily on the first busy lane
             active = None
             step_tids: List[int] = []     # lineage-sampled frames this step
             for s in self.table.occupants():
                 if not s.pending:
                     s.stall_steps += 1
                     continue
-                if batch is None:
-                    shape = (C, self.frame_size) if K == 1 \
-                        else (C, K, self.frame_size)
-                    batch = np.zeros(shape, dtype=self.pipeline.in_dtype)
+                if active is None:
                     active = np.zeros((C,) if K == 1 else (C, K), dtype=bool)
                 if fplan.armed():
                     # per-session fault sites (runtime/faults.py): address a
@@ -984,13 +1008,11 @@ class ServeEngine:
                 tids = []
                 for j in range(min(K, len(s.pending))):
                     entry = s.pending.popleft()
-                    frame, t_sub = entry
+                    t_sub = entry[1]
                     self.credits.release(s.tenant)
                     if K == 1:
-                        batch[s.slot] = frame
                         active[s.slot] = True
                     else:
-                        batch[s.slot, j] = frame
                         active[s.slot, j] = True
                     popped.append(entry)
                     # frame lineage (telemetry/lineage.py): 1-in-stride
@@ -1006,15 +1028,12 @@ class ServeEngine:
             self.steps += 1
             if not lanes:
                 return None
-            if t_enc:
+            if t_step:
                 # submit() and the REST handlers hold `_lock` too, and
                 # t_step started only once it was held
                 _trace.complete("serve", "lock_wait", t_lk, end_ns=t_step,
                                 args={"lock": "state", "seq": self.steps})
                 t_pop = _trace.now()
-                _trace.complete("tpu", "encode", t_enc, end_ns=t_pop,
-                                args={"sessions": len(lanes),
-                                      "capacity": C, "seq": self.steps})
                 # how long this step's frames sat in `pending`: from the
                 # oldest one's submit stamp to the pop, and the mean over all
                 subs = [t for _s, _l, popped, _t in lanes for _f, t in popped]
@@ -1022,10 +1041,6 @@ class ServeEngine:
                     "serve", "queue_wait", min(subs), end_ns=t_pop,
                     args={"seq": self.steps, "frames": len(subs),
                           "mean_ms": (t_pop - sum(subs) / len(subs)) * 1e-6})
-            if step_tids:
-                lin = _lineage.tracer()
-                for tid in step_tids:
-                    lin.stamp(tid, "encode")
             # the fresh vector covers EVERY fresh lane, busy or not: its
             # first ride writes the template to its page either way, so
             # the page is real from this group on
@@ -1034,7 +1049,7 @@ class ServeEngine:
                 if lane < C:
                     fresh[lane] = True
             g = _DispatchGroup(
-                C, K, lanes, batch, active, fresh,
+                C, K, lanes, active, fresh,
                 np.asarray(self.table.page_of_lane, dtype=np.int32),
                 frozenset(self._fresh_lanes), step_tids, t_step, self.steps)
             self._fresh_lanes.clear()
@@ -1042,27 +1057,31 @@ class ServeEngine:
 
     def _launch(self, g: _DispatchGroup) -> None:
         """Launch one assembled group OUTSIDE the state lock (step lock
-        held): program lookup/compile, async H2D starts, the paged program
-        call against the speculative head, async D2H starts. Advancing the
-        head is the LAST effect — a failure anywhere above leaves the
-        chain exactly as it was for the rollback path."""
+        held): program lookup/compile, the input's lane groups filled and
+        put on the wire one after the other, the paged program call against
+        the speculative head, async D2H starts. Advancing the head is the
+        LAST effect — a failure anywhere above leaves the chain exactly as
+        it was for the rollback path."""
         C, K = g.capacity, g.k
         prog = self._program(C, K)
-        # one h2d_put + H2D span pair for the group's four puts
-        grp = xfer.H2DGroup(g.seq) if _trace.enabled else None
-        fx = self._start_h2d(g.batch, True, grp)
+        # one encode span and one h2d_put + H2D span pair for the step; the
+        # two overlap: a group's bytes cross while the next group is filled
+        if self._shard_ok(C):
+            finish_x, grp = self._start_whole_batch(g)
+        else:
+            finish_x, grp = self._start_lane_groups(g)
         fa = self._start_h2d(g.active, True, grp)
         fm = self._start_h2d(g.page_map, False, grp)
         ff = self._start_h2d(g.fresh, False, grp)
         if grp is not None:
             grp.close()
-        x, act = fx(), fa()
+        lin = _lineage.tracer() if g.step_tids else None
+        for tid in g.step_tids:
+            lin.stamp(tid, "encode")
+        x, act = finish_x(), fa()
         pmap, fresh = fm(), ff()
-        g.wire = getattr(fx, "_wire", None)
-        if g.step_tids:
-            lin = _lineage.tracer()
-            for tid in g.step_tids:
-                lin.stamp(tid, "H2D")
+        for tid in g.step_tids:
+            lin.stamp(tid, "H2D")
         t0 = _trace.now() if _trace.enabled else 0
         key = (C, K, self._pipe_tag)
         if key in self._warmed:
@@ -1086,13 +1105,123 @@ class ServeEngine:
                     "seq": g.seq}
             _trace.complete("tpu", "compute", t0, args=args)
             xfer.watch(outs, "program", t0, args)
-        if g.step_tids:
-            lin = _lineage.tracer()
-            for tid in g.step_tids:
-                lin.stamp(tid, "dispatch")
+        for tid in g.step_tids:
+            lin.stamp(tid, "dispatch")
         g.fins = [xfer.start_host_transfer(o, seq=g.seq) for o in outs]
         g.new_pages = new_pages
         self._head_pages = new_pages
+
+    def _lane_group(self, capacity: int) -> int:
+        """Lanes per upload group of a bucket: ``LANE_GROUP`` where it cuts
+        a larger capacity evenly, else the whole bucket as one group."""
+        if capacity > LANE_GROUP and capacity % LANE_GROUP == 0:
+            return LANE_GROUP
+        return capacity
+
+    def _group_shape(self, lanes: int, k: int) -> tuple:
+        return (lanes, self.frame_size) if k == 1 \
+            else (lanes, k, self.frame_size)
+
+    def _zero_part(self, lanes: int, k: int):
+        """The device-resident all-zero input of one lane group, uploaded on
+        the bucket's first launch or warm-up and passed for every group in
+        which no lane rides."""
+        z = self._zero_parts.get((lanes, k))
+        if z is None:
+            dev = self.inst.device
+            zeros = np.zeros(self._group_shape(lanes, k),
+                             dtype=self.pipeline.in_dtype)
+            (z,) = xfer.start_device_transfer_parts(
+                (xfer.wire_part(zeros, dev),), dev)()
+            self._zero_parts[(lanes, k)] = z
+        return z
+
+    @staticmethod
+    def _fill_group(buf: np.ndarray, riders: list, base: int) -> None:
+        """Copy the riding frames of lanes ``base..`` into their rows of
+        ``buf``. Rows of lanes that do not ride keep what they held: the
+        ``active`` mask freezes their carries and nobody is handed their
+        output (``build_slot_program``)."""
+        for lane, popped in riders:
+            if buf.ndim == 2:
+                buf[lane - base] = popped[0][0]
+            else:
+                for j, (frame, _t) in enumerate(popped):
+                    buf[lane - base, j] = frame
+
+    def _start_lane_groups(self, g: _DispatchGroup) -> tuple:
+        """Fill and ship the step's input by lane groups: for each group
+        with a riding lane, copy its frames into the group's staging array
+        and start its ``device_put`` at once, so its bytes cross while the
+        next group is filled. Returns ``(finish_x, span group)``;
+        ``finish_x()`` joins the uploaded parts (and the resident zero block
+        for every group that did not ride) into the program's input."""
+        C, K = g.capacity, g.k
+        G = self._lane_group(C)
+        n = C // G
+        dev = self.inst.device
+        dtype = self.pipeline.in_dtype
+        riders: Dict[int, list] = {}
+        for _s, lane, popped, _tids in g.lanes:
+            riders.setdefault(lane // G, []).append((lane, popped))
+        g.groups_shipped, g.lanes_shipped = len(riders), len(riders) * G
+        shipped = {"lanes_shipped": g.lanes_shipped,
+                   "groups_shipped": g.groups_shipped}
+        zero = self._zero_part(G, K) if n > 1 else None
+        free = self._staging.setdefault((C, K), [])
+        g.staging = staging = free.pop() if free else [None] * n
+        tracing = _trace.enabled
+        grp = None
+        t_enc = t_filled = _trace.now() if tracing else 0
+        fins: list = [None] * n
+        for gi in sorted(riders):
+            buf = staging[gi]
+            if buf is None:
+                buf = staging[gi] = np.zeros(self._group_shape(G, K), dtype)
+            self._fill_group(buf, riders[gi], gi * G)
+            if tracing:
+                t_filled = _trace.now()
+                if grp is None:         # the H2D pair opens at the first put
+                    grp = xfer.H2DGroup(g.seq, **shipped)
+            fins[gi] = xfer.start_device_transfer_parts(
+                (xfer.wire_part(buf, dev),), dev, group=grp)
+        if tracing:
+            _trace.complete("tpu", "encode", t_enc, end_ns=t_filled,
+                            args={"sessions": len(g.lanes), "capacity": C,
+                                  "seq": g.seq, **shipped})
+        wires = [f._wire for f in fins if f is not None]
+        g.wire = (wires[0][0], wires[-1][1])
+
+        def finish_x():
+            return xfer.join_parts(
+                [zero if f is None else f()[0] for f in fins], dtype, dev)
+
+        return finish_x, grp
+
+    def _start_whole_batch(self, g: _DispatchGroup) -> tuple:
+        """A slot-sharded bucket's input: one zeroed batch, one
+        ``device_put`` against the mesh sharding, which owns that layout.
+        Returns what :meth:`_start_lane_groups` returns."""
+        C = g.capacity
+        g.groups_shipped, g.lanes_shipped = 1, C
+        t_enc = _trace.now() if _trace.enabled else 0
+        batch = np.zeros(self._group_shape(C, g.k),
+                         dtype=self.pipeline.in_dtype)
+        self._fill_group(batch, [(l, p) for _s, l, p, _t in g.lanes], 0)
+        grp = None
+        if t_enc:
+            shipped = {"lanes_shipped": C, "groups_shipped": 1}
+            _trace.complete("tpu", "encode", t_enc,
+                            args={"sessions": len(g.lanes), "capacity": C,
+                                  "seq": g.seq, **shipped})
+            grp = xfer.H2DGroup(g.seq, **shipped)
+        return self._start_h2d(batch, True, grp), grp
+
+    def _release_staging(self, g: _DispatchGroup) -> None:
+        """Hand a committed or rolled-back group's staging set back."""
+        if g.staging is not None:
+            self._staging.setdefault((g.capacity, g.k), []).append(g.staging)
+            g.staging = None
 
     def _start_h2d(self, arr: np.ndarray, shard: bool, group=None):
         """Start one async H2D for a group launch; returns a finish thunk.
@@ -1154,6 +1283,7 @@ class ServeEngine:
                     s.pending.extendleft(reversed(popped))   # credits were
                     self.credits.reacquire(s.tenant, len(popped))  # released
                 self._fresh_lanes |= g.fresh_lanes
+                self._release_staging(g)
             if reset_head:
                 self._head_pages = self._pages
 
@@ -1173,7 +1303,10 @@ class ServeEngine:
                 _trace.complete("serve", "lock_wait", t_lk, end_ns=t_dec,
                                 args={"lock": "state", "seq": g.seq})
             self._pages = g.new_pages
+            self._release_staging(g)
             self.dispatches += 1
+            self.lanes_shipped += g.lanes_shipped
+            self.groups_shipped += g.groups_shipped
             dispatched = 0
             for s, lane, popped, tids in g.lanes:
                 deliver = s.state == "active" and s.slot == lane
@@ -1204,6 +1337,7 @@ class ServeEngine:
                     dispatched += 1
             self.frames += dispatched
             _DISPATCHES.inc(app=self.app)
+            _LANES_SHIPPED.inc(g.lanes_shipped, app=self.app)
             self._step_stamps.append(time.monotonic())
             if self._persist_every and self._store is not None:
                 self._steps_since_persist += 1
@@ -1437,8 +1571,6 @@ class ServeEngine:
         if key in self._warmed:
             return
         prog = self._program(C, K)
-        shape = (C, self.frame_size) if K == 1 else (C, K, self.frame_size)
-        batch = np.zeros(shape, dtype=self.pipeline.in_dtype)
         active = np.zeros((C,) if K == 1 else (C, K), dtype=bool)
         pmap = np.asarray(self.table.page_of_lane, dtype=np.int32)
         no_fresh = np.zeros((C,), dtype=bool)
@@ -1446,14 +1578,23 @@ class ServeEngine:
                                 f"cap={C},k={K},frame={self.frame_size},"
                                 f"pipe={self._pipe_tag},warm=restore"):
             # _start_h2d, not bare to_device: a slot-sharded bucket's
-            # pages are committed to the mesh, and a single-device batch
+            # pages are committed to the mesh, and single-device vectors
             # would make the warm dispatch raise (and the first real step
             # pay a second, unbilled compile)
+            if self._shard_ok(C):
+                x = self._start_h2d(np.zeros(self._group_shape(C, K),
+                                             dtype=self.pipeline.in_dtype),
+                                    shard=True)()
+            else:
+                # the input as a launch forms it, with no lane riding:
+                # warms the lane groups' join and uploads the zero block
+                G = self._lane_group(C)
+                x = xfer.join_parts([self._zero_part(G, K)] * (C // G),
+                                    self.pipeline.in_dtype, self.inst.device)
             _new_p, outs = prog(self._pages,
                                 self._start_h2d(pmap, shard=False)(),
                                 self._start_h2d(no_fresh, shard=False)(),
-                                self._start_h2d(batch, shard=True)(),
-                                self._start_h2d(active, shard=True)())
+                                x, self._start_h2d(active, shard=True)())
             jax.block_until_ready(outs)
         self._warmed.add(key)
 
@@ -1819,6 +1960,15 @@ class ServeEngine:
                 "steps": self.steps,
                 "dispatches": self.dispatches,
                 "frames": self.frames,
+                # what crossed the link for them: lane groups in which a
+                # lane rode, and their lanes over every dispatch's capacity
+                # (frames / (dispatches x capacity) is the riding share)
+                "groups_shipped": self.groups_shipped,
+                "lanes_shipped": self.lanes_shipped,
+                "shipped_lane_share": (
+                    self.lanes_shipped
+                    / (self.dispatches * self.table.capacity)
+                    if self.dispatches else None),
                 "credit_total": self.credits.total,
                 "credit_fair_share": self.credits.fair_share(),
                 "draining": self._draining,

@@ -130,7 +130,7 @@ class SpanRecorder:
             from ..config import config
             c = config()
             capacity = capacity if capacity is not None \
-                else int(c.get("trace_ring", 1 << 16))
+                else int(c.get("trace_ring", 1 << 18))
             enabled = enabled if enabled is not None \
                 else bool(c.get("trace", False))
         self.capacity = max(16, int(capacity))

@@ -9,8 +9,8 @@ A/B of the SAME receiver chain serving N concurrent sessions two ways:
   overhead — a deliberately generous baseline: the real actor path also
   pays per-block supervision);
 * **serve** — the ``futuresdr_tpu/serve`` engine: all sessions ride ONE
-  vmapped dispatch per frame time (one stacked H2D, one program call, one
-  D2H per sink), with ragged admission masking the idle lanes.
+  vmapped dispatch per frame time (the input in lane groups, one program
+  call, one D2H per sink), with ragged admission masking the idle lanes.
 
 At a matched per-session throughput target T, sessions/chip = aggregate
 session-frames-per-second / T — so the serve:independent ratio of aggregate
