@@ -1,6 +1,6 @@
 # Developer conveniences; see check.sh for the full health check.
 
-.PHONY: test native tsan check bench perf clean
+.PHONY: test native tsan check perf clean
 
 test:
 	python -m pytest tests/ -q
@@ -13,9 +13,6 @@ tsan:
 
 check:
 	bash check.sh
-
-bench:
-	python bench.py
 
 perf:
 	python perf/fir.py --runs 1

@@ -23,7 +23,7 @@ echo "== device-graph fusion gate (docs/tpu_notes.md 'Device-graph fusion') =="
 # fused A/B smoke: the linear pass engages (dispatches drop 3x -> 1x per
 # frame), the fan-out pass engages (1->2 broadcast region: H2D bytes bill
 # exactly ONE upload per marginal frame via fsdr_xfer_bytes_total, one
-# multi-output dispatch per frame, replayed-link throughput win), AND the
+# multi-output dispatch per frame), AND the
 # general-DAG pass engages (diamond broadcast->merge + nested fan-out:
 # dispatches/frame == 1 with interior-edge D2H bytes == 0 — the fused side's
 # marginal D2H equals exactly the sink payloads)
@@ -36,68 +36,19 @@ FSDR_NO_DEVCHAIN=1 JAX_PLATFORMS=cpu python -m pytest -q \
     tests/test_tpu_frames.py tests/test_retune.py
 
 echo "== host data path gate (docs/tpu_notes.md 'The host data path') =="
-# deterministic fake-link replay: the staging arena's steady-state allocation
-# count is O(1) per frame class (misses flat over a sustained window) and the
-# streamed utilization with arena + codec pool + credit controller armed is
-# no worse than the pre-arena baseline
-JAX_PLATFORMS=cpu python perf/hostpath_ab.py --smoke
+# the staging arena's steady-state allocation count is O(1) per frame class
+# (misses flat over a sustained window, the packed sc16 class included), a
+# codec worker count below 1 is a configuration error, the credit
+# controller's hysteresis
+JAX_PLATFORMS=cpu python -m pytest -q tests/test_arena.py
 
 echo "== single-shot uplink gate (docs/tpu_notes.md 'The single-shot uplink') =="
 # coalesced H2D: a quantizing-wire streamed chain bills exactly ONE physical
 # h2d start per dispatch group (payload + scale ride one packed buffer) and
-# stays bit-identical to the per-part path; zero-copy ingest: a registered
-# read-only capture over the aliasing (f32) wire skips every ring-exit copy
-# (frac == 1.0). The dedicated suite behind it carries the rest (packed
-# replay/fault bit-equality, deferred consume, adaptive wire switching,
-# autotune wire axis).
-JAX_PLATFORMS=cpu python - <<'EOF'
-import numpy as np
-from futuresdr_tpu import Mocker
-from futuresdr_tpu.config import config
-from futuresdr_tpu.ops import fir_stage, rotator_stage
-from futuresdr_tpu.ops import ingest, xfer
-from futuresdr_tpu.tpu import TpuKernel
-
-FS = 2048
-rng = np.random.default_rng(7)
-n = FS * 8
-data = (rng.standard_normal(n) + 1j * rng.standard_normal(n)) \
-    .astype(np.complex64)
-taps = rng.standard_normal(33).astype(np.float32)
-
-def run(wire, coalesce=True, register=False):
-    config().tpu_coalesce = coalesce
-    if register:
-        ingest.register(data, name="gate-capture")
-    tk = TpuKernel([fir_stage(taps, fft_len=256), rotator_stage(0.05)],
-                   np.complex64, frame_size=FS, frames_in_flight=2,
-                   wire=wire)
-    m = Mocker(tk)
-    m.input("in", data)
-    m.init_output("out", n * 2)
-    m.init()                   # compile + cost-model probes bill separately
-    s0 = xfer._XFER_STARTS.get(direction="h2d")
-    m.run()
-    starts = xfer._XFER_STARTS.get(direction="h2d") - s0
-    out = m.output("out").copy()
-    em = tk.extra_metrics()
-    ingest.reset()
-    config().tpu_coalesce = True
-    return out, starts, em
-
-groups = 8
-a, sa, ema = run("sc16", coalesce=True)
-b, sb, emb = run("sc16", coalesce=False)
-np.testing.assert_array_equal(a, b)
-assert ema["uplink_coalesced"] == 1 and ema["h2d_starts_per_frame"] == 1, ema
-assert sa == groups, f"packed chain billed {sa} h2d starts / {groups} groups"
-assert sb == 2 * groups, sb
-_, _, emc = run("f32", register=True)
-assert emc["ingest_zero_copy_frac"] == 1.0, emc
-print(f"uplink gate: {sa} h2d starts / {groups} groups packed (vs {sb} "
-      f"per-part, bit-identical), ingest zero-copy frac "
-      f"{emc['ingest_zero_copy_frac']}: OK")
-EOF
+# stays bit-identical to the per-part form a single-part wire takes;
+# zero-copy ingest: a registered read-only capture over the aliasing (f32)
+# wire skips every ring-exit copy (frac == 1.0); packed replay/fault
+# bit-equality, deferred consume, adaptive wire switching, autotune wire axis
 JAX_PLATFORMS=cpu python -m pytest -q tests/test_uplink.py
 
 echo "== interior precision gate (docs/tpu_notes.md 'Interior precision') =="
@@ -179,27 +130,22 @@ echo "== multi-tenant serving gate (docs/serving.md) =="
 # N sessions of one receiver chain through a single vmapped dispatch per
 # frame: dispatches/frame == 1 regardless of the active session count,
 # session join/leave under load causes ZERO recompiles of resident slot
-# buckets, the sessions/chip ratio vs independent per-session dispatch
-# loops clears the smoke floor, a simulated crash-restart with durable
-# persistence resumes 100% of sessions bit-identically
-# (serve_restart_resume_frac == 1.0), and an admission storm sheds
-# newcomers while residents keep delivering (serve_shed_p99_ms stamped)
+# buckets, a simulated crash-restart with durable persistence resumes 100%
+# of sessions bit-identically to the uncrashed engine, and an admission
+# storm sheds newcomers while residents keep delivering
 JAX_PLATFORMS=cpu python perf/serve_ab.py --smoke
 
 echo "== serve churn gate (docs/serving.md 'Paged session carries') =="
 # the paged-engine acceptance regime: join/leave EVERY step for 100 events
 # at N=64, K in {1,4} — ZERO recompiles of the resident capacity (the page
-# table absorbs all churn as host map edits) and churn p99 within 1.5x the
-# no-churn p99 at the same capacity
+# table absorbs all churn as host map edits), one dispatch per step
 JAX_PLATFORMS=cpu python perf/serve_ab.py --churn --smoke
 
 echo "== mesh-sharded device plane gate (docs/parallel.md) =="
 # the data-sharded fused program on the virtual 8-device mesh: bit-identical
 # per shard to the D=1 program at matched K, ONE dispatch per group (the
 # per-shard dispatch count never multiplies with D), ZERO cross-shard
-# collectives in the compiled HLO (interior edges never leave their shard),
-# and the D=8 scaling fraction vs the independent-per-device-loop linear
-# reference clears the floor (multichip_scaling_frac stamped, regress-graded)
+# collectives in the compiled HLO (interior edges never leave their shard)
 JAX_PLATFORMS=cpu python perf/multichip_ab.py --smoke
 
 echo "== fleet observability gate (docs/observability.md 'The fleet plane') =="
@@ -301,12 +247,6 @@ assert seqs == [e["seq"] for e in full] == sorted(seqs), \
 print(f"lineage smoke: {len(recs)} records, {chained} flow chain(s), "
       f"slowest lane {sl}, journal drained {len(seqs)} events: OK")
 EOF
-
-echo "== perf-regression gate (non-fatal; perf/regress.py vs BENCH_r*.json) =="
-# quick reduced bench on the CPU backend, graded against the committed
-# trajectory with a generous tolerance — warnings only, never fails the check
-JAX_PLATFORMS=cpu python perf/regress.py --run --quick || \
-    echo "WARNING: perf-regression gate could not be graded (non-fatal)"
 
 echo "== python suite =="
 python -m pytest tests/ -q

@@ -97,8 +97,10 @@ class SeifySource(Kernel):
         self.device.driver.deactivate()
 
     async def work(self, io, mio, meta):
-        out = self.outputs[0].slice()
-        n = min((len(o.slice()) for o in self.outputs), default=0)
+        # one slice() per output, taken once: a reader consuming on another
+        # thread grows the next slice(), so `n` must come from the views written
+        outs = [o.slice() for o in self.outputs]
+        n = min((len(v) for v in outs), default=0)
         if n == 0:
             return
         data = self.device.driver.read(n)   # blocking; we're on a dedicated thread
@@ -107,13 +109,9 @@ class SeifySource(Kernel):
             return
         k = len(data)
         if k:
-            if self.n_channels == 1:
-                out[:k] = data
-                self.outputs[0].produce(k)
-            else:
-                for o in self.outputs:
-                    o.slice()[:k] = data
-                    o.produce(k)
+            for o, v in zip(self.outputs, outs):
+                v[:k] = data
+                o.produce(k)
         io.call_again = True
 
 

@@ -130,36 +130,16 @@ class Config:
     # arena (ops/arena.py — recycled host buffers for wire-encode outputs,
     # H2D staging parts and megabatch pads) and the codec worker pool
     # (ops/codec_pool.py — host encode/decode off the drain thread).
-    host_arena: bool = True                # FUTURESDR_TPU_HOST_ARENA=0 falls
-    #   back to per-frame allocation (the A/B baseline mode)
     host_arena_mb: int = 256               # arena pool byte cap: past it a
     #   released buffer is dropped to the allocator instead of pooled
     host_codec_workers: int = 2            # codec threads per lane (encode /
-    #   decode); 0 = inline synchronous codec (the pre-pool path)
+    #   decode), at least 1
     tpu_inflight: int = 0                  # in-flight credit budget of the
     #   streamed drain loop: 0 = auto — an adaptive, hysteretic credit
     #   controller (tpu/kernel_block.py CreditController) seeds from the
     #   autotune_streamed pick (or tpu_frames_in_flight) and adjusts at
     #   runtime from link idle/backpressure signals; N>0 pins the budget
     #   (as does an explicit per-kernel frames_in_flight argument)
-    # Uplink optimization plane (docs/tpu_notes.md "The host data path"):
-    # coalesced H2D transfers, zero-copy ingest and deferred-consume staging.
-    tpu_coalesce: bool = True              # pack a dispatch group's wire
-    #   parts (quantizing wires ship payload + scale; megabatch K-stacks)
-    #   into ONE contiguous arena-backed buffer shipped as a single
-    #   device_put, unpacked by a slicing prolog fused into the wired
-    #   program (ops/xfer.PackedLayout) — h2d starts per dispatch group
-    #   drop from len(parts) to 1. 0 = per-part transfers (A/B baseline)
-    tpu_zero_copy_ingest: bool = True      # let frames backed by a
-    #   REGISTERED externally-owned read-only buffer (ops/ingest.py) skip
-    #   the ring-exit staging copy on aliasing wires: the buffer is pinned
-    #   by refcount until drain + checkpoint coverage instead of copied
-    tpu_deferred_consume: bool = True      # quantizing wires (sc16/sc8,
-    #   K=1) with the codec pool armed: defer the ring consume() until the
-    #   worker-side encode has read the ring slot IN PLACE — quantized
-    #   formats gain the encode-offload overlap without the ring-exit copy
-    #   offloading would otherwise force (only the int payload lands in
-    #   the arena). 0 = inline encode before consume (the pre-uplink path)
     tpu_adaptive_wire: bool = False        # mid-stream adaptive wire
     #   switching (tpu/kernel_block.py WireController): a hysteretic
     #   controller reads the measured stream SNR of the active quantized

@@ -29,8 +29,7 @@ Telemetry (always on, docs/observability.md): ``fsdr_arena_hits_total`` /
 allocations), ``fsdr_arena_pinned_bytes`` / ``fsdr_arena_pooled_bytes``
 gauges, and a ``doctor.report()["arena"]`` snapshot.
 
-Config: ``host_arena`` (default on; ``FUTURESDR_TPU_HOST_ARENA=0`` disables —
-every caller falls back to plain allocation), ``host_arena_mb`` byte cap.
+Config: ``host_arena_mb``, the pool's byte cap.
 """
 
 from __future__ import annotations
@@ -192,38 +191,31 @@ class StagingArena:
 
 _arena: Optional[StagingArena] = None
 _arena_lock = threading.Lock()
-_arena_disabled = False
 
 
-def arena() -> Optional[StagingArena]:
-    """The process-global arena, or None when ``host_arena`` is off (every
-    caller must fall back to plain allocation — the A/B baseline mode)."""
-    global _arena, _arena_disabled
-    if _arena is None and not _arena_disabled:
+def arena() -> StagingArena:
+    """The process-global arena, sized by ``host_arena_mb`` on first use."""
+    global _arena
+    if _arena is None:
         with _arena_lock:
-            if _arena is None and not _arena_disabled:
+            if _arena is None:
                 from ..config import config
-                c = config()
-                if not bool(c.get("host_arena", True)):
-                    _arena_disabled = True
-                    return None
                 _arena = StagingArena(
-                    int(c.get("host_arena_mb", 256)) << 20)
+                    int(config().get("host_arena_mb", 256)) << 20)
     return _arena
 
 
 def reset_arena() -> None:
     """Drop the process arena (tests / config re-reads); the next
     :func:`arena` call re-resolves config."""
-    global _arena, _arena_disabled
+    global _arena
     with _arena_lock:
         _arena = None
-        _arena_disabled = False
 
 
 def arena_stats() -> Optional[dict]:
-    """Snapshot for ``doctor.report()`` (None when the arena is off or was
-    never used)."""
+    """Snapshot for ``doctor.report()`` (None when the arena was never
+    used)."""
     a = _arena
     return a.stats() if a is not None else None
 

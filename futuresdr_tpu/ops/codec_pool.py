@@ -22,8 +22,8 @@ worker thread's own ring — the doctor's interval-union lanes therefore stay
 honest, and ``doctor.report()["host_codec_overlap_frac"]`` measures how much
 of the wall the codec lanes actually covered.
 
-Config: ``host_codec_workers`` (default 2 per lane; 0 disables the pool —
-every caller falls back to the inline synchronous path, the A/B baseline).
+Config: ``host_codec_workers``, the thread count of each lane (default 2;
+below 1 is a configuration error).
 """
 
 from __future__ import annotations
@@ -61,22 +61,21 @@ class CodecPool:
 
 
 _pool: Optional[CodecPool] = None
-_pool_disabled = False
 _pool_lock = threading.Lock()
 
 
-def pool() -> Optional[CodecPool]:
-    """The process-global pool, or None when ``host_codec_workers`` is 0
-    (callers run the codec inline — today's synchronous path)."""
-    global _pool, _pool_disabled
-    if _pool is None and not _pool_disabled:
+def pool() -> CodecPool:
+    """The process-global pool, ``host_codec_workers`` threads per lane."""
+    global _pool
+    if _pool is None:
         with _pool_lock:
-            if _pool is None and not _pool_disabled:
+            if _pool is None:
                 from ..config import config
                 n = int(config().get("host_codec_workers", 2))
-                if n <= 0:
-                    _pool_disabled = True
-                    return None
+                if n < 1:
+                    raise ValueError(
+                        f"host_codec_workers must be at least 1 (the codec "
+                        f"threads per lane), got {n}")
                 _pool = CodecPool(n)
                 log.info("codec pool: %d encode + %d decode worker(s)", n, n)
     return _pool
@@ -84,9 +83,8 @@ def pool() -> Optional[CodecPool]:
 
 def reset_pool() -> None:
     """Shut down and drop the process pool (tests / config re-reads)."""
-    global _pool, _pool_disabled
+    global _pool
     with _pool_lock:
         if _pool is not None:
             _pool.shutdown()
         _pool = None
-        _pool_disabled = False

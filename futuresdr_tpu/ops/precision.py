@@ -1,9 +1,10 @@
 """Interior precision policy: SNR-budgeted auto-lowering of the fused device plane.
 
-The resident chains are HBM-bound (docs/tpu_notes.md roofline table: the
-fir64+fft2048 chain runs at ~5.6% MFU with every hot stage under the ridge
-point), and bf16 alone nearly doubles on-chip throughput (BENCH_TPU_r5: 3967
-vs 2087 Msps). The boundary wire already has a quantified-loss story —
+The resident chains are memory-bound by the operations and bytes their
+shapes need (``benchmark/harness/costs.py``; ``program_roofline`` in
+``PERF_LEDGER.jsonl``); what a lower interior precision buys on the chip is
+not measured by any cell yet (ROADMAP queue 1 item 3). The boundary wire
+already has a quantified-loss story —
 ``ops/wire.py`` measures each codec's SNR and ``pick_wire`` refuses formats
 under a floor. This module extends that machinery INWARD: interior DAG edges
 and stage accumulation lower to bf16 (int8 where a stage declares support)
@@ -148,7 +149,7 @@ class PrecisionPlan:
     @property
     def min_snr_db(self) -> Optional[float]:
         """The worst MEASURED SNR among accepted lowerings — the pinned floor
-        the bench stamps as ``interior_snr_db_min``. None when nothing
+        the planner is held to. None when nothing
         lowered or every measurement was exact (inf)."""
         vals = []
         for e in self.edges:
@@ -518,7 +519,7 @@ def dominant_compute_dtype(pipeline) -> str:
 
 def pallas_stage_count(pipeline) -> int:
     """How many stages of ``pipeline`` route through a hand-written Pallas
-    kernel (the ``pallas_kernels_active`` bench stamp), mirroring each
+    kernel, mirroring each
     stage's actual trace-time dispatch from its ``Stage.route`` — a forced
     ``impl="pallas"`` counts on every backend (the kernel genuinely runs,
     interpret mode off-TPU); ``"auto"`` counts only where the policy picks

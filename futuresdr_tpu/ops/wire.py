@@ -20,8 +20,8 @@ and back, on both ends:
 
 Part layouts are SYMMETRIC in both directions, so a host-side
 ``encode_host → decode_host`` round trip measures exactly the quantization the
-link applies (see :func:`measure_snr_db` — bench.py stamps the measured, not
-nominal, SNR).
+link applies (see :func:`measure_snr_db`: ``chip_smoke.py`` holds the sc16
+streamed phase to the measured, not nominal, SNR).
 
 Formats:
 
@@ -455,8 +455,8 @@ def measure_snr_db(wire, dtype=np.complex64, n: int = 8192,
     """MEASURED codec SNR in dB: a host encode→decode round trip over a
     unit-power Gaussian frame (part layouts are direction-symmetric, so this is
     exactly the quantization one link crossing applies). ``inf`` for exact
-    formats — bench.py stamps this next to the throughput so the artifact
-    carries the actual rate/fidelity tradeoff, not the nominal one."""
+    formats. ``pick_wire`` refuses a format whose measured SNR is under its
+    floor; the ``fsdr_wire_snr_db`` gauge exports it."""
     wire = get_wire(wire)
     rng = np.random.default_rng(seed)
     dt = np.dtype(dtype)
@@ -481,7 +481,7 @@ def streamed_ceiling_msps(wire, h2d_Bps: float, d2h_Bps: float,
     """Link-bounded streamed ceiling for one wire format, in Msamples/s:
     ``min(h2d / up_bytes, d2h / (down_bytes · out_per_in))``. The duplex
     directions overlap when frames are in flight, so the binding one is the
-    slower, not the sum (bench.py's ``streamed_link_ceiling_msps`` rule)."""
+    slower, not the sum."""
     w = get_wire(wire)
     up = w.bytes_per_sample(in_dtype)
     down = w.bytes_per_sample(out_dtype) * max(out_per_in, 1e-12)

@@ -283,8 +283,9 @@ class ControlPort:
         404 for unknown flowgraphs to match the /api/fg/ family; the ring is
         process-global, so any live fg id drains the same recorder. The drain
         is a DESTRUCTIVE read — a poller that must not steal events from
-        another trace consumer (e.g. ``bench.py --trace``) passes ``?keep=1``
-        for a non-draining snapshot instead."""
+        another trace consumer (a second client's ``GET …/trace/``, the
+        benchmark's own drain) passes ``?keep=1`` for a non-draining snapshot
+        instead."""
         from aiohttp import web
 
         from ..telemetry import spans
@@ -299,7 +300,7 @@ class ControlPort:
         """Explicit flight-recorder trigger + bottleneck attribution (the
         operator's "why is this flowgraph stuck" endpoint). Uses the
         NON-destructive span snapshot so a concurrent trace consumer
-        (``bench.py --trace``, ``GET …/trace/``) keeps its events; 404s for
+        (``benchmark/drivers``, ``GET …/trace/``) keeps its events; 404s for
         unknown flowgraphs to match the ``/api/fg/`` family (the doctor is
         process-global, like the trace ring)."""
         import json as _json

@@ -35,8 +35,7 @@ cooperating pieces, all hanging off one process-global :class:`Doctor`:
 * **Bottleneck attribution** — :meth:`Doctor.report` over drained trace
   events: interval-union busy fraction per streamed-pipeline lane
   (encode/H2D/compute/D2H/decode) and per block work lane; the busiest device
-  lane is the rate limiter (``bottleneck_lane``, the ``bench.py --doctor``
-  stamp).
+  lane is the rate limiter (``bottleneck_lane`` of ``GET …/doctor/``).
 
 This module deliberately imports nothing from ``runtime/`` at module level:
 the runtime imports *us* (block event loop, supervisor, control port), and the
@@ -884,8 +883,8 @@ class Doctor:
         # host codec lanes (encode ∪ decode) against the wall: with the codec
         # worker pool armed (ops/codec_pool.py) these spans land in worker
         # threads, so this fraction is how much of the run the host codec
-        # genuinely overlapped under the wire/compute lanes — bench.py stamps
-        # it as `host_codec_overlap_frac`
+        # genuinely overlapped under the wire/compute lanes
+        # (`host_codec_overlap_frac` of the report)
         codec_iv = lane_iv.get("encode", []) + lane_iv.get("decode", [])
         codec_frac = (spans.union_ns(codec_iv) / wall) if wall else 0.0
         # staging-arena occupancy snapshot (ops/arena.py): hit/miss totals and

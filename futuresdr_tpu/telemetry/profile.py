@@ -101,8 +101,7 @@ class _Program:
     this hook's (the gate conservatively bills the hook at work-call
     rate). A refresher-advanced edge was tried instead and rejected: it
     dilutes ``mfu_avg`` by however long the plane sat unread after the run
-    (on a bench without an armed doctor, the whole post-run section
-    sweep). It is deliberately LOCK-FREE: every program entry has exactly
+    (in a process without an armed doctor, everything it does after the run). It is deliberately LOCK-FREE: every program entry has exactly
     one writer (the owning kernel's drain thread / the serving engine's
     step caller under its own engine lock), and the gauge refresher only
     READS the counters — a read racing a write costs at most one unit of
@@ -355,8 +354,8 @@ class ProfilePlane:
             # `units` counter survives — it is the monotonic /metrics-style
             # figure): mfu_avg must never multiply an old incarnation's
             # units by the new incarnation's cost when the program changed
-            # (bench's in-process frame probes collide on per-flowgraph
-            # instance names with different frame sizes). No dispatch can
+            # (two kernels built in one process under the same
+            # per-flowgraph instance name at different frame sizes). No dispatch can
             # race this reset — registration happens inside the owning
             # kernel's init, with the previous incarnation's drain quiesced.
             with p._lock:
@@ -461,7 +460,7 @@ class ProfilePlane:
                 if p.mfu is not None:
                     entry["mfu"] = round(p.mfu, 6)
                     entry["hbm_util"] = round(p.hbm_util, 6)
-                # run-average over first..last dispatch (the bench stamp):
+                # run-average over first..last dispatch:
                 # robust to idle tails the windowed gauge would decay
                 # through. The FIRST dispatch's units mark the interval's
                 # left edge and don't count toward it — units/(t1-t0) would
@@ -481,8 +480,7 @@ class ProfilePlane:
                 "programs": out}
 
     def snapshot(self, ensure_costs: bool = False) -> dict:
-        """The full profile view (the REST ``/api/fg/{fg}/profile/`` body
-        and the bench stamp source). ``ensure_costs`` materializes lazy cost
+        """The full profile view (the REST ``/api/fg/{fg}/profile/`` body). ``ensure_costs`` materializes lazy cost
         thunks first (may compile once per signature — never pass it from a
         scrape path)."""
         if ensure_costs:

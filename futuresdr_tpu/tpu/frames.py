@@ -177,10 +177,7 @@ class TpuH2D(Kernel):
             if self.wire.encode_may_alias(frame.dtype):
                 # async H2D must leave the ring before consume(); quantizing
                 # wires materialize fresh arrays in encode_host already
-                if self._arena is not None:
-                    frame, handle = self._arena.copy_in(frame)
-                else:
-                    frame = frame.copy()
+                frame, handle = self._arena.copy_in(frame)
             self._stage(frame, self.frame_size, tags, handle)
             self.input.consume(self.frame_size)
             inp = self.input.slice()

@@ -590,16 +590,6 @@ class TpuKernel(Kernel):
                              "autotune_streamed pick",
                              type(self).__name__, self.depth)
         self._credits = CreditController(self.depth, adaptive=adaptive)
-        # the pool offloads the ENCODE only when the wire's host encode
-        # ALIASES its input (f32 pairs view): those frames pay the ring-exit
-        # staging copy regardless, so shipping the copy to a worker is free.
-        # Quantizing wires encode inline BEFORE consume() — zero extra copy,
-        # the contract the synchronous path always had — and still get the
-        # pooled D2H-landing/decode lane. (Offloading their encode would
-        # force a ring-exit copy the sync path never paid; measured a net
-        # loss at small frames on the CPU backend's fake link.)
-        self._encode_offload = self._codec_pool is not None and \
-            self.wire.encode_may_alias(self.pipeline.in_dtype)
         # H2D staging read-ahead BEYOND the in-flight budget: at steady state
         # the in-flight deque is full, so without extra headroom a frame would
         # be staged and launched in the same work cycle — its wire time would
@@ -607,44 +597,40 @@ class TpuKernel(Kernel):
         # it (depth=1 keeps 0: strictly serial semantics for A/B baselines)
         self.stage_ahead = 1 if self.depth > 1 else 0
         # ---- the single-shot uplink plane (docs/tpu_notes.md) --------------
-        # transfer coalescing: multi-part wires (quantizers shipping
-        # payload+scale) pack a dispatch group into ONE contiguous buffer,
-        # unpacked by a device-side slicing prolog fused into the program
-        # (ops/xfer.PackedLayout / ops/stages.packed_wired_fn). Single-part
-        # wires stay on the per-part path: they already cost one H2D start,
-        # and packing would add a copy of the f32 pairs view for nothing.
-        self._resolve_packed()
-        # zero-copy ingest: registered externally-owned read-only buffers
-        # (ops/ingest.py) skip the ring-exit staging copy on aliasing wires
-        self._ingest_enabled = bool(config().get("tpu_zero_copy_ingest",
-                                                 True)) and \
-            self.wire.encode_may_alias(self.pipeline.in_dtype)
+        self._resolve_uplink()
         self._ingest_frames = 0
         self._staged_frames = 0
-        # deferred-consume staging: quantizing K=1 pool encodes read the ring
-        # slot IN PLACE (consume() deferred until the worker's encode has
-        # read it), so only the int payload lands in the arena — the staging
-        # copy the quant path would otherwise need to offload its encode
-        self._deferred_consume = self._codec_pool is not None and \
-            not self.wire.encode_may_alias(self.pipeline.in_dtype) and \
-            self.k_batch == 1 and \
-            bool(config().get("tpu_deferred_consume", True))
         self._consume_event = None     # armed per staged frame (see _stage_*)
         self._pending_consume = None   # (event, n_items, seq) awaiting consume()
         # mid-stream adaptive wire switching (off by default: the wire is
         # part of the numerics contract) — controller lives in _init_wirectl
         self._init_wirectl()
 
-    def _resolve_packed(self) -> None:
-        """(Re-)derive the uplink coalescing layout for the CURRENT
-        wire/frame/K signature (``ops/xfer.PackedLayout.probe`` — None for
-        single-part wires, where coalescing is moot, and when
-        ``tpu_coalesce`` is off). Called at construction and again by every
-        wire switch; any probe failure falls back to the per-part path."""
-        from ..config import config
+    def _resolve_uplink(self) -> None:
+        """(Re-)derive how a frame leaves the ring and crosses the link from
+        the CURRENT ``(wire, in_dtype, k_batch)``. Called at construction
+        and again by every wire switch.
+
+        * ``_packed``: the coalescing layout (``ops/xfer.PackedLayout.probe``).
+          Multi-part wires (quantizers shipping payload + scale) pack a
+          dispatch group into ONE contiguous buffer, unpacked by a slicing
+          prolog fused into the program (``ops/stages.packed_wired_fn``).
+          None for single-part wires: they already cost one H2D start, and
+          packing would add a copy of the f32 pairs view for nothing. A
+          probe failure falls back to the per-part path.
+        * ``_encode_offload``: the wire's host encode ALIASES its input (f32
+          pairs view), so the frame pays the ring-exit staging copy
+          regardless and shipping encode + H2D start to a codec worker is
+          free.
+        * ``_ingest_enabled``: on such a wire a frame backed by a REGISTERED
+          externally-owned read-only buffer (``ops/ingest.py``) skips that
+          copy.
+        * ``_deferred_consume``: a quantizing wire at K=1 has the worker's
+          encode read the ring slot IN PLACE (``consume()`` waits until it
+          has), so only the int payload lands in the arena. At K>1 a frame
+          waits in ``_accum`` past ``consume()``, so it is copied out and
+          the group encodes on the staging thread."""
         self._packed = None
-        if not bool(config().get("tpu_coalesce", True)):
-            return
         try:
             self._packed = xfer.PackedLayout.probe(
                 self.wire, self.frame_size, self.pipeline.in_dtype,
@@ -652,6 +638,10 @@ class TpuKernel(Kernel):
         except Exception as e:         # noqa: BLE001 — per-part fallback
             log.warning("%s: uplink coalescing probe failed (%r) — "
                         "shipping per-part", type(self).__name__, e)
+        aliases = self.wire.encode_may_alias(self.pipeline.in_dtype)
+        self._encode_offload = aliases
+        self._ingest_enabled = aliases
+        self._deferred_consume = not aliases and self.k_batch == 1
 
     def _init_wirectl(self) -> None:
         """Arm the adaptive wire controller (``tpu_adaptive_wire``, off by
@@ -704,16 +694,7 @@ class TpuKernel(Kernel):
                      type(self).__name__, fmt, self.wire.name)
             self.wire = get_wire(fmt)
             self._wire0 = self._wire_floor_fmt = fmt
-            self._resolve_packed()
-            self._encode_offload = self._codec_pool is not None and \
-                self.wire.encode_may_alias(self.pipeline.in_dtype)
-            self._ingest_enabled = bool(
-                config().get("tpu_zero_copy_ingest", True)) and \
-                self.wire.encode_may_alias(self.pipeline.in_dtype)
-            self._deferred_consume = self._codec_pool is not None and \
-                not self.wire.encode_may_alias(self.pipeline.in_dtype) and \
-                self.k_batch == 1 and \
-                bool(config().get("tpu_deferred_consume", True))
+            self._resolve_uplink()
 
     def _adopt_credit_mode(self, adaptive: bool) -> None:
         """Re-arm the credit controller post-construction. The device-graph
@@ -1156,18 +1137,8 @@ class TpuKernel(Kernel):
         if fmt == self.wire.name:
             return
         old = self.wire.name
-        from ..config import config
         self.wire = get_wire(fmt)
-        self._resolve_packed()
-        self._encode_offload = self._codec_pool is not None and \
-            self.wire.encode_may_alias(self.pipeline.in_dtype)
-        self._ingest_enabled = bool(config().get("tpu_zero_copy_ingest",
-                                                 True)) and \
-            self.wire.encode_may_alias(self.pipeline.in_dtype)
-        self._deferred_consume = self._codec_pool is not None and \
-            not self.wire.encode_may_alias(self.pipeline.in_dtype) and \
-            self.k_batch == 1 and \
-            bool(config().get("tpu_deferred_consume", True))
+        self._resolve_uplink()
         if getattr(self, "_part_counts", None) is not None:
             self._part_counts = self.pipeline.part_counts(self.wire)
         self._wire_switches += 1
@@ -1292,9 +1263,9 @@ class TpuKernel(Kernel):
                t_in: Optional[int] = None) -> None:
         """Queue one frame toward a dispatch group. ``k_batch == 1``: encode
         into wire parts and START its H2D immediately (compute dispatch waits
-        for :meth:`_launch_staged`) — with the codec pool armed, the encode
-        and the H2D start run on a worker so they ride under this thread's
-        dispatch of older frames. ``k_batch > 1``: accumulate until the group
+        for :meth:`_launch_staged`) — the encode and the H2D start run on a
+        codec worker so they ride under this thread's dispatch of older
+        frames. ``k_batch > 1``: accumulate until the group
         fills, then :meth:`_flush_accum` ships the whole batch as one
         transfer. ``valid_in`` (a frame_multiple multiple) bounds how much of
         the output is real data vs zero-pad tail; ``tags`` are
@@ -1341,49 +1312,47 @@ class TpuKernel(Kernel):
         if self._packed is not None:
             return self._encode_group_packed(frames, frame_handles, seq)
         t0 = _trace.now() if _trace.enabled else 0
-        alloc = _arena_mod.GroupAlloc(self._arena) \
-            if self._arena is not None else None
+        alloc = _arena_mod.GroupAlloc(self._arena)
         if self.k_batch == 1:
             frame = frames[0]
-            parts = self.wire.encode_into(frame, alloc) \
-                if alloc is not None else self.wire.encode_host(frame)
+            parts = self.wire.encode_into(frame, alloc)
             aliases = self.wire.encode_may_alias(frame.dtype)
-            pinned = list(frame_handles) if aliases else []
+            pinned = (list(frame_handles) if aliases else []) + alloc.handles
             rel = [] if aliases else list(frame_handles)
-            if alloc is not None:
-                pinned += alloc.handles
             if t0:
                 _trace.complete("tpu", "encode", t0,
                                 args={"wire": self.wire.name,
                                       "items": len(frame), "seq": seq})
             return parts, pinned, rel
-        # megabatch: per-frame encodes are SCRATCH (the stacked copies are
-        # the group's payload), so they ride the temp side of the alloc and
-        # are dropped before return; the staging frames never alias the
-        # stacked parts, so every frame handle is releasable
-        sub = alloc.temps_only() if alloc is not None else None
-        parts_list = [self.wire.encode_into(f, sub) if sub is not None
-                      else self.wire.encode_host(f) for f in frames]
-        stacked = []
-        for j in range(len(parts_list[0])):
-            rows = [np.asarray(p[j]) for p in parts_list]
-            if alloc is not None:
-                out = alloc((len(rows),) + rows[0].shape, rows[0].dtype)
-                for i, r in enumerate(rows):
-                    out[i] = r
-            else:
-                out = np.stack(rows)
-            stacked.append(out)
-        if alloc is not None:
-            alloc.drop_temps()
+        # megabatch: the staging frames never alias the stacked parts, so
+        # every frame handle is releasable
+        parts = self._encode_stacked(frames, alloc)
         if t0:
             _trace.complete("tpu", "encode", t0,
                             args={"wire": self.wire.name,
                                   "items": len(frames) * self.frame_size,
                                   "frames": len(frames), "seq": seq})
-        return (tuple(stacked),
-                alloc.handles if alloc is not None else [],
-                list(frame_handles))
+        return parts, alloc.handles, list(frame_handles)
+
+    def _encode_stacked(self, frames: list, alloc) -> tuple:
+        """Encode a megabatch's frames and stack each wire part along a
+        leading frame axis. The per-frame encodes are SCRATCH (they ride the
+        temp side of ``alloc`` and are dropped before return); the K-stacked
+        copies are the group's payload, allocated ``(k,) + shape`` from
+        ``alloc`` itself — under a :class:`~futuresdr_tpu.ops.arena.PackedAlloc`
+        exactly the layout's slots, so the stack writes land at their packed
+        offsets directly."""
+        sub = alloc.temps_only()
+        parts_list = [self.wire.encode_into(f, sub) for f in frames]
+        stacked = []
+        for j in range(len(parts_list[0])):
+            rows = [np.asarray(p[j]) for p in parts_list]
+            out = alloc((len(rows),) + rows[0].shape, rows[0].dtype)
+            for i, r in enumerate(rows):
+                out[i] = r
+            stacked.append(out)
+        alloc.drop_temps()
+        return tuple(stacked)
 
     def _encode_group_packed(self, frames: list, frame_handles: list,
                              seq: Optional[int] = None) -> tuple:
@@ -1400,45 +1369,19 @@ class TpuKernel(Kernel):
         frame handle is releasable."""
         lay = self._packed
         t0 = _trace.now() if _trace.enabled else 0
-        if self._arena is not None:
-            alloc = _arena_mod.PackedAlloc(self._arena, lay)
-            if self.k_batch == 1:
-                parts = self.wire.encode_into(frames[0], alloc)
-            else:
-                # megabatch: per-frame encodes are scratch; the K-stacked
-                # copies allocate (k,)+shape — exactly the layout's slots —
-                # so the stack writes land at their packed offsets directly
-                sub = alloc.temps_only()
-                parts_list = [self.wire.encode_into(f, sub) for f in frames]
-                stacked = []
-                for j in range(len(parts_list[0])):
-                    rows = [np.asarray(p[j]) for p in parts_list]
-                    out = alloc((len(rows),) + rows[0].shape, rows[0].dtype)
-                    for i, r in enumerate(rows):
-                        out[i] = r
-                    stacked.append(out)
-                alloc.drop_temps()
-                parts = tuple(stacked)
-            packed = alloc.finish(parts)
-            pinned = alloc.handles
+        alloc = _arena_mod.PackedAlloc(self._arena, lay)
+        if self.k_batch == 1:
+            parts = self.wire.encode_into(frames[0], alloc)
         else:
-            if self.k_batch == 1:
-                parts = self.wire.encode_host(frames[0])
-            else:
-                parts_list = [self.wire.encode_host(f) for f in frames]
-                parts = tuple(
-                    np.stack([np.asarray(p[j]) for p in parts_list])
-                    for j in range(len(parts_list[0])))
-            packed = lay.pack([np.asarray(p) for p in parts],
-                              np.empty(lay.nbytes, np.uint8))
-            pinned = []
+            parts = self._encode_stacked(frames, alloc)
+        packed = alloc.finish(parts)
         if t0:
             _trace.complete("tpu", "encode", t0,
                             args={"wire": self.wire.name,
                                   "items": len(frames) * self.frame_size,
                                   "frames": len(frames),
                                   "packed_bytes": lay.nbytes, "seq": seq})
-        return (packed,), pinned, list(frame_handles)
+        return (packed,), alloc.handles, list(frame_handles)
 
     def _rlog_insert(self, seq: int, parts: tuple, metas: tuple,
                      handles) -> None:
@@ -1480,28 +1423,29 @@ class TpuKernel(Kernel):
                       frame_handles: list) -> None:
         """Route one dispatch group toward the wire.
 
-        Codec pool OFF (``host_codec_workers=0``): the synchronous pre-pool
-        path — encode, then :meth:`_stage_group` starts the H2D and logs the
-        group only AFTER the start succeeds (a fatally-failed start leaves
-        the input in its previous retention: the ring for ``k==1``, or
-        ``_accum`` restored by ``_flush_accum``).
+        Synchronous (a quantizing wire at K>1, or its zero-padded last
+        frame at K=1: neither offload nor deferred consume, see
+        :meth:`_resolve_uplink`): encode on the staging thread, then
+        :meth:`_stage_group` starts the H2D and logs the group only AFTER
+        the start succeeds (a fatally-failed start leaves the input in its
+        previous retention: the ring for ``k==1``, or ``_accum`` restored by
+        ``_flush_accum``).
 
-        Encode offload ON (pool armed AND the wire's encode aliases — see
-        ``_init_hostpath``): encode AND the H2D start run on a worker — the
+        Encode offload (the wire's encode aliases) or a deferred-consume
+        staged frame: encode AND the H2D start run on a codec worker — the
         encode(t+1) ∥ H2D(t) lanes. The frames already left the ring at
         submit (consume() runs right after ``_stage`` returns), so the
-        replay log is the group's ONLY retention: pool mode logs BEFORE the
+        replay log is the group's ONLY retention: this path logs BEFORE the
         start attempt, and a fatally-failed start surfaces at the join in
         :meth:`_launch_staged` with the group still replayable (and still
         counted by the forfeit accounting when checkpointing is off)."""
-        pool = self._codec_pool
         # the pool path runs for aliasing-wire encode offload AND for a
         # deferred-consume staged frame (quantizing K=1: the worker's encode
         # reads the ring slot in place; ev signals the slot has been read so
         # the staging loop may consume() — ops/ingest + docs/tpu_notes.md)
         ev = self._consume_event
         self._consume_event = None
-        if pool is None or not (self._encode_offload or ev is not None):
+        if not (self._encode_offload or ev is not None):
             parts, pinned, rel = self._encode_group(frames, frame_handles,
                                                     self._seq)
             _stamp_metas(metas, "encode")
@@ -1538,7 +1482,7 @@ class TpuKernel(Kernel):
                                                     seq)
 
         try:
-            fut = pool.submit_encode(task)
+            fut = self._codec_pool.submit_encode(task)
         except BaseException:
             if ev is not None:
                 ev.set()       # never leave the staging loop waiting
@@ -1641,8 +1585,8 @@ class TpuKernel(Kernel):
         keep transferring, dispatched frames keep computing, finished frames'
         D2H keeps draining: the H2D(t+1) ∥ compute(t) ∥ D2H(t−1) overlap of
         the reference's circulating h2d/d2h staging pairs, on XLA's async
-        dispatch queue (with the codec pool armed, encode and decode become
-        their own lanes around it). The in-flight bound is the credit
+        dispatch queue (encode and decode are the codec pool's own lanes
+        around it). The in-flight bound is the credit
         controller's LIVE budget, not the construction-time depth. Shared
         verbatim by the fan-out kernel — only the result-side hook differs."""
         fplan = _faults.plan()
@@ -1719,8 +1663,8 @@ class TpuKernel(Kernel):
         """Turn one dispatch group's D2H finish into a zero-arg ``land()``
         yielding the DECODED payload (None for a drop-marked replayed group —
         its transfer still lands, the duplicate emission is suppressed).
-        With the codec pool armed the whole landing — D2H wire wait + host
-        decode — runs on a decode worker starting NOW, so decode(t−1) rides
+        The whole landing — D2H wire wait + host decode — runs on a decode
+        worker starting NOW, so decode(t−1) rides
         under this thread's staging/dispatch of younger frames; emission
         order is preserved because the caller joins the in-flight deque
         oldest-first."""
@@ -1738,10 +1682,7 @@ class TpuKernel(Kernel):
             _stamp_metas(out_metas, "decode")
             return payload
 
-        pool = self._codec_pool
-        if pool is None:
-            return land
-        fut = pool.submit_decode(land)
+        fut = self._codec_pool.submit_decode(land)
 
         def join():
             return fut.result()
@@ -1780,8 +1721,8 @@ class TpuKernel(Kernel):
 
     def _drain_one(self) -> Optional[Tuple[np.ndarray, list]]:
         land, out_metas, seq, _drop = self._inflight.popleft()
-        # sync point: blocks only this block's thread (pool mode: joins the
-        # decode worker's already-running landing task)
+        # sync point: blocks only this block's thread (joins the decode
+        # worker's already-running landing task)
         payload = self._land(land, seq)
         if payload is None:
             # replayed group whose outputs were emitted before the fault: the
@@ -1812,7 +1753,7 @@ class TpuKernel(Kernel):
 
     def _land(self, land, seq):
         """``land()`` under the ``d2h_wait`` span: this thread blocked until
-        the group's results are on the host (and, pool mode, decoded)."""
+        the group's results are on the host and decoded."""
         if not _trace.enabled:
             return land()
         t0 = _trace.now()
@@ -2124,14 +2065,8 @@ class TpuKernel(Kernel):
         """Run a persistence task (snapshot write, clean-EOS purge) off the
         drain thread on the ONE-worker persistence executor
         (:func:`_persist_executor`) — strictly serialized, so writes land
-        newest-last and a purge queued after pending writes wins. Inline
-        with the codec pool off (a deliberate minimal-thread config;
-        persistence is opt-in there, and the kernel thread is trivially
-        serial)."""
-        if self._codec_pool is None:
-            fn()
-        else:
-            _persist_executor().submit(fn)
+        newest-last and a purge queued after pending writes wins."""
+        _persist_executor().submit(fn)
 
     def _persist_ckpt(self, seq: int, leaves) -> None:
         """Serialize one COMMITTED checkpoint under ``checkpoint_dir``:
@@ -2360,10 +2295,9 @@ class TpuKernel(Kernel):
         """The ring-exit staging copy, arena-backed: ``(frame', handle)``.
         The copy is needed when the encode may ALIAS the ring view (async
         H2D would read the ring after the writer reclaims it — the f32 pairs
-        view; ``ops/xfer.h2d_needs_staging`` is always True); in pool mode
-        the worker-side encode then reads the copy, never the ring. With the
-        arena on, the copy lands in recycled pages instead of a fresh
-        allocation.
+        view; ``ops/xfer.h2d_needs_staging`` is always True); the
+        worker-side encode then reads the copy, never the ring. The copy
+        lands in the arena's recycled pages.
 
         Zero-copy ingest fast path (ops/ingest.py): a frame backed by a
         REGISTERED externally-owned read-only buffer skips the copy — nobody
@@ -2384,28 +2318,24 @@ class TpuKernel(Kernel):
                 _ingest_mod.note_zero_copy()
                 return frame, h.retain()
         if not self.wire.encode_may_alias(frame.dtype) and self.k_batch == 1:
-            # quantizing wires materialize fresh arrays in the encode
-            # before consume() — inline in pool mode too (encode offload is
-            # reserved for aliasing wires, see _init_hostpath) — no copy.
+            # quantizing wires materialize fresh arrays in the encode, which
+            # runs on this thread before consume() (encode offload is for
+            # aliasing wires, see _resolve_uplink) — no copy.
             # k==1 ONLY: a megabatch frame sits in _accum across work
             # cycles AFTER consume() freed its ring space, so it must leave
             # the ring regardless of the wire (the writer would otherwise
-            # overwrite it before _flush_accum encodes — a latent hazard of
-            # the pre-arena k>1 quantizing path, now closed by the cheap
-            # recycled copy)
+            # overwrite it before _flush_accum encodes)
             return frame, None
-        if self._arena is not None:
-            return self._arena.copy_in(frame)
-        return frame.copy(), None
+        return self._arena.copy_in(frame)
 
     def _stage_deferred(self, frame: np.ndarray, tags) -> None:
         """Stage one quantizing K=1 frame WITHOUT the ring-exit copy: the
         codec worker's ``encode_into`` reads the live ring slot in place
         (safe — the slot cannot be reclaimed before ``consume()``), so only
         the int payload lands in the arena. ``consume()`` is deferred until
-        the worker signals the read (``_settle_deferred_consume``); the sync
-        fallback (``_submit_group`` took the synchronous path after all)
-        sets the event here — the encode already ran on this thread."""
+        the worker signals the read (``_settle_deferred_consume``); if
+        ``_stage`` fails before a worker picks the event up, it is set
+        here."""
         ev = threading.Event()
         self._consume_event = ev
         self._pending_consume = (ev, self.frame_size, self._seq)
@@ -2413,8 +2343,8 @@ class TpuKernel(Kernel):
             self._stage(frame, self.frame_size, tags, None)
         finally:
             if self._consume_event is ev:
-                # no pool task picked the event up: the encode (or the
-                # failure) already happened synchronously on this thread
+                # no pool task picked the event up: the failure happened on
+                # this thread
                 self._consume_event = None
                 ev.set()
 
@@ -2484,7 +2414,7 @@ class TpuKernel(Kernel):
             tags = self.input.tags(self.frame_size)
             frame = inp[:self.frame_size]
             if self._deferred_consume:
-                # quantizing K=1 + pool: the worker's encode reads the ring
+                # quantizing K=1: the worker's encode reads the ring
                 # slot IN PLACE and only the int payload lands in the arena
                 # — consume() is deferred until the read (at most one)
                 self._stage_deferred(frame, tags)
@@ -2506,14 +2436,9 @@ class TpuKernel(Kernel):
                 self._pending_consume is None and \
                 len(self._staged) + len(self._inflight) < budget:
             # final partial frame: zero-pad, emit only the valid prefix
-            if self._arena is not None:
-                frame, handle = self._arena.take_array(
-                    (self.frame_size,), self.pipeline.in_dtype)
-                frame.fill(0)
-            else:
-                frame = np.zeros(self.frame_size,
-                                 dtype=self.pipeline.in_dtype)
-                handle = None
+            frame, handle = self._arena.take_array(
+                (self.frame_size,), self.pipeline.in_dtype)
+            frame.fill(0)
             frame[:len(inp)] = inp
             n = len(inp)
             tags = self.input.tags(n)

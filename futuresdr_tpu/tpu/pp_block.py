@@ -197,10 +197,7 @@ class PpKernel(Kernel):
             if self._needs_staging and self.wire.encode_may_alias(frame.dtype):
                 # async H2D must leave the ring first (quantizing wires
                 # materialize fresh arrays in encode_host)
-                if self._arena is not None:
-                    frame, handle = self._arena.copy_in(frame)
-                else:
-                    frame = frame.copy()
+                frame, handle = self._arena.copy_in(frame)
             self._stage(frame, handle=handle)
             self.input.consume(self.frame_size)
             inp = self.input.slice()

@@ -4,8 +4,8 @@ Async-dispatch timing loops mislead on any async backend: jax returns before the
 device finishes, and per-dispatch latency swamps sub-second kernels. See
 docs/tpu_notes.md "Measuring device-resident rates".
 
-:func:`run_marginal` implements the corrected methodology used by ``bench.py`` and
-``perf/fir.py``:
+:func:`run_marginal` implements the corrected methodology the ``perf/`` harnesses
+use (``perf/fir.py``, ``perf/fm.py``, ``perf/lora.py``, ``perf/wlan.py``):
 
 - the frame loop rides INSIDE the jitted program via ``lax.scan`` — one dispatch runs
   K frames with the stage carry chained;
@@ -87,8 +87,8 @@ def default_k_pair(platform: str) -> Tuple[int, int]:
     """Scan-length pair for the marginal methodology: hundreds of frames per scan
     make each timed window long against per-dispatch latency on an accelerator;
     the CPU backend is far slower per frame, so short scans keep its runs short.
-    THE single source of these constants — bench.py and every perf/ harness route
-    through here."""
+    THE single source of these constants — every perf/ harness routes through
+    here."""
     return (512, 1024) if platform == "tpu" else (8, 16)
 
 
@@ -103,7 +103,7 @@ def scaled_k_pair(k_pair: Tuple[int, int], frame_items: int, platform: str,
     the CPU backend and ≥512M on accelerators (a few tenths of a second at
     Gsps-class chain rates — the k_hi−k_lo delta then dwarfs per-dispatch
     jitter). THE shared window
-    discipline of bench.py / perf/lora.py / perf/wlan.py."""
+    discipline of perf/lora.py / perf/wlan.py."""
     if min_lo_items is None:
         min_lo_items = 2_000_000 if platform == "cpu" else 512_000_000
     scale = max(1, -(-min_lo_items // (k_pair[0] * max(1, frame_items))))

@@ -1,12 +1,11 @@
-"""Roofline accounting for fused stage pipelines — XLA's own cost model, not hand
-math.
+"""Program cost records and chip peaks for the profile plane
+(``telemetry/profile.py``).
 
-VERDICT r3 item 7: a bare "2,944 Msps" is not auditable; ops/sample and
-bytes/sample turn it into an efficiency claim. The numbers come from the
-compiled program's ``cost_analysis()`` (XLA's flop/byte counts for exactly the
-HLO that runs), so they track fusion decisions instead of a paper formula.
-Caveat: the analysis is per-backend — a CPU-compiled pipeline fuses differently
-than the TPU one, so artifacts must carry the backend they were derived on.
+The numbers come from the compiled program's ``cost_analysis()`` (XLA's
+flop/byte counts for exactly the HLO that runs). Caveat: the analysis is
+per-backend, and its "bytes accessed" counts VMEM traffic as HBM (ROADMAP
+D10); the benchmark's roofline share is computed from shapes instead
+(``benchmark/harness/costs.py``).
 
 Peaks: :func:`detect_peaks` resolves the denominator for MFU/HBM-utilization
 claims in two layers — explicit config overrides (``peak_flops`` in FLOP/s,
@@ -21,19 +20,18 @@ live device.
 
 Cost records are cached **by program signature** (:data:`_cost_cache`):
 ``cost_of`` pays its AOT ``jax.jit(fn).lower().compile()`` once per signature
-per process, so bench roofline accounting and the profile plane's program
-registration (``telemetry/profile.py``) stop double-compiling programs the
-pipeline's own jit cache already holds.
+per process, so the profile plane's program registration does not compile a
+second time what another kernel of the same shape already asked about.
 """
 
 from __future__ import annotations
 
-from typing import Dict, Optional, Sequence
+from typing import Dict, Optional
 
 import numpy as np
 
-__all__ = ["cost_of", "pipeline_roofline", "graph_roofline", "program_cost",
-           "detect_peaks", "dtype_peak_flops", "dominant_dtype", "CHIP_PEAKS"]
+__all__ = ["cost_of", "program_cost", "detect_peaks", "dtype_peak_flops",
+           "dominant_dtype", "CHIP_PEAKS"]
 
 # public per-chip specs (per chip, bf16 matmul peak FLOP/s + HBM B/s;
 # ``int8_flops`` where the generation publishes a distinct int8 OPS figure —
@@ -168,8 +166,8 @@ def detect_peaks(dtype: Optional[str] = None) -> Optional[dict]:
 # ---------------------------------------------------------------------------
 
 #: ``signature -> {"flops", "bytes"}`` — one AOT cost-analysis compile per
-#: signature per process (bench prefix sweeps, kernel registrations and the
-#: profile plane's ensure_costs all share it)
+#: signature per process (kernel registrations and the profile plane's
+#: ensure_costs share it)
 _cost_cache: Dict[tuple, dict] = {}
 
 
@@ -230,14 +228,6 @@ def _stage_marker(s) -> tuple:
             getattr(s, "k", None), getattr(s, "mode", None))
 
 
-def _host_frame(in_dtype, frame: int) -> np.ndarray:
-    rng = np.random.default_rng(0)
-    if np.issubdtype(np.dtype(in_dtype), np.complexfloating):
-        return (rng.standard_normal(frame)
-                + 1j * rng.standard_normal(frame)).astype(in_dtype)
-    return rng.standard_normal(frame).astype(in_dtype)
-
-
 def program_cost(pipeline, frame: int, wire=None, k: int = 1) -> dict:
     """Per-DISPATCH flops/bytes of a pipeline's compiled program FORM.
 
@@ -284,136 +274,3 @@ def program_cost(pipeline, frame: int, wire=None, k: int = 1) -> dict:
         parts = tuple(np.stack([np.asarray(p)] * int(k)) for p in parts)
     return cost_of(pipeline.wired_fn(wire, int(k)), carry,
                    *[np.asarray(p) for p in parts], signature=sig)
-
-
-# ---------------------------------------------------------------------------
-# per-stage / per-node attribution
-# ---------------------------------------------------------------------------
-
-def pipeline_roofline(stages: Sequence, in_dtype, frame: int,
-                      rate_sps: Optional[float] = None,
-                      backend: str = "cpu") -> dict:
-    """Ops/sample + bytes/sample for the FUSED pipeline and per-stage prefixes.
-
-    Per-stage numbers are DIFFERENCES of compiled prefixes (stage k's cost =
-    cost(stages[:k+1]) − cost(stages[:k])), so each stage is charged exactly
-    what adding it to the fused program costs — fusion across the boundary
-    lands on the stage that triggered it. With ``rate_sps`` the achieved
-    FLOP/s, bandwidth, and (when :func:`detect_peaks` knows the chip) MFU
-    are filled in. Prefix costs are signature-cached, so a repeated bench
-    run (or a profile-plane registration of the full chain) compiles each
-    prefix once per process."""
-    from ..ops.stages import Pipeline
-
-    out = {"frame": frame, "backend": backend, "stages": []}
-    prev = {"flops": 0.0, "bytes": 0.0}
-    host = _host_frame(in_dtype, frame)
-    dt = str(np.dtype(in_dtype))
-    markers = tuple(_stage_marker(s) for s in stages)
-
-    for k in range(1, len(stages) + 1):
-        pipe = Pipeline(list(stages[:k]), in_dtype)
-        carry = pipe.init_carry()
-        sig = ("prefix", backend, dt, int(frame), markers[:k])
-        cost = cost_of(pipe.fn(), carry, host, signature=sig)
-        out["stages"].append({
-            "name": stages[k - 1].name,
-            "flops_per_sample": (cost["flops"] - prev["flops"]) / frame,
-            "bytes_per_sample": (cost["bytes"] - prev["bytes"]) / frame,
-        })
-        prev = cost
-    out["flops_per_sample"] = prev["flops"] / frame
-    out["bytes_per_sample"] = prev["bytes"] / frame
-    _finish_roofline(out, out["stages"], rate_sps,
-                     dominant_dtype(stages))
-    return out
-
-
-def graph_roofline(pipeline, frame: Optional[int] = None,
-                   rate_sps: Optional[float] = None,
-                   backend: str = "cpu") -> dict:
-    """Per-NODE roofline attribution for fan-out / general-DAG pipelines.
-
-    The prefix-difference math of :func:`pipeline_roofline` generalized to
-    DAGs: node i's cost = cost(DAG truncated to nodes[:i+1]) − cost(nodes[:i])
-    (node lists are topological, so every prefix is a valid sub-DAG; a
-    truncated prefix's extra sink materializations mirror the linear prefix
-    caveat). Accepts a :class:`~futuresdr_tpu.ops.stages.DagPipeline`, a
-    :class:`~futuresdr_tpu.ops.stages.FanoutPipeline` (viewed as producer
-    node + one node per branch), or a plain
-    :class:`~futuresdr_tpu.ops.stages.Pipeline` (delegates to the per-stage
-    form, re-keyed under ``nodes``). Per-sample numbers are per REGION-INPUT
-    sample."""
-    from ..ops.stages import DagPipeline, FanoutPipeline, Pipeline
-
-    if isinstance(pipeline, Pipeline):
-        out = pipeline_roofline(pipeline.stages, pipeline.in_dtype,
-                                frame or pipeline.frame_multiple,
-                                rate_sps, backend)
-        out["nodes"] = [dict(s, inputs=([] if i == 0 else [i - 1]))
-                        for i, s in enumerate(out["stages"])]
-        return out
-    if isinstance(pipeline, FanoutPipeline):
-        nodes = [(list(pipeline.producer.stages), [])]
-        nodes += [(list(b.stages), [0]) for b in pipeline.branches]
-        in_dtype = pipeline.in_dtype
-    elif isinstance(pipeline, DagPipeline):
-        nodes = [(list(sl), list(inputs))
-                 for sl, inputs in pipeline.raw_nodes]
-        in_dtype = pipeline.in_dtype
-    else:
-        raise TypeError(f"graph_roofline: unsupported pipeline type "
-                        f"{type(pipeline).__name__}")
-    fm = pipeline.frame_multiple
-    frame = frame or fm
-    frame = max(fm, (int(frame) // fm) * fm)
-    host = _host_frame(in_dtype, frame)
-    dt = str(np.dtype(in_dtype))
-    node_names = tuple(
-        ("+".join(str(getattr(s, "name", "?")) for s in sl) or "passthrough",
-         tuple(inputs)) for sl, inputs in nodes)
-    node_markers = tuple(
-        (tuple(_stage_marker(s) for s in sl), tuple(inputs))
-        for sl, inputs in nodes)
-
-    out = {"frame": frame, "backend": backend, "nodes": []}
-    prev = {"flops": 0.0, "bytes": 0.0}
-    for i in range(1, len(nodes) + 1):
-        sub = DagPipeline(nodes[:i], in_dtype)
-        sig = ("dag-prefix", backend, dt, frame, node_markers[:i])
-        cost = cost_of(sub.fn(), sub.init_carry(), host, signature=sig)
-        name, inputs = node_names[i - 1]
-        out["nodes"].append({
-            "name": name,
-            "inputs": list(inputs),
-            "flops_per_sample": (cost["flops"] - prev["flops"]) / frame,
-            "bytes_per_sample": (cost["bytes"] - prev["bytes"]) / frame,
-        })
-        prev = cost
-    out["flops_per_sample"] = prev["flops"] / frame
-    out["bytes_per_sample"] = prev["bytes"] / frame
-    _finish_roofline(out, out["nodes"], rate_sps,
-                     dominant_dtype(pipeline.stages))
-    return out
-
-
-def _finish_roofline(out: dict, entries, rate_sps,
-                     dtype: Optional[str] = None) -> None:
-    """Shared tail of the per-stage/per-node walks: bound classification
-    against the detected chip ridge + achieved-rate fields, with the MFU
-    denominator keyed on the chain's dominant compute dtype."""
-    peak = detect_peaks(dtype=dtype)
-    if dtype is not None:
-        out["compute_dtype"] = str(dtype)
-    if peak:
-        ridge = peak["flops"] / peak["hbm_bytes"]     # flop/byte ridge point
-        for s in entries:
-            ai = s["flops_per_sample"] / max(s["bytes_per_sample"], 1e-12)
-            s["arith_intensity"] = ai
-            s["bound"] = "hbm" if ai < ridge else "compute"
-    if rate_sps:
-        out["achieved_flops"] = rate_sps * out["flops_per_sample"]
-        out["achieved_bw_bytes"] = rate_sps * out["bytes_per_sample"]
-        if peak:
-            out["mfu"] = out["achieved_flops"] / peak["flops"]
-            out["hbm_util"] = out["achieved_bw_bytes"] / peak["hbm_bytes"]

@@ -387,8 +387,7 @@ def scenario_stateful_restart_replay():
 def scenario_arena_recycle_replay():
     """Acceptance (host staging arena × device-plane recovery): with the
     arena recycling under MEMORY PRESSURE (a tiny pool cap forces every
-    released buffer back into circulation immediately) and the codec worker
-    pool armed, seeded mid-stream faults at the dispatch AND h2d sites
+    released buffer back into circulation immediately), seeded mid-stream faults at the dispatch AND h2d sites
     recover BIT-IDENTICAL to the fault-free run — recycling must never alias
     a staging buffer the replay log still pins (the retry-safe pinning
     contract of ops/arena.py)."""
@@ -397,7 +396,6 @@ def scenario_arena_recycle_replay():
     from futuresdr_tpu.config import config
     from futuresdr_tpu.dsp import firdes
     from futuresdr_tpu.ops import arena as arena_mod
-    from futuresdr_tpu.ops import codec_pool as codec_mod
     from futuresdr_tpu.ops import fir_stage, rotator_stage
     from futuresdr_tpu.runtime import faults
     from futuresdr_tpu.tpu import TpuKernel
@@ -408,10 +406,9 @@ def scenario_arena_recycle_replay():
         .astype(np.complex64)
     taps = firdes.lowpass(0.2, 31).astype(np.float32)
     c = config()
-    saved = (c.host_arena, c.host_arena_mb, c.host_codec_workers)
-    c.host_arena, c.host_arena_mb, c.host_codec_workers = True, 1, 2
+    saved = c.host_arena_mb
+    c.host_arena_mb = 1
     arena_mod.reset_arena()
-    codec_mod.reset_pool()
 
     def one_run(fault):
         out = {}
@@ -449,9 +446,8 @@ def scenario_arena_recycle_replay():
             got = one_run(fault)
             np.testing.assert_array_equal(got, clean)
     finally:
-        (c.host_arena, c.host_arena_mb, c.host_codec_workers) = saved
+        c.host_arena_mb = saved
         arena_mod.reset_arena()
-        codec_mod.reset_pool()
 
 
 def scenario_adaptive_wire_switch():

@@ -34,11 +34,12 @@ device block; fused (``TpuDagKernel``) each region is ONE multi-output
 dispatch per frame whose D2H bills exactly the SINK payloads — interior-edge
 transfer bytes drop to ZERO (asserted via ``fsdr_xfer_bytes_total``).
 
-Acceptance gates: linear fused ≥ 1.5× unfused with dispatches 3 → 1 (the
-round-8 artifact); fan-out fused H2D bytes/frame == 1× upload with
-dispatches/frame == 1, and ≥ 1.5× throughput on the replayed link (the
-round-11 artifact, perf/FANOUT_AB_r*.md); DAG fused dispatches/frame == 1
-with interior-edge D2H bytes == 0 (the round-13 artifact, perf/DAG_AB_r*.md).
+``--smoke`` (the check.sh gate) asserts counts only: linear dispatches per
+frame 3 → 1; fan-out fused H2D bytes/frame == 1× upload with
+dispatches/frame == 1; DAG fused dispatches/frame == 1 with interior-edge D2H
+bytes == 0. The sweeps print CPU-backend, fake-link rates: orientation for a
+developer, never a device metric (no cell of ``BENCHMARK.json`` runs an
+unfused, a fan-out or a DAG region).
 
 CSV: ``mode,frame,k,run,msamples_per_sec,frames,dispatches,dispatch_per_frame``
 (+ ``h2d_bytes_per_frame`` in fan-out mode, ``shape`` +
@@ -312,30 +313,26 @@ def _dag_smoke(frame: int = 32768, n_frames: int = 12) -> None:
         sink_bytes = 10 * frame
         r_u, f_u, d_u, b_u = marginal("unfused", "nested")
         r_f, f_f, d_f, b_f = marginal("fused", "nested")
-        print(f"# dag smoke (nested): unfused {r_u:.1f} Msps "
-              f"({d_u / f_u:.0f} disp/frame, {b_u / frame:.1f} B/sample D2H) "
-              f"vs fused {r_f:.1f} Msps ({d_f / f_f:.0f} disp/frame, "
-              f"{b_f / frame:.1f} B/sample D2H)", file=sys.stderr)
+        print(f"# dag smoke (nested): unfused {d_u / f_u:.0f} disp/frame, "
+              f"{b_u / frame:.1f} B/sample D2H vs fused {d_f / f_f:.0f} "
+              f"disp/frame, {b_f / frame:.1f} B/sample D2H", file=sys.stderr)
         assert d_u / f_u >= 5.0, (d_u, f_u)
         assert d_f / f_f <= 1.0, (d_f, f_f)
         # fused D2H == exactly the sink payloads → interior-edge bytes == 0
         assert abs(b_f - sink_bytes) < 1e-6, (b_f, sink_bytes)
         # per-hop pays the interior hops too (prod 8f + a 8f on top)
         assert b_u >= sink_bytes + 12 * frame, (b_u, sink_bytes)
-        assert r_f >= 0.8 * r_u, (r_f, r_u)
         # diamond (frame plane): one f32 sink at 1:4 → frame bytes/frame;
         # interior edges are device-resident on BOTH sides — the fused win
         # here is dispatches/frame (3 member programs + merge → 1)
         r_u, f_u, d_u, b_u = marginal("unfused", "diamond")
         r_f, f_f, d_f, b_f = marginal("fused", "diamond")
-        print(f"# dag smoke (diamond): unfused {r_u:.1f} Msps "
-              f"({d_u / f_u:.0f} disp/frame) vs fused {r_f:.1f} Msps "
-              f"({d_f / f_f:.0f} disp/frame, {b_f / frame:.2f} B/sample D2H)",
-              file=sys.stderr)
+        print(f"# dag smoke (diamond): unfused {d_u / f_u:.0f} disp/frame "
+              f"vs fused {d_f / f_f:.0f} disp/frame, {b_f / frame:.2f} "
+              f"B/sample D2H", file=sys.stderr)
         assert d_u / f_u >= 3.0, (d_u, f_u)
         assert d_f / f_f <= 1.0, (d_f, f_f)
         assert abs(b_f - frame) < 1e-6, (b_f, frame)   # sink payload only
-        assert r_f >= 0.8 * r_u, (r_f, r_u)
     finally:
         set_fake_link(prev.h2d_bps if prev else None,
                       prev.d2h_bps if prev else None)
@@ -345,8 +342,7 @@ def _dag_smoke(frame: int = 32768, n_frames: int = 12) -> None:
 def _fanout_smoke(frame: int = 32768, n_frames: int = 12) -> None:
     """CI gate: fan-out fusion engages, the fused side bills exactly ONE
     input upload per MARGINAL frame on the H2D wire with one dispatch per
-    frame, and on the replayed 96/62 MB/s link envelope beats the per-hop path
-    ≥ 1.5×. Bytes/frame is the marginal between a 1× and a 2× run — each run
+    frame. Bytes/frame is the marginal between a 1× and a 2× run — each run
     pays an identical constant of carry/fence uploads at compile
     (``init_carry`` → ``to_device`` is billed), which the marginal cancels,
     leaving exactly the per-frame wire traffic."""
@@ -366,23 +362,15 @@ def _fanout_smoke(frame: int = 32768, n_frames: int = 12) -> None:
     finally:
         set_fake_link(prev.h2d_bps if prev else None,
                       prev.d2h_bps if prev else None)
-    print(f"# fanout smoke: unfused {r_u:.1f} Msps "
-          f"({d_u / f_u:.0f} disp/frame, {b_u / upload:.2f}x upload on H2D) "
-          f"vs fused {r_f:.1f} Msps ({d_f / f_f:.0f} disp/frame, "
-          f"{b_f / upload:.2f}x upload)", file=sys.stderr)
+    print(f"# fanout smoke: unfused {d_u / f_u:.0f} disp/frame, "
+          f"{b_u / upload:.2f}x upload on H2D vs fused {d_f / f_f:.0f} "
+          f"disp/frame, {b_f / upload:.2f}x upload", file=sys.stderr)
     assert d_u / f_u >= 3.0, (d_u, f_u)
     assert d_f / f_f <= 1.0, (d_f, f_f)
     # fused H2D bytes == exactly one upload per marginal frame
     assert abs(b_f - upload) < 1e-6, (b_f, upload)
     # unfused re-uploads the broadcast intermediate once per branch (3x)
     assert b_u >= 2.5 * upload, (b_u, upload)
-    # loose NON-REGRESSION throughput bound, exactly the linear smoke's
-    # policy: the smoke's single marginal draw at a small compute-bound
-    # frame is too noisy for an improvement gate (observed 1.05x on a loaded
-    # box, 1.5-2x otherwise) — the deterministic byte/dispatch asserts above
-    # are the fusion-engagement gate, and the committed FANOUT_AB artifact
-    # carries the real ≥1.5× evidence at the link-bound frame sizes
-    assert r_f >= 0.8 * r_u, (r_f, r_u)
     print("FANOUT SMOKE OK")
 
 
@@ -399,7 +387,7 @@ def main():
                    help="CI mode: one tiny config per suite (linear + "
                         "fan-out), assert the fused paths engage, dispatches "
                         "drop 3x→1x per frame, fan-out H2D bytes bill 1x "
-                        "upload, and throughput does not regress vs unfused")
+                        "upload")
     p.add_argument("--fanout", action="store_true",
                    help="run the 1→2 broadcast-fusion suite instead of the "
                         "linear chain")
@@ -427,14 +415,10 @@ def main():
         frame, n = 16384, 16384 * 24
         r_u, f_u, d_u = run_one("unfused", frame, 1, n)
         r_f, f_f, d_f = run_one("fused", frame, 1, n)
-        print(f"# smoke: unfused {r_u:.1f} Msps ({d_u / f_u:.0f} dispatch/frame) "
-              f"vs fused {r_f:.1f} Msps ({d_f / f_f:.0f} dispatch/frame)",
-              file=sys.stderr)
+        print(f"# smoke: unfused {d_u / f_u:.0f} dispatch/frame vs fused "
+              f"{d_f / f_f:.0f} dispatch/frame", file=sys.stderr)
         assert d_u / f_u >= 3.0, (d_u, f_u)
         assert d_f / f_f <= 1.0, (d_f, f_f)
-        # loose smoke gate (CI boxes are noisy); the committed artifact
-        # carries the real ≥1.5× evidence
-        assert r_f >= 0.8 * r_u, (r_f, r_u)
         print("SMOKE OK")
         _fanout_smoke()
         _dag_smoke()

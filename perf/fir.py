@@ -76,7 +76,7 @@ def run_device_resident(pipes: int, stages: int, frame_size: int,
     This is the data-parallel row of SURVEY §2.7: independent pipes become a batch
     dimension of one kernel, not N scheduler tasks. CopyRand has no device-resident
     role (it stresses the host scheduler); the measurement is the compute chain, the
-    same methodology as bench.py's device-resident mode: the frame loop rides in a
+    ``utils/measure.run_marginal`` methodology: the frame loop rides in a
     ``lax.scan`` (one dispatch = K frames, checksum feedback defeats loop hoisting)
     and the reported rate is the marginal rate between the two K values, cancelling
     the constant dispatch latency (see docs/tpu_notes.md).

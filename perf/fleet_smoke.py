@@ -20,14 +20,10 @@ failover across OS processes):
   stale → down (journal-ordered, at exactly ``fleet_down_errors``
   consecutive misses) and 100% of subsequent admits land on survivors.
 
-``--stamp`` emits a JSON line with ``fleet_hosts_ready`` and the routed
-admission p99 (``fleet_route_p99_ms``) for bench.py / perf/regress.py.
-
 Run: ``JAX_PLATFORMS=cpu python perf/fleet_smoke.py --smoke``
 """
 
 import argparse
-import json
 import os
 import signal
 import socket
@@ -130,13 +126,11 @@ def smoke() -> int:
         j0 = journal_mod.journal().seq
         procs[0].send_signal(signal.SIGKILL)
         procs[0].wait(timeout=10)
-        t_kill = time.monotonic()
-        deadline = t_kill + 15
+        deadline = time.monotonic() + 15
         while time.monotonic() < deadline:
             if view.hosts()[peers[0]]["state"] == "down":
                 break
             time.sleep(INTERVAL / 3)
-        flip_s = time.monotonic() - t_kill
         assert view.hosts()[peers[0]]["state"] == "down", view.hosts()
         evs = [e for e in journal_mod.events(since=j0, cat="fleet")["events"]
                if e.get("host") == peers[0]]
@@ -153,33 +147,10 @@ def smoke() -> int:
                   if e["event"] == "route"]
         assert len(routed) >= 10 and \
             all(e["host"] != peers[0] for e in routed), routed
-        print(f"# failover: {peers[0]} down in {flip_s:.2f}s "
-              f"({evs[1]['errors']} misses), 10/10 admits to survivors")
+        print(f"# failover: {peers[0]} down after {evs[1]['errors']} misses, "
+              f"10/10 admits to survivors")
         print("FLEET_SMOKE OK: 3 hosts, stable merged exposition, "
               "pressure-routed failover")
-        return 0
-    finally:
-        _teardown(procs, view)
-
-
-def stamp() -> int:
-    """JSON-line stamp for bench.py: ready-host count + routed-admit p99."""
-    procs, peers, view, router = _build()
-    try:
-        ready = _wait_ready(view, 3)
-        n = 80
-        durs = []
-        for i in range(n):
-            t0 = time.perf_counter()
-            router.admit("app", tenant=f"bench{i}")
-            durs.append(time.perf_counter() - t0)
-        durs.sort()
-        print(json.dumps({
-            "fleet_hosts_ready": len(view.ready_hosts()) if ready else 0,
-            "fleet_route_p99_ms": round(
-                durs[min(n - 1, int(0.99 * n))] * 1e3, 3),
-            "fleet_route_p50_ms": round(durs[n // 2] * 1e3, 3),
-        }))
         return 0
     finally:
         _teardown(procs, view)
@@ -189,11 +160,7 @@ def main() -> int:
     p = argparse.ArgumentParser()
     p.add_argument("--smoke", action="store_true",
                    help="run the check.sh smoke (hard asserts)")
-    p.add_argument("--stamp", action="store_true",
-                   help="emit the bench.py JSON stamp line")
-    args = p.parse_args()
-    if args.stamp:
-        return stamp()
+    p.parse_args()
     return smoke()
 
 

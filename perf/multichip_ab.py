@@ -1,59 +1,27 @@
 #!/usr/bin/env python
-"""perf/multichip_ab — scaling curve of the mesh-sharded device plane.
+"""perf/multichip_ab — structural gate of the mesh-sharded device plane.
 
-Measures the DATA-sharded fused program (``futuresdr_tpu/shard``) at
-D ∈ {1, 2, 4, 8} on a VIRTUAL 8-device CPU mesh (``parallel.virtual_cpu_mesh``,
-chosen plainly at start-up: the curve needs more devices than one host holds,
-and its timings are host timings, never device metrics), in both postures:
-
-* **resident** — device-resident input redispatched per group (the compute
-  plane alone: carries chain on-device, only the sink gather leaves);
-* **streamed** — fresh host rows staged per group + the sink gather (the
-  posture ``shard.data.ShardRunner`` drives).
-
-Scaling is graded against the MEASURED linear reference, the
-``perf/serve_ab.py`` discipline: the alternative to the sharded plane is D
-INDEPENDENT per-device dispatch loops (one thread per device driving the
-unsharded program on its own chip — what you would actually run without
-``futuresdr_tpu/shard``), whose aggregate scales linearly with real
-devices by construction and saturates whatever parallelism the host
-physically has (on the virtual CPU mesh: the core count, measured — never
-an assumed ceiling). ``multichip_scaling_frac`` = (aggregate Msps of the
-ONE-dispatch sharded program at D=8) / (aggregate Msps of the 8
-independent loops), per posture, min over {resident, streamed} —
-1.0 means sharding costs nothing over hand-run per-device loops while
-collapsing D dispatches into one.
-
-Estimator: BEST of N paired trials, each measuring the sharded program
-and the independent loops in ADJACENT warmed windows (median of windows).
-Background load on a shared CI host hits both sides of a pair alike, and
-what it removes is achievable parallelism — observed fractions are biased
-DOWN, never up — so the least-contended trial is the honest estimate
-(the argument behind the repo's median-of-3 warm-window headlines).
-``sharded_streamed_msps`` = the best streamed sharded rate. Both stamps
-are regress-graded (perf/regress.py).
-
-``--smoke`` (the check.sh gate) additionally asserts the plane's structural
-invariants: the data-sharded program at D=8 is bit-identical per row to the
-D=1 program at matched K, ONE dispatch per group regardless of D (the
+Runs the DATA-sharded fused program (``futuresdr_tpu/shard``) at D = 8 on a
+VIRTUAL 8-device CPU mesh (``parallel.virtual_cpu_mesh``, chosen plainly at
+start-up: the gate needs more devices than one host holds) and asserts what
+holds on any backend: the data-sharded program is bit-identical per row to
+the D=1 program at matched K, ONE dispatch per group regardless of D (the
 per-shard dispatch count never multiplies), and the compiled HLO carries
-ZERO cross-shard collectives (interior edges never leave their shard).
+ZERO cross-shard collectives (interior edges never leave their shard). It
+times nothing: no cell of ``BENCHMARK.json`` runs across chips yet
+(ROADMAP D8), so a scaling figure is "not measured".
 
 Usage:
   python perf/multichip_ab.py --smoke          # the check.sh gate
-  python perf/multichip_ab.py --stamp          # JSON stamp on stdout
 """
 
 import argparse
-import json
 import os
 import sys
-import time
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _ROOT)
 
-SMOKE_FLOOR = 0.8          # scaling fraction of the achievable ceiling
 DMAX = 8
 
 
@@ -62,158 +30,13 @@ def _chain():
 
     from futuresdr_tpu.ops.stages import (Pipeline, fft_stage, fir_stage,
                                           mag2_stage)
-    # the resident receiver-interior shape (fir -> fft -> |x|^2): per-shard
-    # work heavy enough to amortize the per-device launch overhead an
-    # 8-way shard pays, which is exactly what the curve must price in
+    # the resident receiver-interior shape (fir -> fft -> |x|^2)
     return Pipeline([fir_stage(np.hanning(64).astype(np.float32)),
                      fft_stage(2048), mag2_stage()], np.complex64)
 
 
-def _sharded_state(pipe, D: int, frame: int):
-    """(fn, carry, place, host) of the ONE-dispatch sharded program."""
-    import numpy as np
-
-    from futuresdr_tpu.shard import ShardedProgram, plan_shard
-    rng = np.random.default_rng(0)
-    host = (rng.standard_normal((D, frame))
-            + 1j * rng.standard_normal((D, frame))).astype(np.complex64)
-    prog = ShardedProgram(pipe, plan_shard(pipe, mode="data", n_devices=D),
-                          name=f"multichip_ab_d{D}")
-    fn, carry = prog.compile(frame, 1)
-    return [fn, carry, prog.place, host]
-
-
-def _sharded_window(state, streamed: bool, seconds: float) -> float:
-    """One sharded window's aggregate Msps."""
-    import jax
-    import numpy as np
-    fn, carry, place, host = state
-    x = place(host)
-    n = 0
-    t0 = time.perf_counter()
-    while time.perf_counter() - t0 < seconds:
-        if streamed:
-            x = place(host)                  # fresh host staging per group
-        carry, y = fn(carry, x)
-        if streamed:
-            np.asarray(y)                    # the sink gather
-        else:
-            jax.block_until_ready(y)
-        n += host.shape[0]
-    state[1] = carry
-    return n * host.shape[1] / (time.perf_counter() - t0) / 1e6
-
-
-def _independent_state(pipe, D: int, frame: int):
-    """Per-device (fn, carry, x_dev, host) of the LINEAR REFERENCE: one
-    independent unsharded program per device."""
-    import jax
-    import numpy as np
-    rng = np.random.default_rng(0)
-    devs = jax.devices()[:D]
-    out = []
-    fn = jax.jit(pipe.fn())
-    for d, dev in enumerate(devs):
-        host = (rng.standard_normal(frame)
-                + 1j * rng.standard_normal(frame)).astype(np.complex64)
-        carry = jax.device_put(pipe.init_carry(), dev)
-        x = jax.device_put(host, dev)
-        out.append([fn, carry, x, host, dev])
-    return out
-
-
-def _independent_window(states, streamed: bool, seconds: float) -> float:
-    """Aggregate Msps of the D independent per-device loops (one host
-    thread each — the hand-run alternative to the sharded plane)."""
-    import threading
-
-    import jax
-    import numpy as np
-    counts = [0] * len(states)
-    deadline = time.perf_counter() + seconds
-    barrier = threading.Barrier(len(states) + 1)
-
-    def drive(i, st):
-        fn, carry, x, host, dev = st
-        barrier.wait()
-        while time.perf_counter() < deadline:
-            if streamed:
-                x = jax.device_put(host, dev)
-            carry, y = fn(carry, x)
-            if streamed:
-                np.asarray(y)
-            else:
-                y.block_until_ready()
-            counts[i] += 1
-        st[1], st[2] = carry, x
-
-    threads = [threading.Thread(target=drive, args=(i, st), daemon=True)
-               for i, st in enumerate(states)]
-    for t in threads:
-        t.start()
-    barrier.wait()
-    t0 = time.perf_counter()
-    for t in threads:
-        t.join()
-    dt = max(time.perf_counter() - t0, 1e-9)
-    return sum(counts) * states[0][3].shape[0] / dt / 1e6
-
-
-def _point(window, seconds: float, windows: int = 2) -> float:
-    window(seconds / 2)                      # warm (thread pools, caches)
-    rates = [window(seconds) for _ in range(windows)]
-    return sorted(rates)[len(rates) // 2]
-
-
-def measure(frame: int = 1 << 16, seconds: float = 0.7, trials: int = 3,
-            dmax: int = DMAX, floor: float = 0.0) -> dict:
-    """The scaling measurement (module docstring): per trial and posture,
-    the sharded one-dispatch program and the D independent per-device
-    loops run in ADJACENT warmed windows; fraction = sharded/independent;
-    BEST trial per posture is the estimate. ``floor > 0`` early-exits the
-    trials once both postures clear it (the smoke's common case)."""
-    import jax
-    pipe = _chain()
-    dmax = min(int(dmax), len(jax.devices()))
-    sh = _sharded_state(pipe, dmax, frame)
-    ind = _independent_state(pipe, dmax, frame)
-    best = {"resident": 0.0, "streamed": 0.0}
-    rates_at_best = {"resident": (0.0, 0.0), "streamed": (0.0, 0.0)}
-    best_streamed_rate = 0.0            # best ABSOLUTE sharded rate: the
-    #   best-frac trial may have won on a slowed independent side, and the
-    #   regress-graded rate stamp must not inherit that trial's mediocre
-    #   absolute number
-    trial_rows = []
-    for _ in range(trials):
-        row = {}
-        for mode, streamed in (("resident", False), ("streamed", True)):
-            r_ind = _point(lambda s: _independent_window(ind, streamed, s),
-                           seconds)
-            r_sh = _point(lambda s: _sharded_window(sh, streamed, s),
-                          seconds)
-            frac = r_sh / r_ind if r_ind > 0 else 0.0
-            row[mode] = round(frac, 3)
-            if frac > best[mode]:
-                best[mode] = frac
-                rates_at_best[mode] = (round(r_ind, 2), round(r_sh, 2))
-            if streamed and r_sh > best_streamed_rate:
-                best_streamed_rate = r_sh
-        trial_rows.append(row)
-        if floor and min(best.values()) >= floor:
-            break
-    return {
-        "rates": {m: {"independent": rates_at_best[m][0],
-                      "sharded": rates_at_best[m][1]} for m in best},
-        "trials": trial_rows,
-        "fracs": {m: round(best[m], 3) for m in best},
-        "multichip_scaling_frac": round(min(best.values()), 3),
-        "sharded_streamed_msps": round(best_streamed_rate, 2),
-        "multichip_devices": dmax,
-    }
-
-
 def _structural_asserts() -> None:
-    """The smoke's invariants (module docstring)."""
+    """The gate's invariants (module docstring)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -254,51 +77,16 @@ def _structural_asserts() -> None:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--smoke", action="store_true",
-                    help="structural asserts + scaling floor "
-                         f"(>= {SMOKE_FLOOR} of the achievable ceiling)")
-    ap.add_argument("--stamp", action="store_true",
-                    help="print the JSON stamp line (bench/regress input)")
-    ap.add_argument("--frame", type=int, default=1 << 16)
-    ap.add_argument("--seconds", type=float, default=0.7)
-    ap.add_argument("--trials", type=int, default=0,
-                    help="paired trials (default: 3, or 6 with --smoke — "
-                         "early-exit once the floor clears)")
-    a = ap.parse_args(argv)
+                    help="the structural asserts (the only mode)")
+    ap.parse_args(argv)
 
     # this harness IS the virtual-mesh run: it needs DMAX devices, more than
-    # any one host holds, so its timings are never device metrics
+    # any one host holds
     from futuresdr_tpu.parallel import virtual_cpu_mesh
     virtual_cpu_mesh(DMAX)
     os.environ.setdefault("FUTURESDR_TPU_AUTOTUNE_CACHE_DIR", "off")
-    import jax
-    backend = jax.default_backend()
-
-    if a.smoke:
-        _structural_asserts()
-    trials = a.trials or (6 if a.smoke else 3)
-    got = measure(frame=a.frame, seconds=a.seconds, trials=trials,
-                  floor=SMOKE_FLOOR if a.smoke else 0.0)
-    for mode in ("resident", "streamed"):
-        r = got["rates"][mode]
-        print(f"# {mode:9} D={got['multichip_devices']}: sharded "
-              f"{r['sharded']:8.1f} Msps vs independent loops "
-              f"{r['independent']:8.1f} Msps -> frac "
-              f"{got['fracs'][mode]}")
-    print(f"# best-trial fracs (sharded one-dispatch / {os.cpu_count()}-core "
-          f"independent-loop linear reference): {got['fracs']}  "
-          f"per-trial: {got['trials']}")
-    stamp = {"backend": backend,
-             "multichip_rates": got["rates"],
-             "multichip_scaling_frac": got["multichip_scaling_frac"],
-             "sharded_streamed_msps": got["sharded_streamed_msps"],
-             "multichip_devices": got["multichip_devices"]}
-    if a.smoke:
-        frac = got["multichip_scaling_frac"]
-        assert frac >= SMOKE_FLOOR, (
-            f"multichip_scaling_frac {frac} under the {SMOKE_FLOOR} floor "
-            f"(trials: {got['trials']})")
-        print(f"# scaling floor: {frac} >= {SMOKE_FLOOR} — OK")
-    print(json.dumps(stamp))
+    _structural_asserts()
+    print("multichip_ab smoke OK")
     return 0
 
 

@@ -1,40 +1,20 @@
 #!/usr/bin/env python
-"""perf/precision_ab — interior-precision + Pallas hot-kernel A/B
+"""perf/precision_ab — interior-precision + Pallas hot-kernel correctness gate
 (docs/tpu_notes.md "Interior precision").
 
-Measures the device-resident scan-marginal rate (the bench.py methodology —
-``utils/measure.run_marginal``) of the hot chains in a small matrix:
-
-* **resident** — the headline fir64+fft2048+mag2 chain: f32 reference vs the
-  SNR-budgeted auto-lowering (``ops/precision.plan_interior_precision``) vs
-  forced bf16. The auto point also reports the plan: stages lowered, the
-  worst MEASURED per-edge SNR (the pinned floor ``bench.py`` stamps as
-  ``interior_snr_db_min``), and the end-to-end SNR vs the f32 program.
-* **pfb** — the PFB channelizer: matmul path vs the fused Pallas kernel
-  (``pallas_pfb``: polyphase MAC + twiddle-feed IDFT in one kernel) at f32
-  and bf16.
-* **decim** — the decimating FIR: shifted-matvec polyphase path vs the fused
-  FIR→decimate Pallas kernel (``pallas_poly_fir``) at f32 and bf16.
-
-On the CPU backend the Pallas kernels run in INTERPRET mode — their rates
-are correctness-priced, not wins; the kernels exist to cut HBM traffic on
-the chip. The matrix still runs everywhere so CI grades numerics and the
-artifact carries the shape of the comparison; only TPU rounds are evidence
-for the ≥2× ROADMAP target.
-
-``--smoke`` (the check.sh gate) asserts the correctness half only:
-``interior_precision="off"`` is bit-identical (same program object, same
-bits out), the auto plan lowers the resident chain with its measured floor
-above the configured budget, the lowered output clears budget − allowance
-vs f32, and both Pallas kernels match their matmul paths.
-
-Stamps a JSON line with ``resident_lowered_msps`` / ``interior_snr_db_min``
-/ ``pallas_kernels_active`` (graded by ``perf/regress.py``) plus the full
-matrix; ``bench.py`` embeds the same stamps via :func:`measure`.
+``--smoke`` (the check.sh gate, the only mode) runs the hot chains in a small
+matrix and asserts numerics only: ``interior_precision="off"`` is
+bit-identical (same program object, same bits out), the auto plan lowers the
+resident fir64+fft2048+mag2 chain with its measured floor above the
+configured budget, the lowered output clears budget − allowance vs f32, the
+forced int8 rung stays inside its quantization floor, the fused FIR→FFT stage
+matches the composed chain, and both Pallas kernels (``pallas_pfb``,
+``pallas_poly_fir``) match their matmul paths. On the CPU backend the Pallas
+kernels run in INTERPRET mode. It times nothing: what a precision rung or a
+kernel is worth on the chip is a benchmark cell's to say (ROADMAP D9).
 """
 
 import argparse
-import json
 import os
 import sys
 
@@ -74,28 +54,6 @@ def _chains():
     }
 
 
-def _rate(pipe, frame: int, k_pair=None) -> float:
-    """Device-resident marginal Msps of one pipeline (bench methodology)."""
-    import jax
-
-    from futuresdr_tpu.ops.xfer import to_device
-    from futuresdr_tpu.tpu.instance import instance
-    from futuresdr_tpu.utils.measure import (default_k_pair, run_marginal_retry,
-                                             scaled_k_pair)
-    inst = instance()
-    if k_pair is None:
-        k_pair = scaled_k_pair(default_k_pair(inst.platform), frame,
-                               inst.platform)
-    rng = np.random.default_rng(7)
-    m = pipe.frame_multiple
-    frame = max(m, (frame // m) * m)
-    host = (rng.standard_normal(frame)
-            + 1j * rng.standard_normal(frame)).astype(np.complex64)
-    carry0 = jax.device_put(pipe.init_carry(), inst.device)
-    x = to_device(host, inst.device)
-    return run_marginal_retry(pipe.fn(), carry0, x, k_pair) / 1e6
-
-
 def _one_frame(pipe, frame: int, seed: int = 3) -> np.ndarray:
     import jax.numpy as jnp
     rng = np.random.default_rng(seed)
@@ -114,88 +72,8 @@ def _snr_db(ref, got) -> float:
     return 10 * np.log10(sig / max(err, 1e-30))
 
 
-def measure(frame: int = 1 << 18, rates: bool = True) -> dict:
-    """The A/B matrix as a flat stamp dict (bench.py embeds it verbatim).
-
-    ``rates=False`` skips the marginal-rate measurements (the smoke gate
-    only needs the plans + numerics)."""
-    from futuresdr_tpu.config import config
-    from futuresdr_tpu.ops import precision as P
-    chains = {k: build() for k, build in _chains().items()}
-    budget = float(config().get("interior_snr_budget_db", 40.0))
-
-    out = {"precision_frame": frame, "interior_snr_budget_db": budget}
-
-    # the auto plan on the resident chain: the lowering evidence
-    res = chains["resident"]
-    lowered, plan = P.plan_interior_precision(res, mode="auto",
-                                              budget_db=budget)
-    out["interior_lowered_stages"] = plan.lowered
-    mn = plan.min_snr_db
-    out["interior_snr_db_min"] = round(mn, 1) if mn is not None else None
-    e2e = plan.e2e_snr_db
-    out["interior_e2e_snr_db"] = (round(e2e, 1)
-                                  if e2e is not None and np.isfinite(e2e)
-                                  else None)
-    # how many stages of the MEASURED matrix ride a hand-written Pallas
-    # kernel on this backend (forced-pallas FIRs count everywhere — the
-    # kernel genuinely runs, interpret mode off-TPU; auto routes count only
-    # where the trace-time policy actually picks them)
-    out["pallas_kernels_active"] = sum(
-        P.pallas_stage_count(p) for p in (lowered, chains["pfb_pallas"],
-                                          chains["decim_pallas"],
-                                          chains["fir_fft_fused"]))
-
-    # the forced-int8 rung on the resident chain (mode="int8": FIR-family
-    # stages drop to quantized int8 MXU matmuls, edges/FFT stay bf16 — the
-    # ladder's deepest rung, ~36 dB dynamic-absmax SNR)
-    int8_pipe = None
-    try:
-        int8_pipe, plan8 = P.plan_interior_precision(res, mode="int8")
-        out["interior_int8_stages"] = plan8.lowered
-        mn8 = plan8.min_snr_db
-        out["interior_int8_snr_db_min"] = (round(mn8, 1)
-                                           if mn8 is not None else None)
-        if int8_pipe is res or plan8.lowered == 0:
-            int8_pipe = None                    # nothing took the rung
-    except Exception as e:                      # noqa: BLE001
-        out["interior_int8_error"] = repr(e)
-        print(f"# int8 plan failed: {e!r}", file=sys.stderr)
-
-    if rates:
-        rows = [("resident_f32", res), ("resident_lowered", lowered)]
-        if int8_pipe is not None:
-            rows.append(("resident_int8", int8_pipe))
-        for key, pipe in rows:
-            try:
-                r = _rate(pipe, frame)
-                out[f"{key}_msps"] = round(r, 1)
-                print(f"# {key}: {r:.1f} Msps marginal", file=sys.stderr)
-            except Exception as e:                      # noqa: BLE001
-                out[f"{key}_error"] = repr(e)
-                print(f"# {key} failed: {e!r}", file=sys.stderr)
-        f32 = out.get("resident_f32_msps")
-        low = out.get("resident_lowered_msps")
-        if f32 and low:
-            out["resident_lowered_speedup"] = round(low / f32, 2)
-        i8 = out.get("resident_int8_msps")
-        if f32 and i8:
-            out["resident_int8_speedup"] = round(i8 / f32, 2)
-        for key in ("fir_fft_fused", "pfb_matmul", "pfb_pallas",
-                    "decim_poly", "decim_pallas"):
-            try:
-                r = _rate(chains[key], min(frame, 1 << 17))
-                out[f"{key}_msps"] = round(r, 1)
-                print(f"# {key}: {r:.1f} Msps marginal", file=sys.stderr)
-            except Exception as e:                      # noqa: BLE001
-                out[f"{key}_error"] = repr(e)
-                print(f"# {key} failed: {e!r}", file=sys.stderr)
-    return out
-
-
 def smoke(frame: int = 1 << 15) -> None:
-    """The check.sh correctness gate (no rate assertions — CI hosts are
-    shared; rates are regress-graded from the bench artifact instead)."""
+    """The check.sh correctness gate."""
     from futuresdr_tpu.ops import precision as P
     chains = {k: build() for k, build in _chains().items()}
     res = chains["resident"]
@@ -260,17 +138,10 @@ def smoke(frame: int = 1 << 15) -> None:
 
 def main():
     p = argparse.ArgumentParser(description=__doc__.split("\n")[0])
-    p.add_argument("--frame", type=int, default=1 << 18)
     p.add_argument("--smoke", action="store_true",
-                   help="correctness gate only (check.sh wiring)")
-    p.add_argument("--no-rates", action="store_true",
-                   help="plans + numerics only, skip marginal rates")
-    args = p.parse_args()
-    if args.smoke:
-        smoke()
-        return
-    out = measure(args.frame, rates=not args.no_rates)
-    print(json.dumps(out))
+                   help="the correctness gate (the only mode)")
+    p.parse_args()
+    smoke()
 
 
 if __name__ == "__main__":

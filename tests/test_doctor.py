@@ -1,9 +1,8 @@
 """Flowgraph doctor (telemetry/doctor.py + telemetry/hist.py): histogram
 bucket/percentile math, watchdog trip/classification/re-arm, the
 no-false-positive contract on slow-but-progressing graphs, flight-recorder
-dump shape, bottleneck attribution, the doctor REST endpoint, the devchain
-pick of a cached ``autotune_streamed`` megabatch K, and the perf-regression
-gate's compare logic."""
+dump shape, bottleneck attribution, the doctor REST endpoint, and the
+devchain pick of a cached ``autotune_streamed`` megabatch K."""
 
 import json
 import math
@@ -616,42 +615,3 @@ def test_streamed_pick_cache_persists_across_processes(tmp_path, monkeypatch):
         assert cached_frames_per_dispatch(stages, np.complex64, "cpu") is None
     finally:
         _streamed_cache.clear()
-
-
-# ---------------------------------------------------------------------------
-# perf-regression gate compare logic
-# ---------------------------------------------------------------------------
-
-def test_regress_compare_logic():
-    import importlib.util
-    import os
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "perf", "regress.py")
-    spec = importlib.util.spec_from_file_location("perf_regress", path)
-    regress = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(regress)
-    traj = [
-        (3, {"backend": "cpu", "value": 40.0, "cpu_baseline_msps": 24.0,
-             "streamed_msps": 20.0}),
-        (5, {"backend": "tpu", "value": 2000.0, "cpu_baseline_msps": 23.0,
-             "streamed_msps": 5.0}),
-    ]
-    # cpu stamp: backend fields graded against r03, cpu baseline against the
-    # LATEST stamp that carries it (r05) — never cpu `value` vs tpu `value`
-    cur = {"backend": "cpu", "value": 25.0, "cpu_baseline_msps": 22.0,
-           "streamed_msps": 19.0}
-    rows, ref_round = regress.compare(cur, traj, tolerance=0.25)
-    by = {r[0]: r for r in rows}
-    assert ref_round == 3
-    assert by["value"][2] == 40.0 and by["value"][5] is True      # 0.62 < 0.75
-    assert by["cpu_baseline_msps"][2] == 23.0 and \
-        by["cpu_baseline_msps"][5] is False
-    assert by["streamed_msps"][5] is False                        # 0.95
-    # fields absent from either side are skipped, unknown backend → only the
-    # backend-agnostic cpu baseline is graded
-    rows2, ref2 = regress.compare({"backend": "rocm",
-                                   "cpu_baseline_msps": 23.0}, traj, 0.25)
-    assert ref2 is None and [r[0] for r in rows2] == ["cpu_baseline_msps"]
-
-    traj_loaded = regress.load_trajectory()
-    assert traj_loaded and all(isinstance(s, dict) for _, s in traj_loaded)

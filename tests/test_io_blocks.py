@@ -80,9 +80,10 @@ def test_file_driver_replay(tmp_path):
 def test_seify_cmd_config_map():
     fg = Flowgraph()
     src = SeifySource("driver=dummy,throttle=false")
-    head = Head(np.complex64, 1000)
     snk = NullSink(np.complex64)
-    fg.connect(src, head, snk)
+    # no Head: the flowgraph must outlive the cmd call below (1000 samples
+    # could finish before it lands); stop_sync() ends the run
+    fg.connect(src, snk)
     rt = Runtime()
     running = rt.start(fg)
     r = rt.scheduler.run_coro_sync(running.handle.call(

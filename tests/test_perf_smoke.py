@@ -5,8 +5,8 @@ silently: ``perf/inplace.py`` sat broken from the stream-tag transport change
 (``get_full`` grew a tags element) until round 5 because nothing executed it
 in CI. Each harness runs here in a subprocess with a workload small enough to
 finish in seconds — the assertion is "prints its CSV and exits 0", not any
-rate. TPU-needing scripts (fm/wlan/lora/streamed_ab sweeps) stay out: their
-CPU fallbacks are exercised via bench.py and their own tests."""
+rate. TPU-needing scripts (fm/wlan/lora sweeps) stay out: their stages have
+their own tests."""
 
 import os
 import subprocess

@@ -912,18 +912,14 @@ def test_isolate_group_policy_validation():
 def test_arena_recycling_under_recovery_bit_identical(monkeypatch):
     """Seeded h2d/d2h/dispatch faults while the staging arena recycles under
     MEMORY PRESSURE (a tiny pool cap keeps every released buffer in
-    immediate circulation) with the codec worker pool armed: replayed output
+    immediate circulation): replayed output
     is bit-identical to the fault-free run — a buffer the replay log pins is
     never recycled into a newer frame (ops/arena.py pinning contract)."""
     from futuresdr_tpu.config import config
     from futuresdr_tpu.ops import arena as arena_mod
-    from futuresdr_tpu.ops import codec_pool as codec_mod
     c = config()
-    monkeypatch.setattr(c, "host_arena", True)
     monkeypatch.setattr(c, "host_arena_mb", 1)
-    monkeypatch.setattr(c, "host_codec_workers", 2)
     arena_mod.reset_arena()
-    codec_mod.reset_pool()
     try:
         data = _stateful_data()
         exp, _ = _run_stateful(data)
@@ -943,28 +939,3 @@ def test_arena_recycling_under_recovery_bit_identical(monkeypatch):
         np.testing.assert_array_equal(got4, exp4)
     finally:
         arena_mod.reset_arena()
-        codec_mod.reset_pool()
-
-
-def test_replay_bit_identical_with_hostpath_disabled(monkeypatch):
-    """The pre-round-14 synchronous host path (arena off, inline codec) is a
-    supported fallback config — its replay contract must keep holding now
-    that the defaults moved on."""
-    from futuresdr_tpu.config import config
-    from futuresdr_tpu.ops import arena as arena_mod
-    from futuresdr_tpu.ops import codec_pool as codec_mod
-    c = config()
-    monkeypatch.setattr(c, "host_arena", False)
-    monkeypatch.setattr(c, "host_codec_workers", 0)
-    arena_mod.reset_arena()
-    codec_mod.reset_pool()
-    try:
-        data = _stateful_data()
-        exp, _ = _run_stateful(data)
-        got, r = _run_stateful(data, fault=("dispatch", 0.12, 9),
-                               restart=True)
-        assert r == 1
-        np.testing.assert_array_equal(got, exp)
-    finally:
-        arena_mod.reset_arena()
-        codec_mod.reset_pool()

@@ -89,7 +89,7 @@ def test_auto_lowers_fir_fft_within_budget():
             assert e.edge_snr_db >= 40.0
     # the sink SNR the guard measured clears the incoherent-sum floor
     assert plan.e2e_snr_db >= 40.0 - 10 * np.log10(plan.lowered)
-    # and the pinned floor the bench stamps exists and sits in the bf16 band
+    # and the pinned floor exists and sits in the bf16 band
     assert plan.min_snr_db is not None and plan.min_snr_db >= 40.0
     # tolerance pin vs the f32 reference on fresh data
     x = _frames(1 << 14, seed=3)

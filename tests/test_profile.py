@@ -72,8 +72,8 @@ def test_storm_detection_names_signatures_and_skips_autotune():
     pl2.record_compile("t-two", "warmup", "b")
     assert pl2.storm_report() == []
     # cost-analysis compiles are one-per-signature by construction: like
-    # autotune they never read as a storm (a bench prefix sweep compiles
-    # many signatures back to back)
+    # autotune they never read as a storm (several kernels registering
+    # compile many signatures back to back)
     for i in range(5):
         pl2.record_compile("cost_analysis", "cost", f"sig{i}")
     assert pl2.storm_report() == []

@@ -31,6 +31,8 @@ from futuresdr_tpu.ops.stages import (Pipeline, fir_stage, rotator_stage)
 from futuresdr_tpu.serve import ServeEngine
 from futuresdr_tpu.serve.api import register_app, unregister_app
 
+from _serve_ref import SoloSlot, assert_bit_equal, assert_within_ulps
+
 FRAME = 1024
 
 
@@ -142,14 +144,18 @@ def test_mid_megabatch_join_lands_at_own_cursor():
 
 def test_leave_mid_group_frees_page_without_disturbing_siblings():
     """A session leaving mid-stream is a page-map edit: its page returns
-    to the free list, every sibling's stream stays bit-identical, and the
-    resident capacity never recompiles."""
+    to the free list, every sibling's stream stays bit-identical to the
+    same slot program run with that sibling alone (and within 1 ulp of the
+    bare pipeline: batched and unbatched XLA:CPU programs reassociate,
+    ``_serve_ref``), and the resident capacity never recompiles."""
     pipe = _pipe()
     data = [_frames(6, seed=10 + i) for i in range(3)]
-    refs = [_solo(pipe, d) for d in data]
+    bare = [_solo(pipe, d) for d in data]
     eng = ServeEngine(_pipe(), frame_size=FRAME, app="leave",
                       buckets=(4,), queue_frames=8)
     ss = [eng.admit(tenant=f"t{i}") for i in range(3)]
+    refs = [SoloSlot(_pipe(), FRAME, s.slot).run(4, d)
+            for s, d in zip(ss, data)]
     for i, s in enumerate(ss):
         for f in data[i][:3]:
             assert eng.submit(s.sid, f)
@@ -162,8 +168,8 @@ def test_leave_mid_group_frees_page_without_disturbing_siblings():
     for i in (0, 2):
         got = eng.results(ss[i].sid)
         assert len(got) == 6
-        for g, e in zip(got, refs[i]):
-            np.testing.assert_array_equal(g, e)
+        assert_bit_equal(got, refs[i])
+        assert_within_ulps(got, bare[i])
     assert eng.compiles == 1
 
 
@@ -451,11 +457,14 @@ def test_observability_answers_during_compile_bearing_step():
 
 def test_page_pool_growth_preserves_resident_streams():
     """Growing to the next bucket is page-pool growth: residents keep
-    their pages (streams bit-identical across the growth) and only the
-    NEW capacity compiles."""
+    their pages (each stream bit-identical to the same slot programs run
+    with that session alone, its page carried from the capacity-2 to the
+    capacity-4 pool; within 1 ulp of the bare pipeline: batched and
+    unbatched XLA:CPU programs reassociate, ``_serve_ref``) and only the NEW
+    capacity compiles."""
     pipe = _pipe()
     data = [_frames(6, seed=40 + i) for i in range(3)]
-    refs = [_solo(pipe, d) for d in data]
+    bare = [_solo(pipe, d) for d in data]
     eng = ServeEngine(_pipe(), frame_size=FRAME, app="pgrow",
                       buckets=(2, 4), queue_frames=8)
     s0 = eng.admit(tenant="t0")
@@ -467,8 +476,10 @@ def test_page_pool_growth_preserves_resident_streams():
     _pump(eng, {s0.sid: data[0][3:], s1.sid: data[1][3:],
                 s2.sid: data[2]})
     assert eng.compiles == 2          # exactly one new-capacity compile
-    for s, ref in ((s0, refs[0]), (s1, refs[1]), (s2, refs[2])):
+    for i, s in enumerate((s0, s1, s2)):
+        solo = SoloSlot(_pipe(), FRAME, s.slot)
+        ref = (solo.run(2, data[i][:3]) + solo.run(4, data[i][3:])
+               if i < 2 else solo.run(4, data[i]))
         got = eng.results(s.sid)
-        assert len(got) == len(ref)
-        for g, e in zip(got, ref):
-            np.testing.assert_array_equal(g, e)
+        assert_bit_equal(got, ref)
+        assert_within_ulps(got, bare[i])

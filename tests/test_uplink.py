@@ -38,13 +38,24 @@ def _uplink_defaults():
     """Every test starts from the shipped uplink defaults and leaves no
     ingest registrations behind."""
     c = config()
-    saved = (c.tpu_coalesce, c.tpu_zero_copy_ingest, c.tpu_deferred_consume,
-             c.tpu_adaptive_wire)
+    saved = c.tpu_adaptive_wire
     ingest.reset()
     yield
-    (c.tpu_coalesce, c.tpu_zero_copy_ingest, c.tpu_deferred_consume,
-     c.tpu_adaptive_wire) = saved
+    c.tpu_adaptive_wire = saved
     ingest.reset()
+
+
+@pytest.fixture
+def per_part(monkeypatch):
+    """Calling it makes every kernel built afterwards ship its wire parts
+    one transfer each: the coalescing probe answers None, which is how a
+    single-part wire reaches the per-part form. The packed tests use that
+    form as their REFERENCE."""
+    def arm():
+        monkeypatch.setattr(xfer.PackedLayout, "probe",
+                            classmethod(lambda cls, *a, **k: None))
+    yield arm
+    monkeypatch.undo()
 
 
 def _taps():
@@ -81,7 +92,7 @@ def _drive(mk, data, out_scale=2):
 
 def test_packed_layout_probe_gates():
     """Single-part wires never pack (coalescing is moot at one H2D start);
-    quantizers pack payload+scale; the config kill switch wins."""
+    quantizers pack payload+scale."""
     assert xfer.PackedLayout.probe(get_wire("f32"), FS, np.complex64,
                                    k=1) is None
     lay = xfer.PackedLayout.probe(get_wire("sc16"), FS, np.complex64, k=1)
@@ -142,9 +153,7 @@ def test_packed_alloc_writes_through_slots():
 # coalescing: end-to-end bit-equality + starts billing
 # ---------------------------------------------------------------------------
 
-def _run_chain(wire, k, coalesce, n_frames=8, seed=7):
-    c = config()
-    c.tpu_coalesce = coalesce
+def _run_chain(wire, k, n_frames=8, seed=7):
     data = _data(n_frames, seed)
     mk = _kernel(wire=wire, k=k)
     m = Mocker(mk)
@@ -159,9 +168,10 @@ def _run_chain(wire, k, coalesce, n_frames=8, seed=7):
 
 @pytest.mark.parametrize("wire", ["sc16", "sc8"])
 @pytest.mark.parametrize("k", [1, 4])
-def test_packed_bit_identical_and_single_start(wire, k):
-    a, sa, ema = _run_chain(wire, k, coalesce=True)
-    b, sb, emb = _run_chain(wire, k, coalesce=False)
+def test_packed_bit_identical_and_single_start(wire, k, per_part):
+    a, sa, ema = _run_chain(wire, k)
+    per_part()
+    b, sb, emb = _run_chain(wire, k)
     np.testing.assert_array_equal(a, b)
     assert ema["uplink_coalesced"] == 1 and emb["uplink_coalesced"] == 0
     assert ema["h2d_starts_per_frame"] == 1
@@ -174,12 +184,12 @@ def test_packed_bit_identical_and_single_start(wire, k):
 
 
 def test_packed_single_part_wires_stay_per_part():
-    out, _, em = _run_chain("f32", 1, coalesce=True)
+    out, _, em = _run_chain("f32", 1)
     assert em["uplink_coalesced"] == 0
     assert em["h2d_starts_per_frame"] == 1       # already single-start
 
 
-def test_packed_fanout_bit_identical():
+def test_packed_fanout_bit_identical(per_part):
     """Fan-out kernels ride the same packed upload (one input crossing)."""
     def mk_fan():
         return TpuFanoutKernel(
@@ -190,7 +200,8 @@ def test_packed_fanout_bit_identical():
     data = _data(6)
     outs = {}
     for coalesce in (True, False):
-        config().tpu_coalesce = coalesce
+        if not coalesce:
+            per_part()
         mk = mk_fan()
         m = Mocker(mk)
         m.input("in", data)
@@ -199,8 +210,7 @@ def test_packed_fanout_bit_identical():
         m.init()
         m.run()
         outs[coalesce] = (m.output("out0").copy(), m.output("out1").copy())
-        if coalesce:
-            assert mk.extra_metrics()["uplink_coalesced"] == 1
+        assert mk.extra_metrics()["uplink_coalesced"] == int(coalesce)
     np.testing.assert_array_equal(outs[True][0], outs[False][0])
     np.testing.assert_array_equal(outs[True][1], outs[False][1])
 
@@ -209,7 +219,6 @@ def test_packed_fanout_bit_identical():
 def test_packed_replay_bit_identical(k):
     """A recovery mid-stream re-ships the logged PACKED buffers untouched:
     the full output matches the unfailed run bit-for-bit."""
-    config().tpu_coalesce = True
     data = _data(8, seed=11)
     want = _drive(_kernel(wire="sc16", k=k, ck=2), data)
 
@@ -229,7 +238,6 @@ def test_packed_replay_bit_identical(k):
 def test_packed_survives_fake_link_faults():
     """Transient H2D faults under the seeded fake link retry the SAME packed
     buffer — output equals the clean run exactly."""
-    config().tpu_coalesce = True
     data = _data(8, seed=5)
     want = _drive(_kernel(wire="sc16", k=1), data)
     old_backoff = config().xfer_backoff
@@ -272,14 +280,16 @@ def test_ingest_refcount_idle_callback():
     assert not h.pinned and idled == [h]
 
 
-def test_ingest_zero_copy_frames_on_aliasing_wire():
-    """A registered read-only buffer skips the ring-exit copy on the f32
-    wire; output is bit-identical to the copying run and the buffer is
-    unpinned once everything drained."""
-    data = _data(6, seed=9)
-    want = _drive(_kernel(wire="f32", k=1), data)
+@pytest.mark.parametrize("k", [1, 4])
+def test_ingest_zero_copy_frames_on_aliasing_wire(k):
+    """A registered read-only buffer skips EVERY ring-exit copy on the f32
+    wire (frac == 1.0, also for frames that wait in a megabatch group);
+    output is bit-identical to the copying run and the buffer is unpinned
+    once everything drained."""
+    data = _data(8, seed=9)
+    want = _drive(_kernel(wire="f32", k=k), data)
     h = ingest.register(data, name="capture")
-    mk = _kernel(wire="f32", k=1)
+    mk = _kernel(wire="f32", k=k)
     got = _drive(mk, data)
     em = mk.extra_metrics()
     assert em["ingest_zero_copy_frac"] == 1.0, em
@@ -334,21 +344,57 @@ def test_ingest_from_dlpack():
 
 
 # ---------------------------------------------------------------------------
-# deferred-consume staging (quantizing wires, K=1 pool mode)
+# deferred-consume staging (quantizing wires, K=1)
 # ---------------------------------------------------------------------------
 
 def test_deferred_consume_engages_and_matches():
-    config().tpu_deferred_consume = True
     data = _data(8)
     mk = _kernel(wire="sc16", k=1)
-    want_engaged = mk._codec_pool is not None
     got = _drive(mk, data)
-    em = mk.extra_metrics()
-    assert em["deferred_consume"] == int(want_engaged)
+    assert mk.extra_metrics()["deferred_consume"] == 1
     assert mk._pending_consume is None           # fully settled at EOS
-    config().tpu_deferred_consume = False
-    off = _drive(_kernel(wire="sc16", k=1), data)
+    # the reference: the same kernel encoding on the staging thread before
+    # consume() (a test fake: the instance attribute, not a setting)
+    ref = _kernel(wire="sc16", k=1)
+    ref._deferred_consume = False
+    off = _drive(ref, data)
+    assert ref.extra_metrics()["deferred_consume"] == 0
     np.testing.assert_array_equal(got, off)
+
+
+@pytest.mark.parametrize("wire,k,want", [("sc16", 4, (0, 0, 0)),
+                                         ("sc8", 1, (0, 0, 1)),
+                                         ("f32", 1, (1, 1, 0)),
+                                         ("f32", 4, (1, 1, 0))])
+def test_uplink_modes_follow_wire_and_k(wire, k, want):
+    """``_resolve_uplink`` derives (encode offload, zero-copy ingest,
+    deferred consume) from what the kernel can observe: whether the wire's
+    encode aliases its input, and K."""
+    mk = _kernel(wire=wire, k=k)
+    assert (int(mk._encode_offload), int(mk._ingest_enabled),
+            int(mk._deferred_consume)) == want
+
+
+@pytest.mark.parametrize("wire", ["sc16", "sc8"])
+def test_one_physical_h2d_start_per_group_sustained(wire):
+    """A quantizing-wire streamed chain bills exactly ONE physical H2D start
+    per dispatch group over a sustained window, each of the packed layout's
+    byte count (payload + scale ride one buffer)."""
+    groups = 48
+    data = _data(groups, seed=21)
+    mk = _kernel(wire=wire, k=1)
+    m = Mocker(mk)
+    m.input("in", data)
+    m.init_output("out", len(data) * 2)
+    m.init()                 # compile + warm-up bill separately
+    s0 = xfer._XFER_STARTS.get(direction="h2d")
+    b0 = xfer._XFER_BYTES.get(direction="h2d")
+    m.run()
+    assert xfer._XFER_STARTS.get(direction="h2d") - s0 == groups
+    assert xfer._XFER_BYTES.get(direction="h2d") - b0 == \
+        groups * mk._packed.nbytes
+    em = mk.extra_metrics()
+    assert em["uplink_coalesced"] == 1 and em["h2d_starts_per_frame"] == 1
 
 
 # ---------------------------------------------------------------------------
