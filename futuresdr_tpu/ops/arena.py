@@ -309,5 +309,6 @@ class PackedAlloc(GroupAlloc):
     def finish(self, parts) -> np.ndarray:
         """Settle the packed buffer for shipping: copy in every part the
         encode did not write through a slot view, zero alignment gaps, and
-        return the packed uint8 array (backed by ``handles[0]``)."""
+        return the buffer as it ships — the ``uint32`` word view of the
+        packed bytes (same memory, backed by ``handles[0]``)."""
         return self.layout.pack(parts, self.packed)

@@ -263,11 +263,15 @@ class Pipeline:
             carry = jax.device_put(carry, device)
         return fn, carry
 
-    def wired_fn(self, wire, k: int = 1):
+    def wired_fn(self, wire, k: int = 1, words: bool = False):
         """The stage chain with the wire codec's decode PROLOG and encode EPILOG
         fused in: ``(carries, *in_parts) -> (carries, out_parts)``. Dequantized
         frames exist only inside the XLA program — they never round-trip
-        through HBM as a separate dispatch (``ops/wire.py``).
+        through HBM as a separate dispatch (``ops/wire.py``). ``words``: the
+        payload part arrives as the 32-bit words it crossed the link in and
+        the prolog is the wire's ``decode_words_jax`` (only
+        :meth:`packed_wired_fn` asks for it, for a slot the wire's
+        ``pair_words`` names).
 
         ``k > 1`` returns the MEGABATCH form: each wire part gains a leading
         ``[k]`` axis and a ``lax.scan`` runs the k frames through the chain in
@@ -278,14 +282,15 @@ class Pipeline:
         stable across compiles."""
         from .wire import get_wire
         wire = get_wire(wire)
-        key = (wire.name, int(k))
+        key = (wire.name, int(k)) + (("words",) if words else ())
         if key not in self._wired_fns:
             inner = self.fn()
             in_dt, w = self.in_dtype, wire
+            decode = w.decode_words_jax if words else w.decode_jax
 
             def run(carries, *parts):
                 with jax.named_scope("wire_decode"):
-                    x = w.decode_jax(parts, in_dt)
+                    x = decode(parts, in_dt)
                 carries, y = inner(carries, x)
                 with jax.named_scope("wire_encode"):
                     return carries, w.encode_jax(y)
@@ -303,28 +308,46 @@ class Pipeline:
 
     def packed_wired_fn(self, wire, k: int = 1, packed=None):
         """:meth:`wired_fn` with the COALESCED-uplink slicing prolog fused in
-        front: ``(carries, packed_u8) -> (carries, out_parts)``. ``packed`` is
+        front: ``(carries, packed_u32) -> (carries, out_parts)``. ``packed`` is
         an ``ops/xfer.PackedLayout`` — the offset table both the host packer
         and this unpacker derive from the wire codec, so they cannot
-        disagree. The unpack is pure slice→bitcast→reshape, which XLA fuses
-        into the decode prolog; the host pays ONE ``device_put`` per dispatch
-        group instead of ``len(parts)``. Cached per
-        ``(wire, k, layout)`` so the jit identity stays stable across
-        compiles, exactly like :meth:`wired_fn`."""
+        disagree. The buffer enters as 32-bit words; ``unpack`` is one slice
+        per slot, and a slot the wire's ``pair_words`` names (sc16 under a
+        complex ``in_dtype``: a complex sample a word) stays words for
+        ``wire_decode`` to split by two shifts, so no array with a minor
+        dimension below the lane count stands between the program's input
+        and the first stage's (:meth:`pair_word_slots` names them). The
+        host pays ONE ``device_put`` per dispatch group instead of
+        ``len(parts)``. Cached per ``(wire, k, layout)`` so the jit identity
+        stays stable across compiles, exactly like :meth:`wired_fn`."""
         from .wire import get_wire
         wire = get_wire(wire)
         key = (wire.name, int(k), "packed", packed.key)
         if key not in self._wired_fns:
-            inner = self.wired_fn(wire, k)
             lay = packed
+            as_words = self.pair_word_slots(wire, lay)
+            inner = self.wired_fn(wire, k, words=any(as_words))
 
             def run_packed(carries, buf):
                 with jax.named_scope("unpack"):
-                    parts = lay.unpack_jax(buf)
+                    parts = lay.unpack_jax(buf, as_words)
                 return inner(carries, *parts)
 
             self._wired_fns[key] = run_packed
         return self._wired_fns[key]
+
+    def pair_word_slots(self, wire, packed) -> tuple:
+        """One flag per slot of ``packed``: does the prolog of
+        :meth:`packed_wired_fn` decode it a word a sample? Read from the
+        slot's dtype and shape and ``in_dtype`` (``Wire.pair_words``); empty
+        without a layout. Their sum is ``TpuKernel``'s
+        ``uplink_word_slots``."""
+        from .wire import get_wire
+        if packed is None:
+            return ()
+        w = get_wire(wire)
+        return tuple(w.pair_words(sh, dt, self.in_dtype)
+                     for sh, dt, _off, _nb in packed.slots)
 
     def compile_wired(self, frame_size: int, wire, device=None,
                       donate=True, k: int = 1, packed=None):
@@ -334,7 +357,7 @@ class Pipeline:
         ``[k]`` frame axis). ``donate`` accepts the same bool-or-argnums
         per-argnum mask as :meth:`compile`. ``packed`` (an
         ``ops/xfer.PackedLayout``) compiles the single-buffer coalesced form
-        instead — the fn consumes ONE packed uint8 array
+        instead — the fn consumes ONE packed uint32 array
         (:meth:`packed_wired_fn`); only the carries (argnum 0) can donate
         there, so an explicit parts-argnum mask is clamped."""
         assert frame_size % self.frame_multiple == 0, \
@@ -573,22 +596,24 @@ class FanoutPipeline:
         from .wire import get_wire
         return get_wire(wire).part_count(self.in_dtype)
 
-    def wired_fn(self, wire, k: int = 1):
+    def wired_fn(self, wire, k: int = 1, words: bool = False):
         """The fan-out DAG with the wire codec's decode PROLOG fused in and
         one encode EPILOG per branch: ``(carries, *in_parts) -> (carries,
         flat_out_parts)`` where the flat tuple concatenates each branch's
         parts in branch order (:meth:`part_counts` gives the split). ``k > 1``
-        is the megabatch scan form, exactly as :meth:`Pipeline.wired_fn`."""
+        is the megabatch scan form and ``words`` the decode from 32-bit
+        words, exactly as :meth:`Pipeline.wired_fn`."""
         from .wire import get_wire
         wire = get_wire(wire)
-        key = (wire.name, int(k))
+        key = (wire.name, int(k)) + (("words",) if words else ())
         if key not in self._wired_fns:
             inner = self.fn()
             in_dt, w = self.in_dtype, wire
+            decode = w.decode_words_jax if words else w.decode_jax
 
             def run(carries, *parts):
                 with jax.named_scope("wire_decode"):
-                    x = w.decode_jax(parts, in_dt)
+                    x = decode(parts, in_dt)
                 carries, ys = inner(carries, x)
                 flat = []
                 with jax.named_scope("wire_encode"):
@@ -629,6 +654,7 @@ class FanoutPipeline:
     compile = Pipeline.compile
     compile_wired = Pipeline.compile_wired
     packed_wired_fn = Pipeline.packed_wired_fn
+    pair_word_slots = Pipeline.pair_word_slots
     update_stage = Pipeline.update_stage
     # carry checkpointing borrows too: the FLAT carries tuple (producer then
     # branches) is an ordinary pytree, so snapshot/validate/restore of the
@@ -858,6 +884,7 @@ class DagPipeline:
     compile = Pipeline.compile
     compile_wired = Pipeline.compile_wired
     packed_wired_fn = Pipeline.packed_wired_fn
+    pair_word_slots = Pipeline.pair_word_slots
     update_stage = Pipeline.update_stage
     snapshot_carry = Pipeline.snapshot_carry
     carry_matches = Pipeline.carry_matches

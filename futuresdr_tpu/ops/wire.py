@@ -132,6 +132,14 @@ class Wire:
     def decode_jax(self, parts: Sequence, dtype):
         raise NotImplementedError
 
+    def pair_words(self, shape, part_dtype, dtype) -> bool:
+        """Does a part of ``shape``/``part_dtype`` hold ONE logical sample of
+        ``dtype`` per 32-bit word, so that the coalesced uplink's prolog can
+        hand it to :meth:`decode_words_jax` as the words it crossed the link
+        in (``ops/xfer.PackedLayout.unpack_jax``)? A function of the slot
+        alone; no format but sc16 under a complex dtype has such a part."""
+        return False
+
     def encode_jax(self, y) -> tuple:
         raise NotImplementedError
 
@@ -341,6 +349,32 @@ class _QuantWire(Wire):
         if np.issubdtype(dt, np.complexfloating):
             return jax.lax.complex(x[..., 0], x[..., 1])
         return x
+
+    def pair_words(self, shape, part_dtype, dtype) -> bool:
+        # a little-endian int16 I/Q pair IS one 32-bit word: I the low half,
+        # Q the high half (sc8 packs two samples a word, a real dtype two
+        # int16 samples: neither has a word-a-sample form)
+        return (np.dtype(part_dtype) == np.dtype(self.itype) == np.int16
+                and len(shape) >= 2 and shape[-1] == 2
+                and np.issubdtype(np.dtype(dtype), np.complexfloating))
+
+    def decode_words_jax(self, parts: Sequence, dtype):
+        """:meth:`decode_jax` for a payload still in the 32-bit words it
+        crossed the link in (:meth:`pair_words`): ``parts`` is ``(int32
+        words [...], scale)``, one complex sample a word, split by two
+        shifts. Same convert and same one multiply per component as
+        :meth:`decode_jax`, so every sample is bit-identical to it, and no
+        array with a minor dimension of 2 exists on the way (such an array is
+        padded to 128 lanes on the TPU: ``docs/tpu_notes.md``)."""
+        import jax
+        import jax.numpy as jnp
+        w, scale = parts
+        s = scale.astype(jnp.float32) / self.qmax
+        sh = jnp.int32(16)
+        i = jax.lax.shift_right_arithmetic(jax.lax.shift_left(w, sh), sh)
+        q = jax.lax.shift_right_arithmetic(w, sh)
+        return jax.lax.complex(i.astype(jnp.float32) * s,
+                               q.astype(jnp.float32) * s)
 
     def encode_jax(self, y):
         import jax.numpy as jnp

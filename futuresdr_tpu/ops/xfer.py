@@ -643,19 +643,25 @@ class PackedLayout:
     frame (int payload + scale; a megabatch K-stack per part), and each part
     is a separate ``device_put`` — a separate link start. ``PackedLayout``
     fixes the byte layout that packs every part of a dispatch group into one
-    contiguous uint8 buffer: slot ``i`` holds part ``i``'s bytes at a
-    64-byte-aligned offset (TPU/infeed-friendly, and it keeps every int16
-    payload view naturally aligned). The host side writes payloads in place
-    via ``ops/arena.PackedAlloc``; the device side recovers the parts with
-    :meth:`unpack_jax` — a slice→bitcast prolog fused into the wired program
-    by ``Pipeline.compile_wired(packed=...)``, so the unpack costs one fused
-    reshape pass, not a dispatch.
+    contiguous buffer: slot ``i`` holds part ``i``'s bytes at a
+    64-byte-aligned offset, so every slot starts on a 32-bit word and
+    ``nbytes`` is a whole number of words. The host side writes payloads in
+    place through byte views (``ops/arena.PackedAlloc``); what crosses the
+    link, and what the program takes, is that buffer as ``uint32[nbytes /
+    4]`` (:meth:`pack` returns the view: same bytes, no copy). The device
+    side recovers the parts with :meth:`unpack_jax`, fused into the wired
+    program by ``Pipeline.compile_wired(packed=...)``: one slice per slot
+    and at most one bitcast. Words, because of what a byte buffer costs on
+    the TPU: ``u8[n, 2]`` → int16 and ``int16[n, 2]`` → I, Q are arrays
+    whose minor dimension is 2, padded to 128 lanes and relaid, 1.0 ms of
+    the spectrum program's 1.3 (``docs/tpu_notes.md``, "pair formats cross
+    as words").
 
     The layout is a pure function of the wire codec + frame shape (probed
     from an encode of zeros), so host packer and device unpacker can never
-    disagree, and a replayed frame re-ships the EXACT packed bytes the first
-    attempt shipped (the replay log retains the packed buffer, not the
-    parts).
+    disagree, and a replayed frame re-ships the EXACT packed words the first
+    attempt shipped (the replay log retains the shipped view of the packed
+    buffer, not the parts).
     """
 
     ALIGN = 64
@@ -708,7 +714,9 @@ class PackedLayout:
         ``(nbytes,)`` uint8 buffer) and zero the alignment gaps, so the
         shipped bytes are a deterministic function of the parts. Parts the
         encoder already wrote through a slot view (``PackedAlloc``) are left
-        untouched."""
+        untouched. Returns the buffer in the form it SHIPS in: a
+        ``uint32[nbytes / 4]`` view of ``out`` (what ``device_put`` is given
+        on the hot path, at warm-up and on every replay)."""
         assert out.nbytes >= self.nbytes, (out.nbytes, self.nbytes)
         end = 0
         for p, (sh, dt, off, nb) in zip(parts, self.slots):
@@ -721,22 +729,34 @@ class PackedLayout:
             end = off + nb
         if end < self.nbytes:
             out[end:self.nbytes] = 0
-        return out
+        return out[:self.nbytes].view(np.uint32)
 
-    def unpack_jax(self, buf):
+    def unpack_jax(self, words, as_words=()):
         """The device-side slicing prolog: recover the part tuple from the
-        packed uint8 buffer with slice→bitcast→reshape (pure XLA ops — they
-        fuse into the wired program's decode prolog, no extra dispatch)."""
+        packed ``uint32`` words, one slice per slot. A slot flagged in
+        ``as_words`` (one flag per slot, from ``Wire.pair_words``: a complex
+        pair of int16 IS a word) is handed on as the int32 words themselves,
+        shape ``slot shape[:-1]``, for ``decode_words_jax`` to split by two
+        shifts. Every other slot is bitcast back to its dtype and shape (a
+        4-byte dtype: one bitcast; a narrower one unfolds ``[w, 4 /
+        itemsize]``, the general form sc8 and a real int16 payload take)."""
         import jax
 
         parts = []
-        for sh, dt, off, nb in self.slots:
-            seg = jax.lax.slice(buf, (off,), (off + nb,))
-            if dt.itemsize > 1:
-                seg = jax.lax.bitcast_convert_type(
-                    seg.reshape(-1, dt.itemsize), dt)
-            elif dt != np.uint8:
+        flags = tuple(as_words) or (False,) * len(self.slots)
+        for (sh, dt, off, nb), word in zip(self.slots, flags):
+            seg = jax.lax.slice(words, (off // 4,), ((off + nb + 3) // 4,))
+            if word:
+                parts.append(jax.lax.bitcast_convert_type(
+                    seg, np.int32).reshape(sh[:-1]))
+                continue
+            if dt.itemsize > 4:
+                seg = seg.reshape(-1, dt.itemsize // 4)
+            if dt != np.uint32:
                 seg = jax.lax.bitcast_convert_type(seg, dt)
+            if nb % 4:                  # a narrow dtype's last, part-filled word
+                seg = jax.lax.slice(seg.reshape(-1), (0,),
+                                    (nb // dt.itemsize,))
             parts.append(seg.reshape(sh))
         return tuple(parts)
 

@@ -613,8 +613,10 @@ class TpuKernel(Kernel):
 
         * ``_packed``: the coalescing layout (``ops/xfer.PackedLayout.probe``).
           Multi-part wires (quantizers shipping payload + scale) pack a
-          dispatch group into ONE contiguous buffer, unpacked by a slicing
-          prolog fused into the program (``ops/stages.packed_wired_fn``).
+          dispatch group into ONE contiguous buffer, shipped as 32-bit words
+          and unpacked by a slicing prolog fused into the program
+          (``ops/stages.packed_wired_fn``); ``_word_slots`` counts the slots
+          that prolog decodes a word a sample (sc16 under a complex dtype).
           None for single-part wires: they already cost one H2D start, and
           packing would add a copy of the f32 pairs view for nothing. A
           probe failure falls back to the per-part path.
@@ -638,6 +640,8 @@ class TpuKernel(Kernel):
         except Exception as e:         # noqa: BLE001 — per-part fallback
             log.warning("%s: uplink coalescing probe failed (%r) — "
                         "shipping per-part", type(self).__name__, e)
+        self._word_slots = sum(
+            self.pipeline.pair_word_slots(self.wire, self._packed))
         aliases = self.wire.encode_may_alias(self.pipeline.in_dtype)
         self._encode_offload = aliases
         self._ingest_enabled = aliases
@@ -734,6 +738,7 @@ class TpuKernel(Kernel):
             # wires were already 1), the zero-copy ingest hit fraction, and
             # the adaptive-wire policy state
             "uplink_coalesced": int(self._packed is not None),
+            "uplink_word_slots": self._word_slots,
             "h2d_starts_per_frame": (
                 1 if self._packed is not None
                 else self.wire.part_count(self.pipeline.in_dtype)),
@@ -757,9 +762,9 @@ class TpuKernel(Kernel):
             parts = tuple(np.stack([np.asarray(p)] * self.k_batch)
                           for p in parts)
         if self._packed is not None:
-            buf = self._packed.pack([np.asarray(p) for p in parts],
-                                    np.empty(self._packed.nbytes, np.uint8))
-            return (jax.device_put(buf, self.inst.device),)
+            words = self._packed.pack([np.asarray(p) for p in parts],
+                                      np.empty(self._packed.nbytes, np.uint8))
+            return (jax.device_put(words, self.inst.device),)
         return tuple(jax.device_put(np.asarray(p), self.inst.device)
                      for p in parts)
 
@@ -1362,9 +1367,10 @@ class TpuKernel(Kernel):
         views, so coalescing costs zero extra payload copies; bare parts
         like the quantizer's scale scalar are settled in by
         ``PackedLayout.pack``). The group ships as a single-element part
-        tuple, so the transfer plane bills ONE h2d start with the summed
-        bytes, the replay log retains the EXACT shipped buffer, and a
-        retry/replay re-ships identical packed bytes. Packed wires are
+        tuple, the buffer as the ``uint32`` words the program takes, so the
+        transfer plane bills ONE h2d start with the summed bytes, the replay
+        log retains the EXACT shipped array, and a retry/replay re-ships
+        identical packed words. Packed wires are
         quantizers — their parts never alias the staging frame — so every
         frame handle is releasable."""
         lay = self._packed
@@ -1380,7 +1386,9 @@ class TpuKernel(Kernel):
                             args={"wire": self.wire.name,
                                   "items": len(frames) * self.frame_size,
                                   "frames": len(frames),
-                                  "packed_bytes": lay.nbytes, "seq": seq})
+                                  "packed_bytes": lay.nbytes,
+                                  "uplink_word_slots": self._word_slots,
+                                  "seq": seq})
         return (packed,), alloc.handles, list(frame_handles)
 
     def _rlog_insert(self, seq: int, parts: tuple, metas: tuple,

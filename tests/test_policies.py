@@ -576,7 +576,7 @@ def _stateful_stages():
 
 
 def _run_stateful(data, fault=None, restart=False, k=1, ck=None,
-                  max_faults=1):
+                  max_faults=1, wire=None):
     """One VectorSource → TpuKernel(FIR→rotator) → VectorSink run; ``fault``
     = (site, rate, seed) armed NON-transient (h2d/d2h included — the fatal
     class is what exercises restart, the transient class only the retry
@@ -586,7 +586,7 @@ def _run_stateful(data, fault=None, restart=False, k=1, ck=None,
     fg = Flowgraph()
     tk = TpuKernel(_stateful_stages(), np.complex64, frame_size=_FRAME,
                    frames_in_flight=2, frames_per_dispatch=k,
-                   checkpoint_every=ck)
+                   checkpoint_every=ck, wire=wire)
     if restart:
         tk.policy = BlockPolicy(on_error="restart", max_restarts=4,
                                 backoff=0.002)
@@ -656,6 +656,26 @@ def test_stateful_restart_replay_megabatch():
                            k=4)
     assert r == 1
     np.testing.assert_array_equal(got, exp)
+
+
+@pytest.mark.parametrize("k", [1, 4])
+def test_restart_replay_reships_the_packed_words(k, monkeypatch):
+    """The coalesced sc16 uplink under a `restart` policy: a group the
+    recovery re-stages from the replay log crosses as the SAME uint32 words,
+    dtype and bytes, as its first attempt (the program's one input is words,
+    ISSUE 32), and the output is bit-identical to the fault-free run."""
+    from _ship_log import ShipLog
+    data = _stateful_data()
+    exp, _ = _run_stateful(data, k=k, wire="sc16")
+    log = ShipLog(monkeypatch)
+    before = _replayed()
+    got, r = _run_stateful(data, fault=("dispatch", 0.3 if k > 1 else 0.12,
+                                        5 if k > 1 else 9),
+                           restart=True, k=k, wire="sc16")
+    assert r == 1
+    assert _replayed() - before > 0
+    np.testing.assert_array_equal(got, exp)
+    assert log.assert_reships_identical(np.uint32) >= 1
 
 
 def test_sparse_checkpoint_cadence_replays_bit_correct():

@@ -303,7 +303,8 @@ def test_wired_program_hlo_carries_stage_and_wire_names():
     wire = get_wire("sc16")
     lay = PackedLayout.probe(wire, frame, np.complex64)
     fn, carry = pipe.compile_wired(frame, wire, packed=lay)
-    text = fn.lower(carry, jax.ShapeDtypeStruct((lay.nbytes,), np.uint8)) \
+    text = fn.lower(carry, jax.ShapeDtypeStruct((lay.nbytes // 4,),
+                                                np.uint32)) \
         .compile().as_text()
     for name in [s.name for s in pipe.stages] + \
             ["wire_decode", "wire_encode", "unpack"]:

@@ -30,6 +30,8 @@ from futuresdr_tpu.ops.wire import WIRE_FORMATS, get_wire
 from futuresdr_tpu.tpu import TpuKernel
 from futuresdr_tpu.tpu.kernel_block import TpuFanoutKernel, WireController
 
+from _ship_log import ShipLog
+
 FS = 2048
 
 
@@ -103,28 +105,149 @@ def test_packed_layout_probe_gates():
         assert off % xfer.PackedLayout.ALIGN == 0
 
 
-def test_packed_layout_roundtrip_bit_exact():
-    """pack → device unpack prolog → bitcast views reproduce every part
-    bit-for-bit, gaps zeroed (deterministic replay bytes)."""
+def _special_frame(kind, in_dtype, rng, n=FS):
+    """One frame of ``in_dtype``: Gaussian, all zero, or Gaussian with
+    non-finite samples in it (the quantizer zeroes them on encode)."""
+    cplx = np.issubdtype(np.dtype(in_dtype), np.complexfloating)
+    if kind == "zeros":
+        return np.zeros(n, in_dtype)
+    x = rng.standard_normal(n)
+    if cplx:
+        x = x + 1j * rng.standard_normal(n)
+    x = x.astype(in_dtype)
+    if kind == "nonfinite":
+        x[3] = np.nan
+        x[n // 2] = np.inf
+        x[-1] = -np.inf if not cplx else complex(1.0, -np.inf)
+    return x
+
+
+@pytest.mark.parametrize("k", [1, 4])
+@pytest.mark.parametrize("in_dtype", [np.complex64, np.float32],
+                         ids=["c64", "f32"])
+@pytest.mark.parametrize("wname", ["sc16", "sc8"])
+def test_packed_layout_roundtrip_bit_exact(wname, in_dtype, k):
+    """pack → the packed program's prolog (``unpack`` + ``wire_decode``, as
+    ``packed_wired_fn`` composes them: words in, a word a sample where the
+    slot allows) decodes every frame BIT FOR BIT as ``decode_jax`` does from
+    the separate parts — Gaussian, all-zero and non-finite frames alike; the
+    plain unpack reproduces every part, gaps zeroed (deterministic replay
+    bytes), and the buffer ships as uint32 words."""
     import jax
-    for wname in ("sc16", "sc8"):
-        for k in (1, 4):
-            w = get_wire(wname)
-            lay = xfer.PackedLayout.probe(w, FS, np.complex64, k=k)
-            rng = np.random.default_rng(3)
-            frames = [(rng.standard_normal(FS) + 1j
-                       * rng.standard_normal(FS)).astype(np.complex64)
-                      for _ in range(k)]
-            encs = [w.encode_host(f) for f in frames]
-            parts = [np.stack([np.asarray(e[i]) for e in encs])
-                     if k > 1 else np.asarray(encs[0][i])
-                     for i in range(len(encs[0]))]
-            buf = lay.pack(parts, np.empty(lay.nbytes, np.uint8))
-            out = jax.jit(lay.unpack_jax)(buf)
-            assert len(out) == len(parts)
-            for a, b in zip(parts, out):
-                np.testing.assert_array_equal(np.asarray(a),
-                                              np.asarray(b), err_msg=wname)
+    from futuresdr_tpu.ops import Pipeline
+    w = get_wire(wname)
+    lay = xfer.PackedLayout.probe(w, FS, in_dtype, k=k)
+    pipe = Pipeline([mag2_stage()], in_dtype)
+    as_words = pipe.pair_word_slots(w, lay)
+    want_words = wname == "sc16" and in_dtype is np.complex64
+    assert as_words == (want_words, False)
+    rng = np.random.default_rng(3)
+    kinds = ["gauss", "zeros", "nonfinite", "gauss"]
+    for rot in range(3):
+        frames = [_special_frame(kinds[(i + rot) % 4], in_dtype, rng)
+                  for i in range(k)]
+        encs = [w.encode_host(f) for f in frames]
+        parts = [np.stack([np.asarray(e[i]) for e in encs])
+                 if k > 1 else np.asarray(encs[0][i])
+                 for i in range(len(encs[0]))]
+        buf = lay.pack(parts, np.full(lay.nbytes, 0xA5, np.uint8))
+        assert buf.dtype == np.uint32 and buf.shape == (lay.nbytes // 4,)
+        # the plain unpack: every part back, bit for bit
+        out = jax.jit(lay.unpack_jax)(buf)
+        assert len(out) == len(parts)
+        for a, b in zip(parts, out):
+            assert np.asarray(b).dtype == np.asarray(a).dtype
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                          err_msg=wname)
+        # alignment gaps are zeroed whatever the buffer held
+        bytes_ = buf.view(np.uint8)
+        end = 0
+        for _sh, _dt, off, nb in lay.slots:
+            assert not bytes_[end:off].any()
+            end = off + nb
+        assert not bytes_[end:].any()
+
+        # the prolog the packed program runs, frame by frame
+        def prolog(words):
+            ps = lay.unpack_jax(words, as_words)
+            dec = w.decode_words_jax if any(as_words) else w.decode_jax
+            if k == 1:
+                return dec(ps, in_dtype)
+            return jax.lax.map(lambda p: dec(p, in_dtype), ps)
+
+        got = np.asarray(jax.jit(prolog)(buf))
+        ref = jax.jit(lambda *ps: w.decode_jax(ps, in_dtype))
+        want = np.stack([np.asarray(ref(*e)) for e in encs]) if k > 1 \
+            else np.asarray(ref(*encs[0]))
+        assert got.dtype == want.dtype and got.shape == want.shape
+        bits = np.uint32 if got.dtype == np.float32 else np.uint64
+        np.testing.assert_array_equal(got.view(bits), want.view(bits))
+        assert np.isfinite(got).all()
+
+
+def _lowered_scope_types(text, scopes=("unpack", "wire_decode")):
+    """Every tensor type on a line of the lowered text that carries one of
+    ``scopes`` in its location (debug info on), as lists of dimensions."""
+    import re
+    locs = {m.group(1) for m in re.finditer(
+        r'^(#loc\d+) = loc\("[^"]*/(?:%s)/' % "|".join(scopes), text,
+        re.M)}
+    assert locs, "no op of the lowered text carries the prolog's scopes"
+    dims = []
+    for line in text.splitlines():
+        m = re.search(r"loc\((#loc\d+)\)\s*$", line)
+        if m and m.group(1) in locs:
+            for t in re.findall(r"tensor<([0-9x]*)x?[a-z]+[0-9]*>", line):
+                dims.append([int(d) for d in t.split("x") if d])
+    assert dims
+    return dims
+
+
+@pytest.mark.parametrize("k", [1, 4])
+def test_packed_prolog_has_no_narrow_minor_dimension(k):
+    """The structural guard (ISSUE 32): at the cells' size, sc16 / complex64
+    / 262144, no tensor under the scopes ``unpack`` and ``wire_decode`` of
+    the lowered packed program has a minor dimension of 2 or 4 (such an
+    array is padded to 128 lanes on the TPU and relaid: 1.0 ms of a 1.3 ms
+    program), and the kernel's counter says the word arm engaged — 1 there,
+    0 for sc8 and f32."""
+    import jax
+    from futuresdr_tpu.ops import Pipeline
+    fs = 262144
+    w = get_wire("sc16")
+    pipe = Pipeline([mag2_stage()], np.complex64)
+    lay = xfer.PackedLayout.probe(w, fs, np.complex64, k=k)
+    fn = jax.jit(pipe.packed_wired_fn(w, k, lay))
+    text = fn.lower(pipe.init_carry(),
+                    jax.ShapeDtypeStruct((lay.nbytes // 4,), np.uint32)) \
+        .as_text(debug_info=True)
+    # (rank 1 is exempt: at k = 4 the scale slot IS four words, f32[4])
+    narrow = [d for d in _lowered_scope_types(text)
+              if len(d) >= 2 and d[-1] in (2, 4)]
+    assert not narrow, narrow
+    # the guard can tell: the byte-and-pair form it replaces trips it
+    def old_prolog(buf):
+        with jax.named_scope("unpack"):
+            q = jax.lax.bitcast_convert_type(
+                buf[:fs * 4].reshape(-1, 2), np.int16).reshape(fs, 2)
+        with jax.named_scope("wire_decode"):
+            return w.decode_jax((q, np.float32(1.0)), np.complex64)
+    old = jax.jit(old_prolog).lower(
+        jax.ShapeDtypeStruct((lay.nbytes,), np.uint8)) \
+        .as_text(debug_info=True)
+    assert any(len(d) >= 2 and d[-1] == 2
+               for d in _lowered_scope_types(old))
+
+    for wname, want in (("sc16", 1), ("sc8", 0), ("f32", 0)):
+        mk = TpuKernel([mag2_stage()], np.complex64, frame_size=FS,
+                       wire=wname, frames_per_dispatch=k)
+        em = mk.extra_metrics()
+        assert em["uplink_word_slots"] == want, (wname, em)
+        assert em["uplink_coalesced"] == int(wname != "f32")
+    # a real-float input on sc16 ships int16[n]: two samples a word, no
+    # word-a-sample form — chosen from the slot, not from the wire's name
+    mk = TpuKernel([mag2_stage()], np.float32, frame_size=FS, wire="sc16")
+    assert mk.extra_metrics()["uplink_word_slots"] == 0
 
 
 def test_packed_alloc_writes_through_slots():
@@ -139,12 +262,15 @@ def test_packed_alloc_writes_through_slots():
     parts = w.encode_into(x, alloc)
     assert np.shares_memory(np.asarray(parts[0]), alloc.packed)
     packed = alloc.finish(parts)
+    # what ships is the SAME buffer seen as 32-bit words: a view, no copy
+    assert packed.dtype == np.uint32 and packed.nbytes == lay.nbytes
+    assert np.shares_memory(packed, alloc.packed)
     ref = [np.asarray(p) for p in w.encode_host(x)]
-    got = lay.unpack_host(packed) if hasattr(lay, "unpack_host") else None
     # settle through the slot table directly
+    raw = packed.view(np.uint8)
     for (sh, dt, off, nb), r in zip(lay.slots, ref):
         np.testing.assert_array_equal(
-            packed[off:off + nb].view(dt).reshape(sh), r)
+            raw[off:off + nb].view(dt).reshape(sh), r)
     for h in alloc.handles:
         h.release()
 
@@ -216,23 +342,33 @@ def test_packed_fanout_bit_identical(per_part):
 
 
 @pytest.mark.parametrize("k", [1, 4])
-def test_packed_replay_bit_identical(k):
+def test_packed_replay_bit_identical(k, monkeypatch):
     """A recovery mid-stream re-ships the logged PACKED buffers untouched:
-    the full output matches the unfailed run bit-for-bit."""
+    the full output matches the unfailed run bit-for-bit, and every replayed
+    group crosses as the same uint32 words, dtype and bytes, as its first
+    attempt (the program takes words: ISSUE 32)."""
     data = _data(8, seed=11)
     want = _drive(_kernel(wire="sc16", k=k, ck=2), data)
 
+    log = ShipLog(monkeypatch)
     mk = _kernel(wire="sc16", k=k, ck=2)
     m = Mocker(mk)
     m.init_output("out", len(data) * 2)
     m.init()
-    m.input("in", data[:FS * 4])
+    # k = 1: five frames, so that group 4 lies past the checkpoint @3 and
+    # is re-shipped from the log (k = 4: group 0 replays from the sentinel)
+    cut = FS * (5 if k == 1 else 4)
+    m.input("in", data[:cut])
     m.run()
     assert mk._packed is not None
     assert asyncio.run(mk.recover(RuntimeError("injected test fault")))
-    m.input("in", data[FS * 4:])
+    m.input("in", data[cut:])
     m.run()
     np.testing.assert_array_equal(m.output("out"), want)
+    assert log.assert_reships_identical(np.uint32) >= 1
+    for attempts in log.ships.values():          # first attempts too
+        assert [p[0] for p in attempts[0]] == [np.uint32]
+        assert attempts[0][0][1] == (mk._packed.nbytes // 4,)
 
 
 def test_packed_survives_fake_link_faults():
@@ -533,3 +669,50 @@ def test_adaptive_kernel_starts_from_cached_pick(tmp_path, monkeypatch):
     assert mk._packed is not None                # re-derived for the start
     at._streamed_cache.clear()
     at._disk_memo.clear()
+
+
+# ---------------------------------------------------------------------------
+# the serving program never meets the uplink prolog (ISSUE 32, satellite 2)
+# ---------------------------------------------------------------------------
+
+def test_serving_program_has_no_uplink_prolog():
+    """``ServeEngine`` compiles ``Pipeline.fn()`` under its slot program and
+    ships raw complex64 through ``xfer.wire_part`` / ``join_parts``: what the
+    slot program lowers to for the FM front end at [64, 65536] (the
+    ``fm_serve_*`` cells' shape) names neither ``unpack`` nor
+    ``wire_decode``, and no source file under ``futuresdr_tpu/serve/``
+    reaches for the wired program, its layout or a wire codec — so a change
+    to the coalesced uplink cannot move a serving cell."""
+    import pathlib
+    import re
+
+    import jax
+    from futuresdr_tpu.apps.fm_receiver import front_end_stages
+    from futuresdr_tpu.ops import Pipeline
+    from futuresdr_tpu.serve.engine import build_slot_program
+    pipe = Pipeline(front_end_stages(), np.complex64)
+    # frame_size 65536 as the cells give it: the engine rides the largest
+    # multiple of the chain's frame multiple below it (65500)
+    cap = 64
+    fs = 65536 // pipe.frame_multiple * pipe.frame_multiple
+    prog = build_slot_program(pipe, cap)
+    pages = jax.tree_util.tree_map(
+        lambda l: jax.ShapeDtypeStruct((cap,) + np.shape(l),
+                                       np.asarray(l).dtype),
+        pipe.init_carry())
+    text = prog.lower(pages,
+                      jax.ShapeDtypeStruct((cap,), np.int32),
+                      jax.ShapeDtypeStruct((cap,), np.bool_),
+                      jax.ShapeDtypeStruct((cap, fs), np.complex64),
+                      jax.ShapeDtypeStruct((cap,), np.bool_)) \
+        .as_text(debug_info=True)
+    assert "serve_gather" in text                  # the scopes ARE in the text
+    assert not re.search(r"/(unpack|wire_decode|wire_encode)/", text)
+    serve = pathlib.Path(xfer.__file__).resolve().parents[1] / "serve"
+    files = sorted(serve.glob("*.py"))
+    assert files
+    for f in files:
+        hit = re.search(r"compile_wired|wired_fn|PackedLayout|PackedAlloc|"
+                        r"decode_words_jax|decode_jax|ops\.wire|get_wire",
+                        f.read_text())
+        assert hit is None, (f.name, hit.group(0))
