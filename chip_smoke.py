@@ -22,6 +22,11 @@ another XLA program, never by bit-identity):
             frames of 802.11a/g packets, all eight rates; payloads against
             what was sent, record entries against models/wlan/reference.py
             (numpy float64), and one frame's LLRs pulled back from the device
+  lora_gw   apps.lora_gw.build_flowgraph(use_tpu=True): a seeded capture of 24
+            frames with one packet on every (channel, SF) of the EU868 gateway
+            (8 x SF7 ... SF12, the SF12 ones seventeen frames long); payloads
+            against what was sent, record entries against the benchmark's
+            float64 receiver (benchmark/harness/refs_lora.py)
   multichip (only with --devices N > 1) the spectrum chain data-sharded over
             N devices and examples/sharded_spectrum.py, matched against the
             single-device run, every device holding a shard
@@ -967,6 +972,80 @@ def phase_wlan_rx(ctx: Ctx) -> None:
 
 
 # ---------------------------------------------------------------------------
+# phase: lora_gw
+# ---------------------------------------------------------------------------
+
+_LORA_SMALL = dict(n_channels=4, sfs=(7, 8, 9), max_payload={7: 48, 8: 32, 9: 24},
+                   ldro_from_sf=9, done_slots=8)
+
+
+def phase_lora_gw(ctx: Ctx) -> None:
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                                    "benchmark"))
+    from harness import refs_lora as R
+
+    from futuresdr_tpu import Runtime
+    from futuresdr_tpu.apps.lora_gw import build_flowgraph
+    from futuresdr_tpu.blocks import VectorSource
+    from futuresdr_tpu.config import config
+    from futuresdr_tpu.models.lora.rx_stages import EU868_MAX_PAYLOAD
+
+    t0, mark = time.perf_counter(), ctx.meter.mark()
+    sizes = dict(_LORA_SMALL) if ctx.rehearse else {}
+    n_ch, sfs = sizes.get("n_channels", 8), sizes.get("sfs", (7, 8, 9, 10, 11, 12))
+    max_len = sizes.get("max_payload", EU868_MAX_PAYLOAD)
+    ldro_from = sizes.get("ldro_from_sf", 11)
+    frame, n_frames, n0 = config().tpu_frame_size, 24, 1e-2
+    rng = np.random.default_rng([ctx.seed, 33])
+    n = n_frames * frame
+    x = (rng.standard_normal(n) + 1j * rng.standard_normal(n)) * np.sqrt(n0 / 2)
+    sent = []
+    for c in range(n_ch):               # one packet a branch, anywhere it fits
+        for sf in sfs:
+            de = R.ldro(sf, ldro_from)
+            payload = rng.integers(0, 256, int(rng.integers(13, max_len[sf] + 1)),
+                                   dtype=np.uint8).tobytes()
+            dur = R.packet_chips(sf, len(payload), de) * n_ch * R.SLOT / R.BW
+            R.add_packet(x, n_ch, c, sf, payload, rng.uniform(4096, n - 4096 - dur),
+                         rng.uniform(6, 10), n0, rng.uniform(-10e3, 10e3),
+                         rng.uniform(0, 1), de)
+            sent.append((c, sf, payload))
+    x = x.astype(np.complex64)
+    fg, kernel, rx = build_flowgraph(VectorSource(x), use_tpu=True, **sizes)
+    Runtime().run(fg)
+    m = kernel.extra_metrics()
+    check(kernel.inst.platform == ctx.device.platform, "kernel on wrong platform")
+    check(m["frames_dispatched"] == n_frames and m["frame_size"] == frame,
+          f"dispatched {m['frames_dispatched']} frames of {m['frame_size']}")
+    got = [(p["channel"], p["sf"], p["payload"]) for p in rx.packets if p["crc_ok"]]
+    check(sorted(got) == sorted(sent),
+          f"{len(got)} payloads with a good CRC, {len(sent)} sent")
+    totals = rx.extra_metrics()
+    check(totals["overflow"] == 0 and totals["crc_bad"] == 0, f"totals {totals}")
+
+    # record entries against the float64 receiver, frame after frame
+    gw = R.Gateway(n_ch, sfs, max_len, ldro_from)
+    want = [r for j in range(n_frames) for r in gw.frame(x[j * frame:(j + 1) * frame])[0]]
+    check(len(want) == len(rx.packets), "as many entries as the reference's")
+    cfo_err = tau_err = share_err = 0.0
+    for p, w in zip(rx.packets, want):
+        key = ("channel", "sf", "start", "end", "length", "n_sym", "payload")
+        check([p[k] for k in key] == [w[k] for k in key],
+              f"entry {[p[k] for k in key[:4]]} differs from the reference's "
+              f"{[w[k] for k in key[:4]]}")
+        cfo_err = max(cfo_err, abs(p["cfo_hz"] - w["cfo_hz"]))
+        tau_err = max(tau_err, abs(p["timing"] - w["timing"]))
+        share_err = max(share_err, abs(p["share"] - w["share"]) / w["share"])
+    check(cfo_err <= 0.05 and tau_err <= 1e-4 and share_err <= 1e-4,
+          f"CFO off by {cfo_err:.3g} Hz, timing by {tau_err:.3g} chips, the mean "
+          f"peak share by {share_err:.3g} of itself")
+    ctx.emit("lora_gw", mark, t0, samples=len(x), frame_size=frame, wire=m["wire"],
+             packets_sent=len(sent), packets_emitted=totals["packets"],
+             entries_checked=len(want), cfo_err_max_hz=cfo_err,
+             timing_err_max_chips=tau_err, share_err_max_rel=share_err)
+
+
+# ---------------------------------------------------------------------------
 # phase: multichip (only when asked: --devices N > 1)
 # ---------------------------------------------------------------------------
 
@@ -1064,7 +1143,8 @@ def main(argv=None) -> int:
     ctx = Ctx(args)
     phases = {"streamed": phase_streamed, "fm_app": phase_fm_app,
               "serve": phase_serve, "pallas": phase_pallas,
-              "wlan_rx": phase_wlan_rx, "multichip": phase_multichip}
+              "wlan_rx": phase_wlan_rx, "lora_gw": phase_lora_gw,
+              "multichip": phase_multichip}
     full = [p for p in phases if p != "multichip" or args.devices > 1]
     want = [p for p in args.phases.split(",") if p] or full
     skipped = [p for p in full if p not in want]

@@ -6,12 +6,12 @@ mapping, whitening, explicit header, CRC16 — frame-level and batched for TPU.
 
 from .phy import (LoraParams, modulate_frame, demodulate_frame, detect_frames,
                   decode_symbols, encode_payload_symbols)
-from .blocks import LoraTransmitter, LoraReceiver
+from .blocks import LoraGatewayRecords, LoraTransmitter, LoraReceiver
 from .forwarder import PacketForwarderClient, build_rxpk
 from .multichannel import EU868_CHANNELS_HZ, build_multichannel_rx
 from . import coding, meshtastic
 
 __all__ = ["LoraParams", "modulate_frame", "demodulate_frame", "detect_frames",
            "decode_symbols", "encode_payload_symbols", "LoraTransmitter",
-           "LoraReceiver", "PacketForwarderClient", "build_rxpk",
+           "LoraReceiver", "LoraGatewayRecords", "PacketForwarderClient", "build_rxpk",
            "EU868_CHANNELS_HZ", "build_multichannel_rx", "coding", "meshtastic"]

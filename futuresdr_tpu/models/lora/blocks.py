@@ -14,7 +14,7 @@ from ...types import Pmt
 from . import phy
 from .phy import LoraParams
 
-__all__ = ["LoraTransmitter", "LoraReceiver"]
+__all__ = ["LoraTransmitter", "LoraReceiver", "LoraGatewayRecords"]
 
 
 class LoraTransmitter(Kernel):
@@ -124,4 +124,57 @@ class LoraReceiver(Kernel):
                       if k * (self.params.n // 2) >= self._tail_abs - self.OVERLAP}
         self.input.consume(n)
         if self.input.finished() and self.input.available() == 0:
+            io.finished = True
+
+
+class LoraGatewayRecords(Kernel):
+    """Record blocks of ``rx_stages.lora_gw_stages`` (one per device frame,
+    ``block_words`` int32 each) → ``rx`` messages, the ones a
+    ``LoraReceiver`` + ``multichannel.ChannelTag`` pair posts per channel today
+    (``payload``, ``crc_ok``, ``freq``) with the branch's ``sf`` beside them:
+    the host end of the on-device gateway. Keeps its totals as metrics for the
+    REST plane."""
+
+    def __init__(self, block_words: int, channels_hz=None):
+        super().__init__()
+        from .rx_stages import parse_records    # jax: not at package import
+        self._parse = parse_records
+        self.block_words = int(block_words)
+        self.channels_hz = None if channels_hz is None else list(channels_hz)
+        self.frames = []           # payloads with a good CRC, in order of ending
+        self.packets = []          # every record entry parsed, CRC good or not
+        self.totals = {"frames": 0, "packets": 0, "crc_bad": 0, "overflow": 0}
+        self.input = self.add_stream_input("in", np.int32,
+                                           min_items=self.block_words)
+        self.add_message_output("rx")
+
+    def extra_metrics(self) -> dict:
+        return dict(self.totals)
+
+    async def work(self, io, mio, meta):
+        inp = self.input.slice()
+        n = len(inp) // self.block_words
+        for i in range(n):
+            head, packets = self._parse(
+                inp[i * self.block_words:(i + 1) * self.block_words])
+            self.totals["frames"] += 1
+            self.totals["overflow"] += head.get("lora_overflow", 0)
+            for pkt in packets:
+                self.packets.append(pkt)
+                if not pkt["crc_ok"]:
+                    self.totals["crc_bad"] += 1
+                    continue
+                self.totals["packets"] += 1
+                self.frames.append(pkt["payload"])
+                d = {"payload": Pmt.blob(pkt["payload"]), "crc_ok": Pmt.bool_(True),
+                     "sf": Pmt.f64(pkt["sf"]), "channel": Pmt.f64(pkt["channel"])}
+                if self.channels_hz is not None:
+                    d["freq"] = Pmt.f64(self.channels_hz[pkt["channel"]])
+                mio.post("rx", Pmt.map(d))
+        if n:
+            self.input.consume(n * self.block_words)
+        if self.input.finished() and \
+                self.input.available() < self.block_words:
+            # what is left is the cut block of a last partial frame
+            self.input.consume(self.input.available())
             io.finished = True

@@ -1,0 +1,497 @@
+"""A LoRaWAN gateway as ONE fixed-shape device program per frame.
+
+``TpuKernel(lora_gw_stages(), np.complex64)``: a wideband stream of
+``n_channels`` slots of 200 kHz in, *records* out. Every (channel, SF) pair is
+a **branch**, a receiver of its own (8 x 6 = 48 at the defaults: the EU868
+uplink channels, SF7 to SF12 at once), and a packet outlives a frame (an SF12
+packet of 64 bytes lasts 2.79 s, seventeen frames of 262144 samples at
+1.6 Msps), so the carry holds, per branch, the frame-sync state, timing, CFO
+and the symbols collected so far; a record leaves in the frame in which the
+scan reaches the end of the packet's last symbol (the frame in which its last
+sample lies, as delayed by the front end's filters). Per frame, by scope:
+
+``chan``    a half-slot rotation (the band's centre, 867.8 MHz for EU868, lies
+            between two slots; channel ``c`` lands in slot ``(c - n/2 + 1) mod
+            n``), then the critically sampled polyphase bank
+            (``ops.stages.channelizer_stage``).
+``resamp``  5/4 to 250 kHz, two samples a chip (``ops.stages.resample_stage``,
+            channels as a batch).
+``detect``  per SF, channels as a batch: windows of one symbol at a hop of a
+            quarter symbol over the last four symbols of the earlier frames +
+            this frame, dechirped (``ops.stages.lora_dechirp_dft``: a DFT of
+            2 * 2^SF points through ``ops/mxu_fft``; bins ``k`` and ``k +
+            2^SF`` hold a symbol's two parts on either side of its wrap and add
+            as powers, so no fraction of a chip in the timing costs the peak);
+            peak bin, the share of the energy in the peak and its larger
+            neighbour, the phase against the window a symbol earlier. A
+            preamble = four symbol-spaced windows that agree within a bin.
+``sync`` / ``demod``  per SF one ``lax.scan`` over the frame's symbol times;
+            each step every branch looks at ONE window of its own grid: idle
+            branches at four detection windows (a look-up), the others at the
+            aligned window their state asks for. The grid moves by ``-2k``
+            samples so that the preamble dechirps to bin 0, its first window
+            gives the rest ``nu`` (Jacobsen), it walks to the sync word (24,
+            32 for 0x34), the two whole down-chirps against the up-chirp give
+            ``g = 2 (cfo - nu)``: CFO ``nu + g/2`` (its fraction from the
+            preamble's phase), symbol edge ``g`` samples on; then aligned data
+            symbols, rotated by CFO and the rest of the timing (their two
+            parts added as amplitudes, the second turned by that rest), argmax. The
+            header block (8 symbols, 2^(SF-2) resolution, Hamming 4/8) is
+            decoded in the step that completes it and sets the packet's count
+            of symbols. Every branch is a lane of its SF's scan: the scan is
+            latency-bound, so an idle lane costs nothing and no pool of
+            active slots stands between a preamble and its lane.
+``decode``  finished packets (copied out of the scan into ``done_slots`` rows
+            per SF): Gray, diagonal de-interleave, Hamming 4/5 (hard
+            decisions), nibbles to bytes, de-whitening, CRC-16 as an XOR of
+            table rows.
+``pack``    entries of all SFs in order of ending into the record block.
+
+The record block of a frame of ``n`` samples is ``n // 8`` int32 words:
+
+================  ==========================================================
+words             content
+================  ==========================================================
+``0 ... 15``      header: magic, detected, synced, header_ok, emitted,
+                  crc_bad, in_flight (branches past detection at the frame's
+                  end), symbols (aligned data symbols demodulated), overflow,
+                  channels, SFs, 0 ...
+``16 + 80 i``     entry ``i``, in order of ending: channel, SF, start and end
+                  (250 kHz samples from this frame's first; the start is
+                  negative for a packet begun in an earlier frame), CFO (Hz),
+                  rest of the timing (chips), SNR estimate (dB), mean share of
+                  a symbol's energy in its peak (the number every matmul of
+                  the program passes through), length, CRC verdict, symbols,
+                  0 ..., then from word 16 the payload, little-endian
+================  ==========================================================
+
+Decoded: explicit header, CR 4/5, payload CRC on (LoRaWAN uplinks), LDRO from
+``ldro_from_sf``. ``benchmark/harness/refs_lora.py`` is the same receiver in
+numpy float64, one branch at a time, and lists the departures from
+gr-lora_sdr (no SFO tracking among them). Precision: float32; every DFT and
+the bank at ``Precision.HIGHEST`` (``benchmark/tools/lora_precision_control.py``
+is the control).
+"""
+
+from __future__ import annotations
+
+from fractions import Fraction
+from typing import Dict, List, Optional, Sequence
+
+import numpy as np
+
+from ...ops.stages import (Stage, channelizer_stage, lora_dechirp_dft,
+                           lora_downchirp, resample_stage)
+from . import coding
+
+__all__ = ["lora_gw_stages", "parse_records", "record_counters", "front_end_taps",
+           "MAGIC", "HEADER_WORDS", "ENTRY_WORDS", "EU868_MAX_PAYLOAD"]
+
+MAGIC = 0x4C_4F_52_41          # "LORA"
+HEADER_WORDS = 16
+ENTRY_WORDS = 80
+MAX_ENTRIES = 64
+OS = 2                         # samples a chip after the resampler
+MAX_PREAMBLE_WALK = 10
+SYNC_WORD = 0x34
+_PRECISION = "f32"             # of every DFT and the bank (the control patches it)
+_IDLE, _PRE, _SW2, _DN1, _DN2, _DATA = range(6)
+_COUNTERS = ("detected", "synced", "header_ok", "emitted", "crc_bad", "in_flight",
+             "symbols", "overflow")
+#: RP002 EU863-870: the largest PHYPayload of DR0 ... DR5
+EU868_MAX_PAYLOAD = {12: 64, 11: 64, 10: 64, 9: 128, 8: 255, 7: 255}
+
+
+def _kaiser_lowpass(cutoff: float, n_taps: int, beta: float) -> np.ndarray:
+    m = np.arange(n_taps) - (n_taps - 1) / 2
+    h = 2 * cutoff * np.sinc(2 * cutoff * m) * np.kaiser(n_taps, beta)
+    return h / h.sum()
+
+
+def front_end_taps(n_channels: int) -> tuple:
+    """(bank prototype: 12 taps a branch, pass band the 125 kHz of a 200 kHz
+    slot; 5/4 resampler: 120 taps at 1 MHz, pass 62.5 kHz, stop by 100 kHz)."""
+    return (_kaiser_lowpass(0.5 * 0.82 / n_channels, 12 * n_channels, 7.0) * n_channels,
+            _kaiser_lowpass(0.081, 120, 7.0) * 5)
+
+
+def detect_share(sf: int) -> float:
+    """The least share of a window's energy in its peak and the larger
+    neighbour that counts as a preamble: 0.6 of what a packet at its SF's
+    demodulation floor (-7.5 dB at SF7, 2.5 dB lower per SF) shows when its
+    tone falls between two bins; 2 to 3 times what noise alone shows."""
+    g = 10 ** ((-7.5 - 2.5 * (sf - 7)) / 10)
+    return 0.6 * 0.81 * g / (1 + g)
+
+
+def n_data_symbols(sf: int, length, de: bool):
+    """Header block + payload symbols at CR 4/5 with CRC (ints or arrays)."""
+    rows = 4 * (sf - 2 * de)
+    blocks = (8 * length - 4 * sf + 28 + 16 + rows - 1) // rows
+    return 8 + blocks * (blocks > 0) * 5
+
+
+def record_counters(frame: np.ndarray) -> dict:
+    """The eight counts of one landed record block's header (``Stage.counters``:
+    what ``TpuKernel`` adds to the ``emit`` span while tracing)."""
+    if len(frame) < HEADER_WORDS or int(frame[0]) != MAGIC:
+        return {}
+    return {f"lora_{name}": int(frame[1 + i]) for i, name in enumerate(_COUNTERS)}
+
+
+def parse_records(block: np.ndarray) -> tuple:
+    """One record block -> ``(header dict, [packet dict])`` on the host."""
+    block = np.asarray(block).astype(np.int32)
+    head = record_counters(block)
+    if not head:
+        return {}, []
+    n = min(head["lora_emitted"], (len(block) - HEADER_WORDS) // ENTRY_WORDS)
+    packets = []
+    for e in block[HEADER_WORDS:HEADER_WORDS + n * ENTRY_WORDS].reshape(n, ENTRY_WORDS):
+        f = e[4:8].view(np.float32)
+        length = int(e[8])
+        packets.append({
+            "channel": int(e[0]), "sf": int(e[1]), "start": int(e[2]), "end": int(e[3]),
+            "cfo_hz": float(f[0]), "timing": float(f[1]), "snr_db": float(f[2]),
+            "share": float(f[3]), "length": length, "crc_ok": bool(e[9]),
+            "n_sym": int(e[10]),
+            "payload": e[16:].astype("<i4").tobytes()[:max(0, min(length, 256))]})
+    return head, packets
+
+
+def _crc_table() -> np.ndarray:
+    """``T[d, b]``: CRC-16/CCITT (initial value 0) of byte ``b`` followed by
+    ``d`` zero bytes; the CRC of a message is the XOR of its bytes' rows."""
+    t = np.zeros((256, 256), np.int32)
+    t[0] = [coding.crc16(bytes([b])) for b in range(256)]
+    for d in range(1, 256):
+        c = t[d - 1]
+        for _ in range(8):
+            c = np.where(c & 0x8000, ((c << 1) ^ 0x1021) & 0xFFFF, (c << 1) & 0xFFFF)
+        t[d] = c
+    return t
+
+
+def lora_gw_stages(n_channels: int = 8, sfs: Sequence[int] = (7, 8, 9, 10, 11, 12),
+                   max_payload: Optional[Dict[int, int]] = None,
+                   ldro_from_sf: int = 11, done_slots: int = 16, chan_taps=None,
+                   resamp_taps=None) -> List[Stage]:
+    """The gateway as a one-stage pipeline (see the module docstring).
+
+    ``max_payload``: the largest PHYPayload decoded per SF (default RP002's
+    EU868 limits; a header that announces more is let go);
+    ``done_slots``: packets of one SF that may end in one frame (more are
+    counted as overflow); the taps
+    default to :func:`front_end_taps`. A frame must hold a whole number of
+    quarter symbols of the largest SF after the front end: ``frame * 5 / (4 *
+    n_channels)`` divisible by ``2^sf_max / 2`` (262144 at the defaults,
+    16384 for four channels up to SF9)."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    C, sfs = int(n_channels), tuple(int(s) for s in sfs)
+    max_payload = dict(max_payload or EU868_MAX_PAYLOAD)
+    D = int(done_slots)
+    h_bank, h_rs = front_end_taps(C)
+    chan = channelizer_stage(C, h_bank if chan_taps is None else chan_taps,
+                             precision=None if _PRECISION == "f32" else "bf16")
+    rs = resample_stage(5, 4, h_rs if resamp_taps is None else resamp_taps)
+    slot_of = np.array([(c - C // 2 + 1) % C for c in range(C)])
+    rot = np.exp(2j * np.pi * np.arange(2 * C) / (2 * C)).astype(np.complex64)
+    S_max = OS << max(sfs)
+    H_max = 4 * S_max
+    crc_t = _crc_table()
+    whitening = np.frombuffer(coding.whiten(bytes(256)), np.uint8).astype(np.int32)
+    flip = np.zeros(8, np.int32)
+    flip[[0b111, 0b011, 0b101, 0b110]] = [1, 2, 4, 8]
+    prec = _PRECISION
+
+    def wrap(k, n):
+        return (k + n // 2) % n - n // 2
+
+    def gray(x):
+        return x ^ (x >> 1)
+
+    def deinterleave(q, rows):
+        """``[..., n_sym]`` symbol values -> ``[..., rows]`` codewords."""
+        n_sym = q.shape[-1]
+        sh = (np.arange(rows)[:, None] - np.arange(n_sym)[None, :]) % rows
+        bits = (q[..., None, :] >> jnp.asarray(sh, jnp.int32)) & 1
+        return jnp.sum(bits << jnp.arange(n_sym, dtype=jnp.int32), axis=-1)
+
+    def header(syms8, sf):
+        """``[..., 8]`` -> (ok, length, nibbles ``[..., sf - 2]``)."""
+        q = gray(((syms8 + 2) >> 2) % (1 << (sf - 2)))
+        cw = deinterleave(q, sf - 2)
+        d = [(cw >> i) & 1 for i in range(7)]
+        syn = (d[4] ^ d[0] ^ d[1] ^ d[2]) | ((d[5] ^ d[0] ^ d[1] ^ d[3]) << 1) \
+            | ((d[6] ^ d[0] ^ d[2] ^ d[3]) << 2)
+        nib = (cw & 0xF) ^ jnp.asarray(flip)[syn]
+        n0, n1, n2 = nib[..., 0], nib[..., 1], nib[..., 2]
+        c4 = ((n0 >> 3) ^ (n0 >> 2) ^ (n0 >> 1) ^ n0) & 1
+        c3 = ((n0 >> 3) ^ (n1 >> 3) ^ (n1 >> 2) ^ (n1 >> 1) ^ n2) & 1
+        c2 = ((n0 >> 2) ^ (n1 >> 3) ^ n1 ^ (n2 >> 3) ^ (n2 >> 1)) & 1
+        c1 = ((n0 >> 1) ^ (n1 >> 2) ^ n1 ^ (n2 >> 2) ^ (n2 >> 1) ^ n2) & 1
+        c0 = (n0 ^ (n1 >> 1) ^ (n2 >> 3) ^ (n2 >> 2) ^ (n2 >> 1) ^ n2) & 1
+        length = (n0 << 4) | n1
+        ok = ((nib[..., 3] & 1) == c4) \
+            & (nib[..., 4] == ((c3 << 3) | (c2 << 2) | (c1 << 1) | c0)) \
+            & (n2 == 0b0011) & (length >= 1) & (length <= max_payload[sf])
+        return ok, length, nib
+
+    def spectrum(w, ref, sf):
+        """Windows ``[..., S]`` times ``ref`` -> (X, P, folded Q, peak bin)."""
+        n = 1 << sf
+        X = lora_dechirp_dft(w, sf, OS, ref=ref, precision=prec)
+        P = jnp.real(X) ** 2 + jnp.imag(X) ** 2
+        Q = P[..., :n] + P[..., n:]
+        return X, P, Q, jnp.argmax(Q, axis=-1).astype(jnp.int32)
+
+    def at(a, idx):
+        return jnp.take_along_axis(a, idx[..., None], axis=-1)[..., 0]
+
+    def branch_sizes(sf):
+        n = 1 << sf
+        S = OS * n
+        de = sf >= ldro_from_sf
+        return n, S, S // 4, 4 * S, de, int(n_data_symbols(sf, max_payload[sf], de))
+
+    def init_branches(sf):
+        n, S, hop, H, de, max_sym = branch_sizes(sf)
+        zi = lambda: jnp.zeros(C, jnp.int32)
+        zf = lambda: jnp.zeros(C, jnp.float32)
+        return dict(st=zi(), pos=jnp.full(C, H, jnp.int32), cnt=zi(), nsym=zi(),
+                    need=zi(), start=zi(), k1=zi(), nu=zf(), eps=zf(), cfo=zf(),
+                    tau=zf(), ssum=zf(), syms=jnp.zeros((C, max_sym), jnp.int32))
+
+    def run_sf(sf, b, ext):
+        """One SF over one frame: ``ext`` = ``[C, H + L]`` (history + frame)."""
+        n, S, hop, H, de, max_sym = branch_sizes(sf)
+        T = ext.shape[1]
+        L = T - H
+        down = lora_downchirp(sf, OS)
+        down_c, up_c = jnp.asarray(down.astype(np.complex64)), \
+            jnp.asarray(np.conj(down).astype(np.complex64))
+        with jax.named_scope("detect"):
+            nw = (T - S) // hop + 1
+            blocks = ext[:, :(nw + 3) * hop].reshape(C, nw + 3, hop)
+            win = jnp.concatenate([blocks[:, q:q + nw] for q in range(4)], axis=2)
+            X, P, Q, kb = spectrum(win, down_c, sf)
+            share = (at(Q, kb) + jnp.maximum(at(Q, (kb - 1) % n), at(Q, (kb + 1) % n))) \
+                / jnp.maximum(jnp.sum(Q, axis=-1), 1e-30)
+            back = lambda a, m: jnp.pad(a, ((0, 0), (4 * m, 0)))[:, :nw]
+            cond = jnp.arange(nw)[None, :] >= 12
+            for m in range(4):
+                cond &= (back(share, m) > detect_share(sf)) \
+                    & (jnp.abs(wrap(back(kb, m) - kb, n)) <= 1)
+            Xb = jnp.pad(X, ((0, 0), (4, 0), (0, 0)))[:, :nw]
+            z = at(X, kb) * jnp.conj(at(Xb, kb)) + at(X, kb + n) * jnp.conj(at(Xb, kb + n))
+            eps_all = jnp.arctan2(jnp.imag(z), jnp.real(z)) / (2 * np.pi)
+            cond_p = jnp.pad(cond, ((0, 0), (0, 4)))
+            kb_p = jnp.pad(kb, ((0, 0), (0, 4)))
+            eps_p = jnp.pad(eps_all, ((0, 0), (0, 4)))
+        i_s = jnp.arange(S, dtype=jnp.int32)
+
+        def window(rows, pos, up, nu, data, tau):
+            """The aligned window of each lane -> (bin, Jacobsen, peak share).
+            ``data`` lanes know the rest of their timing ``tau``: the two parts
+            of their symbol add as amplitudes, the second turned by ``tau``."""
+            w = jax.vmap(lambda row, p: lax.dynamic_slice(row, (p,), (S,)))(
+                rows, jnp.clip(pos, 0, T - S))
+            nu_i = jnp.floor(nu)
+            ph = ((nu_i.astype(jnp.int32)[:, None] * i_s[None, :]) % S).astype(jnp.float32) / S \
+                + (nu - nu_i)[:, None] * (i_s.astype(jnp.float32) / S)[None, :]
+            rotn = jnp.exp(-2j * np.pi * ph.astype(jnp.complex64))
+            ref = jnp.where(up[:, None], up_c[None, :], down_c[None, :]) * rotn
+            X, P, Q, k = spectrum(w, ref, sf)
+            Z = X[..., :n] + X[..., n:] * jnp.exp(
+                2j * np.pi * tau.astype(jnp.complex64))[:, None]
+            Q = jnp.where(data[:, None], jnp.real(Z) ** 2 + jnp.imag(Z) ** 2, Q)
+            k = jnp.argmax(Q, axis=-1).astype(jnp.int32)
+            kp = k + n * (at(P, k + n) > at(P, k))
+            xm, x0, xp = at(X, (kp - 1) % S), at(X, kp), at(X, (kp + 1) % S)
+            den = 2 * x0 - xm - xp
+            jac = jnp.real((xm - xp) * jnp.conj(den)) \
+                / jnp.maximum(jnp.real(den) ** 2 + jnp.imag(den) ** 2, 1e-30)
+            return k, jac, at(Q, k) / jnp.maximum(jnp.sum(P, axis=-1), 1e-30)
+
+        def step(carry, _):
+            b, cnts, done, n_done = carry
+            with jax.named_scope("sync"):
+                st, pos = b["st"], b["pos"]
+                fits = pos + S <= T
+                idle = (st == _IDLE) & fits
+                # -- detection: the first of four windows that sees a preamble
+                j0 = pos // hop
+                idx = j0[:, None] + jnp.arange(4)[None, :]
+                c4 = jnp.take_along_axis(cond_p, jnp.clip(idx, 0, nw + 3), axis=1)
+                jt = j0 + jnp.argmax(c4, axis=1).astype(jnp.int32)
+                trig = idle & jnp.any(c4, axis=1)
+                kd, ed = at(kb_p, jnp.clip(jt, 0, nw + 3)), at(eps_p, jnp.clip(jt, 0, nw + 3))
+                # -- the aligned window of every branch past detection
+                up = (st == _DN1) | (st == _DN2)
+                nu_use = jnp.where(st == _DATA, b["cfo"] + b["tau"], b["nu"])
+            with jax.named_scope("demod"):
+                k, jac, shr = window(ext, pos, up, nu_use, st == _DATA, b["tau"])
+            with jax.named_scope("sync"):
+                kw = wrap(k, n)
+                in_pre, in_sw2 = (st == _PRE) & fits, (st == _SW2) & fits
+                first = in_pre & (b["cnt"] == 0) & (jnp.abs(kw) <= 1)
+                nu = jnp.where(first, kw.astype(jnp.float32) + jac, b["nu"])
+                kw = jnp.where(first, 0, kw)
+                stay = in_pre & (jnp.abs(kw) <= 1) & (b["cnt"] < MAX_PREAMBLE_WALK)
+                want = jnp.where(st == _PRE, (SYNC_WORD >> 4) * 8, (SYNC_WORD & 0xF) * 8)
+                adv = (in_pre | in_sw2) & ~stay & (jnp.abs(kw - want) <= 1)
+                in_dn1, in_dn2 = (st == _DN1) & fits, (st == _DN2) & fits
+                synced = in_dn2 & (jnp.abs(wrap(k - b["k1"], n)) <= 1)
+                g = kw.astype(jnp.float32) + jac
+                cfo_new = b["eps"] + jnp.floor(b["nu"] + g / 2 - b["eps"] + 0.5)
+                sh = jnp.floor(g + 0.5).astype(jnp.int32)
+                pos_data = pos + S + S // 4 + sh
+                in_data = (st == _DATA) & fits
+                nsym1 = b["nsym"] + in_data
+                syms = jnp.where((jnp.arange(max_sym)[None, :] == b["nsym"][:, None])
+                                 & in_data[:, None], k[:, None], b["syms"])
+                at8 = in_data & (nsym1 == 8)
+                hok, length, _ = header(syms[:, :8], sf)
+                need = jnp.where(at8 & hok, n_data_symbols(sf, length, de), b["need"])
+                hbad = at8 & ~hok
+                complete = in_data & (nsym1 >= 8) & (nsym1 == need) & ~hbad
+                ssum = b["ssum"] + jnp.where(in_data, shr, 0.0)
+                to_idle = ((in_pre | in_sw2) & ~stay & ~adv) \
+                    | (in_dn2 & ~synced) | hbad | complete
+                nxt = pos + S
+                # an idle branch moves on to the first window it has not looked at
+                # (the last of a frame's do not fit and wait for the next frame)
+                new_pos = jnp.where(trig, jt * hop + (-OS * kd) % S, jnp.where(
+                    synced, pos_data, jnp.where(
+                        idle, pos + hop * jnp.minimum(4, nw - j0), jnp.where(
+                            to_idle, (nxt + hop - 1) // hop * hop, nxt))))
+                new_st = jnp.where(trig, _PRE, jnp.where(
+                    to_idle, _IDLE, jnp.where(adv & in_pre, _SW2, jnp.where(
+                        adv & in_sw2, _DN1, jnp.where(in_dn1, _DN2, jnp.where(
+                            synced, _DATA, st))))))
+                # -- a finished packet leaves the scan through a done row
+                row = n_done + jnp.cumsum(complete.astype(jnp.int32)) - 1
+                row = jnp.where(complete & (row < D), row, D)
+                end = nxt - H
+                done = dict(
+                    syms=done["syms"].at[row].set(syms, mode="drop"),
+                    ints=done["ints"].at[row].set(jnp.stack(
+                        [jnp.arange(C, dtype=jnp.int32), b["start"], end, nsym1, need],
+                        axis=1), mode="drop"),
+                    flts=done["flts"].at[row].set(jnp.stack(
+                        [b["cfo"], b["tau"], ssum / jnp.maximum(nsym1, 1)], axis=1),
+                        mode="drop"))
+                n_fin = jnp.sum(complete.astype(jnp.int32))
+                new_b = dict(
+                    st=jnp.where(fits, new_st, st).astype(jnp.int32),
+                    pos=jnp.where(fits, new_pos, pos).astype(jnp.int32),
+                    cnt=jnp.where(trig, 0, b["cnt"] + stay),
+                    nsym=jnp.where(synced, 0, nsym1), need=jnp.where(synced, 8, need),
+                    start=jnp.where(synced, pos_data - 12 * S - S // 4 - H, b["start"]),
+                    k1=jnp.where(in_dn1, k, b["k1"]),
+                    nu=jnp.where(trig, 0.0, nu), eps=jnp.where(trig, ed, b["eps"]),
+                    cfo=jnp.where(synced, cfo_new, b["cfo"]),
+                    tau=jnp.where(synced, (sh.astype(jnp.float32) - g) / OS, b["tau"]),
+                    ssum=jnp.where(synced, 0.0, ssum), syms=syms)
+                cnts = cnts + jnp.stack([
+                    jnp.sum(trig), jnp.sum(synced), jnp.sum(at8 & hok),
+                    jnp.minimum(n_fin, jnp.maximum(D - n_done, 0)), 0, 0, jnp.sum(in_data),
+                    jnp.maximum(n_done + n_fin - D, 0)
+                    - jnp.maximum(n_done - D, 0)]).astype(jnp.int32)
+            return (new_b, cnts, done, n_done + n_fin), None
+
+        done0 = dict(syms=jnp.zeros((D, max_sym), jnp.int32),
+                     ints=jnp.zeros((D, 5), jnp.int32), flts=jnp.zeros((D, 3), jnp.float32))
+        (b, cnts, done, n_done), _ = lax.scan(
+            step, (b, jnp.zeros(8, jnp.int32), done0, jnp.int32(0)), None,
+            length=-(-L // S) + 6)
+        b = dict(b, pos=b["pos"] - L, start=b["start"] - L)
+        cnts = cnts.at[5].set(jnp.sum((b["st"] != _IDLE).astype(jnp.int32)))
+        with jax.named_scope("decode"):
+            entries, crc_ok = decode(sf, de, max_sym, done, jnp.minimum(n_done, D))
+        valid = jnp.arange(D) < jnp.minimum(n_done, D)
+        cnts = cnts.at[4].set(jnp.sum(valid & ~crc_ok))
+        key = jnp.where(valid, (done["ints"][:, 2] + H_max) * 128
+                        + sfs.index(sf) * 16 + done["ints"][:, 0], jnp.int32(2 ** 31 - 1))
+        return b, cnts, entries, key
+
+    def decode(sf, de, max_sym, done, n_done):
+        """Done rows -> (entries ``[D, 80]`` int32, CRC verdicts)."""
+        n, rows = 1 << sf, (sf - 2 if de else sf)
+        syms, ints, flts = done["syms"], done["ints"], done["flts"]
+        _, length, nib0 = header(syms[:, :8], sf)
+        length = jnp.clip(length, 1, max_payload[sf])
+        body = syms[:, 8:].reshape(D, -1, 5)
+        if de:
+            body = ((body + 2) >> 2) % (n >> 2)
+        cw = deinterleave(gray(body), rows)                       # [D, blocks, rows]
+        nib = jnp.concatenate([nib0[:, 5:], (cw & 0xF).reshape(D, -1)], axis=1)
+        nib = jnp.pad(nib, ((0, 0), (0, max(0, 2 * 258 - nib.shape[1]))))[:, :2 * 258]
+        byt = nib[:, 0::2] | (nib[:, 1::2] << 4)                  # [D, 258]
+        i = jnp.arange(256)[None, :]
+        inside = i < length[:, None]
+        pay = jnp.where(inside, byt[:, :256] ^ jnp.asarray(whitening)[None, :], 0)
+        dist = jnp.clip(length[:, None] - 1 - i, 0, 255)
+        crc = lax.reduce(jnp.where(inside, jnp.asarray(crc_t)[dist, pay], 0),
+                         jnp.int32(0), lax.bitwise_xor, (1,))
+        sent = at(byt, length) | (at(byt, length + 1) << 8)
+        crc_ok = crc == sent
+        words = pay.reshape(D, 64, 4)
+        words = words[..., 0] | (words[..., 1] << 8) | (words[..., 2] << 16) \
+            | (words[..., 3] << 24)
+        share = flts[:, 2]
+        snr = 10 * jnp.log10(jnp.maximum(share, 1e-9) / jnp.maximum(1 - share, 1e-9))
+        f32 = lambda v: lax.bitcast_convert_type(v.astype(jnp.float32), jnp.int32)
+        fields = jnp.stack([
+            ints[:, 0], jnp.full(D, sf, jnp.int32), ints[:, 1], ints[:, 2],
+            f32(flts[:, 0] * (125e3 / n)), f32(flts[:, 1]), f32(snr), f32(share),
+            length, crc_ok.astype(jnp.int32), ints[:, 3]], axis=1)
+        return jnp.concatenate([fields, jnp.zeros((D, 5), jnp.int32), words], axis=1), crc_ok
+
+    def fn(carry, x):
+        c_chan, c_rs, hist, branches = carry
+        n_words = x.shape[0] // 8
+        assert (x.shape[0] * 5) % (4 * C * (S_max // 4)) == 0, \
+            f"frame {x.shape[0]} does not hold whole quarter symbols of SF{max(sfs)}"
+        with jax.named_scope("chan"):
+            xr = (x.reshape(-1, 2 * C) * jnp.asarray(rot)[None, :]).reshape(-1)
+            c_chan, y = chan.fn(c_chan, xr)
+            y = y.reshape(-1, C).T[jnp.asarray(slot_of)]                 # [C, t]
+        with jax.named_scope("resamp"):
+            c_rs, y = jax.vmap(rs.fn)(c_rs, y)                           # [C, L]
+        ext = jnp.concatenate([hist, y], axis=1)
+        new_branches, cnts, entries, keys = [], jnp.zeros(8, jnp.int32), [], []
+        for sf, b in zip(sfs, branches):
+            S = OS << sf
+            b, c, e, k = run_sf(sf, b, ext[:, H_max - 4 * S:])
+            new_branches.append(b)
+            cnts = cnts + c
+            entries.append(e)
+            keys.append(k)
+        with jax.named_scope("pack"):
+            cap = min(MAX_ENTRIES, (n_words - HEADER_WORDS) // ENTRY_WORDS)
+            entries, keys = jnp.concatenate(entries), jnp.concatenate(keys)
+            order = jnp.argsort(keys)[:cap]
+            n_emit = jnp.minimum(cnts[3], cap)
+            picked = jnp.where((jnp.arange(len(order)) < n_emit)[:, None],
+                               entries[order], 0)
+            head = jnp.zeros(HEADER_WORDS, jnp.int32).at[:11].set(jnp.concatenate([
+                jnp.array([MAGIC], jnp.int32), cnts.at[3].set(n_emit)
+                .at[7].add(cnts[3] - n_emit), jnp.array([C, len(sfs)], jnp.int32)]))
+            out = jnp.concatenate([head, picked.reshape(-1)])
+            out = jnp.pad(out, (0, n_words - out.shape[0]))
+        return (c_chan, c_rs, ext[:, ext.shape[1] - H_max:], tuple(new_branches)), out
+
+    def init_carry(dtype):
+        c_rs = rs.init_carry(np.complex64)
+        return (chan.init_carry(np.complex64),
+                jnp.zeros((C,) + tuple(c_rs.shape), jnp.complex64),
+                jnp.zeros((C, H_max), jnp.complex64),
+                tuple(init_branches(sf) for sf in sfs))
+
+    mult = int(np.lcm(4 * C, 8))
+    return [Stage(fn, init_carry, ratio=Fraction(1, 8), out_dtype=np.int32,
+                  frame_multiple=mult, name="lora_gw", counters=record_counters)]

@@ -27,7 +27,7 @@ from . import mxu_fft
 __all__ = ["Stage", "Pipeline", "FanoutPipeline", "MergeStage", "DagPipeline",
            "apply_merge_stage", "add_merge_stage", "interleave_merge_stage",
            "concat_merge_stage", "fir_stage", "fft_stage",
-           "mag2_stage", "log10_stage",
+           "mag2_stage", "log10_stage", "lora_downchirp", "lora_dechirp_dft",
            "rotator_stage", "quad_demod_stage", "apply_stage", "fftshift_stage",
            "decimate_stage", "moving_avg_stage"]
 
@@ -1962,18 +1962,34 @@ def channelizer_stage(n_channels: int, taps=None, name: str = "channelizer",
                  route=(impl, None, precision))
 
 
-def lora_demod_stage(sf: int, name: str = "lora_demod") -> Stage:
-    """LoRa dechirp + batched FFT + argmax as a stage: frames of k·2^sf complex chips →
-    k int32 symbol values (the `FftDemod` hot loop of the LoRa example, fused).
-    The downchirp is generated in-trace (no HBM table)."""
+def lora_downchirp(sf: int, os: int = 1) -> np.ndarray:
+    """The conjugate of the base up-chirp of ``2^sf`` chips at ``os`` samples a
+    chip (complex128; float32 cannot hold the quadratic phase of SF12)."""
     n = 1 << sf
-    k_idx = np.arange(n)
-    ph = 2 * np.pi * ((k_idx * k_idx) / (2 * n) + k_idx * (-0.5))
-    down = np.exp(-1j * ph).astype(np.complex64)    # conj(upchirp)
+    u = np.arange(n * os) / os
+    return np.exp(-2j * np.pi * (u * u / (2 * n) - u / 2))
+
+
+def lora_dechirp_dft(blocks: jnp.ndarray, sf: int, os: int = 1, ref=None,
+                     precision: Optional[str] = None) -> jnp.ndarray:
+    """``[..., os * 2^sf]`` samples of one symbol each → their dechirped
+    spectra: the one dechirp-DFT form of the LoRa receivers
+    (``lora_demod_stage``, ``models/lora/rx_stages.py``). ``ref``: what the
+    samples are multiplied by (default the down-chirp; the gateway passes the
+    up-chirp for its down-chirp symbols, and a CFO rotation folded in). The DFT
+    is ``mxu_fft.fft``: matmuls on the TPU, ``jnp.fft`` elsewhere."""
+    if ref is None:
+        ref = jnp.asarray(lora_downchirp(sf, os).astype(np.complex64))
+    return mxu_fft.fft(blocks * ref, precision=precision)
+
+
+def lora_demod_stage(sf: int, name: str = "lora_demod") -> Stage:
+    """LoRa dechirp + batched DFT + argmax as a stage: frames of k·2^sf complex chips →
+    k int32 symbol values (the `FftDemod` hot loop of the LoRa example, fused)."""
+    n = 1 << sf
 
     def fn(carry, x):
-        blocks = x.reshape(-1, n) * jnp.asarray(down)[None, :]
-        spec = jnp.abs(jnp.fft.fft(blocks, axis=1))
+        spec = jnp.abs(lora_dechirp_dft(x.reshape(-1, n), sf))
         return carry, jnp.argmax(spec, axis=1).astype(jnp.int32)
 
     return Stage(fn, lambda d: jnp.zeros(()), Fraction(1, n), np.int32, n, name)

@@ -13,7 +13,7 @@ import pytest
 
 _ROOT = Path(__file__).resolve().parent.parent
 _PHASES = ["device", "streamed", "streamed", "fm_app", "serve", "pallas",
-           "wlan_rx", "multichip", "summary"]
+           "wlan_rx", "lora_gw", "multichip", "summary"]
 
 
 def _run(*args, timeout=600):
@@ -32,7 +32,7 @@ def test_rehearsal_passes_every_phase_on_cpu():
     assert lines[-1] == {
         "complete": False, "rehearse": True,
         "phases": ["device", "streamed", "fm_app", "serve", "pallas",
-                   "wlan_rx", "multichip"],
+                   "wlan_rx", "lora_gw", "multichip"],
         "skipped": [], "device": {"platform": "cpu", "kind": "cpu",
                                   "count": lines[0]["device_count"]}}
     assert r.stdout.rstrip().splitlines()[-1].startswith('{"complete": false')
@@ -59,7 +59,7 @@ def test_a_phase_subset_names_what_it_skipped_and_prints_no_ok_line():
     last = json.loads(r.stdout.rstrip().splitlines()[-1])
     assert last["complete"] is False and "ok" not in last
     assert last["phases"] == ["device", "streamed"]
-    assert last["skipped"] == ["fm_app", "serve", "pallas", "wlan_rx"]
+    assert last["skipped"] == ["fm_app", "serve", "pallas", "wlan_rx", "lora_gw"]
 
 
 def test_without_rehearsal_a_cpu_host_is_refused():
