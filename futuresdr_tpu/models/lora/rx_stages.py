@@ -28,7 +28,12 @@ sample lies, as delayed by the front end's filters). Per frame, by scope:
 ``sync`` / ``demod``  per SF one ``lax.scan`` over the frame's symbol times;
             each step every branch looks at ONE window of its own grid: idle
             branches at four detection windows (a look-up), the others at the
-            aligned window their state asks for. The grid moves by ``-2k``
+            aligned window their state asks for; the windows of all lanes,
+            each starting anywhere, are fetched by ONE kernel a step
+            (``_window_fetch``: whole tiles by DMA, then a rotation; a
+            ``vmap(dynamic_slice)`` is a loop of a trip a lane inside every
+            step), and the peak's neighbours are read for all lanes at once,
+            so a step's body holds no loop. The grid moves by ``-2k``
             samples so that the preamble dechirps to bin 0, its first window
             gives the rest ``nu`` (Jacobsen), it walks to the sync word (24,
             32 for 0x34), the two whole down-chirps against the up-chirp give
@@ -100,6 +105,75 @@ _COUNTERS = ("detected", "synced", "header_ok", "emitted", "crc_bad", "in_flight
              "symbols", "overflow")
 #: RP002 EU863-870: the largest PHYPayload of DR0 ... DR5
 EU868_MAX_PAYLOAD = {12: 64, 11: 64, 10: 64, 9: 128, 8: 255, 7: 255}
+
+
+def _interpret() -> bool:
+    """Mosaic on a TPU backend, the interpreter elsewhere (the convention of
+    ``ops/pallas_kernels.py``; a compile for a described chip patches it)."""
+    import jax
+    return jax.default_backend() != "tpu"
+
+
+def _window_fetch(ext, S: int):
+    """``fetch(pos)`` -> ``ext[c, p:p + S]`` of every lane ``c``, ``p = clip(pos,
+    0, T - S)``, bit for bit, as ONE kernel over all lanes and both planes.
+
+    A gather of slices that start anywhere (``vmap(lax.dynamic_slice)``) is on
+    this compiler a loop of one trip a lane and a plane inside whatever calls
+    it (``docs/tpu_notes.md``): in a scan step more than half of what the step
+    ran. Here the planes are laid out once a frame as rows of 128 samples
+    (``[2, lanes, rows, 128]``, at least 1024 samples of zeros behind the
+    end); per lane the kernel copies the whole ``(8, 128)`` tiles that hold
+    the window from HBM (all lanes' copies in flight at once), rotates its
+    rows by ``p % 128`` lanes and takes each output row from two neighbouring
+    rows."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    C, T = ext.shape
+    if S % 128:
+        raise ValueError(f"a window of {S} samples is not whole rows of 128")
+    n = S // 128
+    n_copy = 8 * max(2, S // 1024 + 1)          # rows: the tiles S samples can touch
+    rows = jnp.pad(jnp.stack([jnp.real(ext), jnp.imag(ext)]),
+                   ((0, 0), (0, 0), (0, (-T) % 1024 + 1024))).reshape(2, C, -1, 128)
+
+    def kernel(tile_ref, off_ref, rows_ref, out_ref, buf, sem):
+        copies = []
+        for c in range(C):
+            first = pl.multiple_of(tile_ref[c] * 8, 8)
+            copies.append(pltpu.make_async_copy(
+                rows_ref.at[:, c, pl.ds(first, n_copy)], buf.at[:, c], sem.at[c]))
+            copies[-1].start()
+        lane = lax.broadcasted_iota(jnp.int32, (n, 128), 1)
+        for c, copy in enumerate(copies):
+            copy.wait()
+            row, r = off_ref[c] // 128, off_ref[c] % 128
+            for plane in range(2):
+                a = pltpu.roll(buf[plane, c, pl.ds(row, n), :], 128 - r, axis=1)
+                b = pltpu.roll(buf[plane, c, pl.ds(row + 1, n), :], 128 - r, axis=1)
+                out_ref[plane, c] = jnp.where(lane < 128 - r, a, b)
+
+    call = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((2, C, n, 128), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(1,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((2, C, n, 128), lambda i, *_: (0, 0, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((2, C, n_copy, 128), jnp.float32),
+                            pltpu.SemaphoreType.DMA((C,))]),
+        interpret=_interpret(), name="lora_window_fetch")
+
+    def fetch(pos):
+        p = jnp.clip(pos, 0, T - S)
+        x = call(p // 1024, p % 1024, rows).reshape(2, C, S)
+        return lax.complex(x[0], x[1])
+
+    return fetch
 
 
 def _kaiser_lowpass(cutoff: float, n_taps: int, beta: float) -> np.ndarray:
@@ -203,8 +277,6 @@ def lora_gw_stages(n_channels: int = 8, sfs: Sequence[int] = (7, 8, 9, 10, 11, 1
     H_max = 4 * S_max
     crc_t = _crc_table()
     whitening = np.frombuffer(coding.whiten(bytes(256)), np.uint8).astype(np.int32)
-    flip = np.zeros(8, np.int32)
-    flip[[0b111, 0b011, 0b101, 0b110]] = [1, 2, 4, 8]
     prec = _PRECISION
 
     def wrap(k, n):
@@ -227,7 +299,10 @@ def lora_gw_stages(n_channels: int = 8, sfs: Sequence[int] = (7, 8, 9, 10, 11, 1
         d = [(cw >> i) & 1 for i in range(7)]
         syn = (d[4] ^ d[0] ^ d[1] ^ d[2]) | ((d[5] ^ d[0] ^ d[1] ^ d[3]) << 1) \
             | ((d[6] ^ d[0] ^ d[2] ^ d[3]) << 2)
-        nib = (cw & 0xF) ^ jnp.asarray(flip)[syn]
+        # the data bit a syndrome points at, as compares: a look-up in a table of
+        # eight is a gather, 0.36 ms a frame in the scan steps (PERF.md, PR 34)
+        nib = (cw & 0xF) ^ ((syn == 0b111) * 1 | (syn == 0b011) * 2 | (syn == 0b101) * 4
+                            | (syn == 0b110) * 8)
         n0, n1, n2 = nib[..., 0], nib[..., 1], nib[..., 2]
         c4 = ((n0 >> 3) ^ (n0 >> 2) ^ (n0 >> 1) ^ n0) & 1
         c3 = ((n0 >> 3) ^ (n1 >> 3) ^ (n1 >> 2) ^ (n1 >> 1) ^ n2) & 1
@@ -288,20 +363,30 @@ def lora_gw_stages(n_channels: int = 8, sfs: Sequence[int] = (7, 8, 9, 10, 11, 1
             Xb = jnp.pad(X, ((0, 0), (4, 0), (0, 0)))[:, :nw]
             z = at(X, kb) * jnp.conj(at(Xb, kb)) + at(X, kb + n) * jnp.conj(at(Xb, kb + n))
             eps_all = jnp.arctan2(jnp.imag(z), jnp.real(z)) / (2 * np.pi)
-            cond_p = jnp.pad(cond, ((0, 0), (0, 4)))
-            kb_p = jnp.pad(kb, ((0, 0), (0, 4)))
-            eps_p = jnp.pad(eps_all, ((0, 0), (0, 4)))
+            # what an idle branch at window j reads, worked out here for every j
+            # at once, so that a scan step reads two words: is a preamble seen in
+            # j ... j + 3 (bit 0), the first such window (bits 1, 2), its bin
+            # (from bit 3); and its phase against the window a symbol earlier
+            pad4 = lambda a: jnp.pad(a, ((0, 0), (0, 4)))
+            cond_p = pad4(cond)
+            seen4 = jnp.stack([cond_p[:, i:i + nw + 1] for i in range(4)], axis=2)
+            first = jnp.argmax(seen4, axis=2).astype(jnp.int32)
+            jt_all = jnp.arange(nw + 1)[None, :] + first
+            det_code = jnp.any(seen4, axis=2).astype(jnp.int32) | (first << 1) \
+                | (jnp.take_along_axis(pad4(kb), jt_all, axis=1) << 3)
+            det_eps = jnp.take_along_axis(pad4(eps_all), jt_all, axis=1)
         i_s = jnp.arange(S, dtype=jnp.int32)
+        frac = jnp.asarray((np.arange(S) / S).astype(np.float32))
+        fetch = _window_fetch(ext, S)
 
-        def window(rows, pos, up, nu, data, tau):
+        def window(pos, up, nu, data, tau):
             """The aligned window of each lane -> (bin, Jacobsen, peak share).
             ``data`` lanes know the rest of their timing ``tau``: the two parts
             of their symbol add as amplitudes, the second turned by ``tau``."""
-            w = jax.vmap(lambda row, p: lax.dynamic_slice(row, (p,), (S,)))(
-                rows, jnp.clip(pos, 0, T - S))
+            w = fetch(pos)
             nu_i = jnp.floor(nu)
             ph = ((nu_i.astype(jnp.int32)[:, None] * i_s[None, :]) % S).astype(jnp.float32) / S \
-                + (nu - nu_i)[:, None] * (i_s.astype(jnp.float32) / S)[None, :]
+                + (nu - nu_i)[:, None] * frac[None, :]
             rotn = jnp.exp(-2j * np.pi * ph.astype(jnp.complex64))
             ref = jnp.where(up[:, None], up_c[None, :], down_c[None, :]) * rotn
             X, P, Q, k = spectrum(w, ref, sf)
@@ -309,31 +394,47 @@ def lora_gw_stages(n_channels: int = 8, sfs: Sequence[int] = (7, 8, 9, 10, 11, 1
                 2j * np.pi * tau.astype(jnp.complex64))[:, None]
             Q = jnp.where(data[:, None], jnp.real(Z) ** 2 + jnp.imag(Z) ** 2, Q)
             k = jnp.argmax(Q, axis=-1).astype(jnp.int32)
-            kp = k + n * (at(P, k + n) > at(P, k))
-            xm, x0, xp = at(X, (kp - 1) % S), at(X, kp), at(X, (kp + 1) % S)
+            # X and P at k - 1, k, k + 1 and the same three a half further on, as
+            # three reads; the half with the larger peak is taken
+            idx = (k[:, None] + jnp.asarray([-1, 0, 1, n - 1, n, n + 1], jnp.int32)) % S
+            re, im, pw = (jnp.take_along_axis(a, idx, axis=1, mode="promise_in_bounds")
+                          for a in (jnp.real(X), jnp.imag(X), P))
+            x3 = lax.complex(re, im)
+            x3 = jnp.where((pw[:, 4] > pw[:, 1])[:, None], x3[:, 3:], x3[:, :3])
+            xm, x0, xp = x3[:, 0], x3[:, 1], x3[:, 2]
             den = 2 * x0 - xm - xp
             jac = jnp.real((xm - xp) * jnp.conj(den)) \
                 / jnp.maximum(jnp.real(den) ** 2 + jnp.imag(den) ** 2, 1e-30)
-            return k, jac, at(Q, k) / jnp.maximum(jnp.sum(P, axis=-1), 1e-30)
+            # max(Q) is Q[k]: k is its argmax
+            return k, jac, jnp.max(Q, axis=-1) / jnp.maximum(jnp.sum(P, axis=-1), 1e-30)
+
+        lower = jnp.asarray(np.tril(np.ones((C, C), bool)))
 
         def step(carry, _):
-            b, cnts, done, n_done = carry
+            """What a step carries beside the branches stays in vectors: per-lane
+            tallies of the counters and ``n_done`` in every lane. A sum over the
+            lanes is a scalar, and a scalar that goes back into a vector (eight
+            counters a step were 0.8 ms a frame, the done rows' count 0.25) or a
+            small gather (0.2-0.4 ms each) costs a step more than its DFT's
+            neighbours do (``PERF.md`` section 6, PR 34)."""
+            b, tally, done, n_done = carry
             with jax.named_scope("sync"):
                 st, pos = b["st"], b["pos"]
                 fits = pos + S <= T
                 idle = (st == _IDLE) & fits
                 # -- detection: the first of four windows that sees a preamble
                 j0 = pos // hop
-                idx = j0[:, None] + jnp.arange(4)[None, :]
-                c4 = jnp.take_along_axis(cond_p, jnp.clip(idx, 0, nw + 3), axis=1)
-                jt = j0 + jnp.argmax(c4, axis=1).astype(jnp.int32)
-                trig = idle & jnp.any(c4, axis=1)
-                kd, ed = at(kb_p, jnp.clip(jt, 0, nw + 3)), at(eps_p, jnp.clip(jt, 0, nw + 3))
+                j = jnp.clip(j0, 0, nw)
+                code, ed = (jnp.take_along_axis(a, j[:, None], axis=1,
+                                                mode="promise_in_bounds")[:, 0]
+                            for a in (det_code, det_eps))
+                trig = idle & ((code & 1) != 0)
+                jt, kd = j0 + ((code >> 1) & 3), code >> 3
                 # -- the aligned window of every branch past detection
                 up = (st == _DN1) | (st == _DN2)
                 nu_use = jnp.where(st == _DATA, b["cfo"] + b["tau"], b["nu"])
             with jax.named_scope("demod"):
-                k, jac, shr = window(ext, pos, up, nu_use, st == _DATA, b["tau"])
+                k, jac, shr = window(pos, up, nu_use, st == _DATA, b["tau"])
             with jax.named_scope("sync"):
                 kw = wrap(k, n)
                 in_pre, in_sw2 = (st == _PRE) & fits, (st == _SW2) & fits
@@ -373,18 +474,15 @@ def lora_gw_stages(n_channels: int = 8, sfs: Sequence[int] = (7, 8, 9, 10, 11, 1
                         adv & in_sw2, _DN1, jnp.where(in_dn1, _DN2, jnp.where(
                             synced, _DATA, st))))))
                 # -- a finished packet leaves the scan through a done row
-                row = n_done + jnp.cumsum(complete.astype(jnp.int32)) - 1
+                fin = complete.astype(jnp.int32)
+                row = n_done + jnp.sum(jnp.where(lower, fin[None, :], 0), axis=1) - 1
                 row = jnp.where(complete & (row < D), row, D)
                 end = nxt - H
-                done = dict(
-                    syms=done["syms"].at[row].set(syms, mode="drop"),
-                    ints=done["ints"].at[row].set(jnp.stack(
-                        [jnp.arange(C, dtype=jnp.int32), b["start"], end, nsym1, need],
-                        axis=1), mode="drop"),
-                    flts=done["flts"].at[row].set(jnp.stack(
-                        [b["cfo"], b["tau"], ssum / jnp.maximum(nsym1, 1)], axis=1),
-                        mode="drop"))
-                n_fin = jnp.sum(complete.astype(jnp.int32))
+                bits = lambda v: lax.bitcast_convert_type(v, jnp.int32)
+                done = done.at[row].set(jnp.concatenate([jnp.stack(
+                    [jnp.arange(C, dtype=jnp.int32), b["start"], end, nsym1, need,
+                     bits(b["cfo"]), bits(b["tau"]), bits(ssum / jnp.maximum(nsym1, 1))]).T,
+                    syms], axis=1), mode="drop")
                 new_b = dict(
                     st=jnp.where(fits, new_st, st).astype(jnp.int32),
                     pos=jnp.where(fits, new_pos, pos).astype(jnp.int32),
@@ -396,24 +494,27 @@ def lora_gw_stages(n_channels: int = 8, sfs: Sequence[int] = (7, 8, 9, 10, 11, 1
                     cfo=jnp.where(synced, cfo_new, b["cfo"]),
                     tau=jnp.where(synced, (sh.astype(jnp.float32) - g) / OS, b["tau"]),
                     ssum=jnp.where(synced, 0.0, ssum), syms=syms)
-                cnts = cnts + jnp.stack([
-                    jnp.sum(trig), jnp.sum(synced), jnp.sum(at8 & hok),
-                    jnp.minimum(n_fin, jnp.maximum(D - n_done, 0)), 0, 0, jnp.sum(in_data),
-                    jnp.maximum(n_done + n_fin - D, 0)
-                    - jnp.maximum(n_done - D, 0)]).astype(jnp.int32)
-            return (new_b, cnts, done, n_done + n_fin), None
+                tally = tally + jnp.stack([trig, synced, at8 & hok, in_data]).astype(jnp.int32)
+                n_done = n_done + jnp.sum(jnp.broadcast_to(fin[None, :], (C, C)), axis=1)
+            return (new_b, tally, done, n_done), None
 
-        done0 = dict(syms=jnp.zeros((D, max_sym), jnp.int32),
-                     ints=jnp.zeros((D, 5), jnp.int32), flts=jnp.zeros((D, 3), jnp.float32))
-        (b, cnts, done, n_done), _ = lax.scan(
-            step, (b, jnp.zeros(8, jnp.int32), done0, jnp.int32(0)), None,
+        # a done row: channel, start, end, symbols, symbols owed, then CFO, rest
+        # of the timing and mean peak share as their bits, then the symbols
+        done0 = jnp.zeros((D, 8 + max_sym), jnp.int32)
+        (b, tally, done, n_done), _ = lax.scan(
+            step, (b, jnp.zeros((4, C), jnp.int32), done0, jnp.zeros(C, jnp.int32)), None,
             length=-(-L // S) + 6)
+        n_done = n_done[0]
         b = dict(b, pos=b["pos"] - L, start=b["start"] - L)
-        cnts = cnts.at[5].set(jnp.sum((b["st"] != _IDLE).astype(jnp.int32)))
+        done = dict(ints=done[:, :5], syms=done[:, 8:],
+                    flts=lax.bitcast_convert_type(done[:, 5:8], jnp.float32))
         with jax.named_scope("decode"):
             entries, crc_ok = decode(sf, de, max_sym, done, jnp.minimum(n_done, D))
         valid = jnp.arange(D) < jnp.minimum(n_done, D)
-        cnts = cnts.at[4].set(jnp.sum(valid & ~crc_ok))
+        detected, synced, header_ok, symbols = jnp.sum(tally, axis=1)
+        cnts = jnp.stack([
+            detected, synced, header_ok, jnp.minimum(n_done, D), jnp.sum(valid & ~crc_ok),
+            jnp.sum(b["st"] != _IDLE), symbols, jnp.maximum(n_done - D, 0)]).astype(jnp.int32)
         key = jnp.where(valid, (done["ints"][:, 2] + H_max) * 128
                         + sfs.index(sf) * 16 + done["ints"][:, 0], jnp.int32(2 ** 31 - 1))
         return b, cnts, entries, key

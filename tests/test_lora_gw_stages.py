@@ -250,6 +250,126 @@ def test_emit_args_carry_the_counters_only_while_tracing():
         assert sum(a["lora_emitted"] for a in emits) > 0
 
 
+# -- (e) the scan step's window fetch --------------------------------------------
+
+@pytest.mark.parametrize("sf", [7, 8, 9, 10, 11, 12])
+def test_window_fetch_equals_dynamic_slice_bit_for_bit(sf):
+    """The fetch is a copy: every window of every lane is ``lax.dynamic_slice``'s
+    bit for bit, at the published sizes (8 lanes, ``T = 4 S + 40960``): the first
+    row, the clamped last one, remainders 0, 1 and 127 of a row of 128 at either
+    end, every remainder somewhere, and positions outside, clipped as before."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from futuresdr_tpu.models.lora.rx_stages import _window_fetch
+    S, C = 2 << sf, 8
+    T = 4 * S + 40960
+    rng = np.random.default_rng(sf)
+    ext = rng.standard_normal((C, 2 * T), np.float32).view(np.complex64)
+    ext[0, :3] = [np.complex64(complex(-0.0, np.inf)), np.nan, 1e-42]   # bits, not values
+    fetch = jax.jit(lambda e, p: _window_fetch(e, S)(p))
+    old = jax.jit(jax.vmap(lambda row, p: lax.dynamic_slice(row, (p,), (S,))))
+    last = T - S                                         # a multiple of 128
+    cases = [[0, 1, 127, 128, 129, last, last - 1, last - 127],
+             [-1, -(1 << 20), last + 1, T, T + 12345, 1 << 30, 255, 1023],
+             rng.integers(0, last + 1, C), rng.integers(0, last + 1, C),
+             last - 131 * np.arange(C), 1024 * np.arange(C) + 1023]
+    cases += [128 * rng.integers(0, last // 128, C) + 8 * k + np.arange(C) for k in range(16)]
+    seen = set()
+    for pos in cases:
+        pos = jnp.asarray(pos, jnp.int32)
+        want = np.asarray(old(ext, jnp.clip(pos, 0, last)))
+        got = np.asarray(fetch(ext, pos))
+        assert got.dtype == want.dtype and got.shape == (C, S)
+        assert np.array_equal(got.view(np.uint64), want.view(np.uint64))
+        seen |= {int(p) % 128 for p in np.clip(np.asarray(pos), 0, last)}
+    assert seen == set(range(128))
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One chip of a v5e that is described, not attached (the TPU's compiler
+    is loaded by the one worker that runs this file, inside this fixture)."""
+    from jax.experimental import topologies
+    with pytest.MonkeyPatch.context() as mp:
+        for k, v in (("TPU_LOG_DIR", "disabled"), ("TPU_ACCELERATOR_TYPE", "v5litepod-4"),
+                     ("TPU_WORKER_HOSTNAMES", "localhost"), ("TPU_SKIP_MDS_QUERY", "1")):
+            mp.setenv(k, v)
+        try:
+            topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+        except Exception as e:                            # no TPU compiler loads here
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield topo.devices[0]
+
+
+def compiled_for(device, stage, frame):
+    """The stage's program as the chip's compiler leaves it (text; nothing runs)."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    chip = SingleDeviceSharding(device)
+    spec = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip)
+    carry = jax.tree_util.tree_map(spec, jax.eval_shape(
+        lambda: stage.init_carry(np.complex64)))
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)   # it could not be read back
+    compilation_cache.reset_cache()
+    try:
+        return jax.jit(stage.fn).trace(carry, spec(np.zeros(frame, np.complex64))) \
+            .lower(lowering_platforms=("tpu",)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+
+
+def while_bodies(text):
+    """``{while: (computation it is in, its body)}`` and ``{computation:
+    instructions}`` of an optimized HLO module; parameters, tuple plumbing,
+    constants and bitcasts are not counted."""
+    import re
+    free = ("parameter(", "get-tuple-element(", "tuple(", "constant(", "bitcast(")
+    whiles, count, comp = {}, {}, None
+    for line in text.splitlines():
+        head = re.match(r"\s*(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$", line)
+        if head:
+            comp = head.group(1)
+            count[comp] = 0
+        elif line.strip() == "}":
+            comp = None
+        elif comp and (instr := re.match(r"\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s", line)):
+            body = re.search(r"\swhile\(.*body=%?([\w.\-]+)", line)
+            if body:
+                whiles[instr.group(1)] = (comp, body.group(1))
+            count[comp] += not any(f" {op}" in line for op in free)
+    return whiles, count
+
+
+#: instructions of a scan body of the small gateway as compiled for a v5e: 49, 49
+#: and 65 as PR 34 leaves it; the parent's bodies held 94, 95 and 114 and each
+#: ran, besides, two loops of one trip a lane and 6 instructions a trip
+STEP_CEILING = 72
+
+
+def test_compiled_for_v5e_one_while_per_sf_and_a_short_step(v5e, monkeypatch):
+    """No loop inside a scan step: the program compiled for the chip (Mosaic
+    kernel and MXU DFTs as there) holds one ``while`` per SF, the scan, and a
+    step's body stays under the ceiling."""
+    from futuresdr_tpu.models.lora import rx_stages
+    from futuresdr_tpu.ops import mxu_fft
+    monkeypatch.setattr(rx_stages, "_interpret", lambda: False)
+    monkeypatch.setattr(mxu_fft, "_impl", "mxu")
+    text = compiled_for(v5e, lora_gw_stages(**SMALL)[0], FRAME)
+    whiles, count = while_bodies(text)
+    bodies = {body for _, body in whiles.values()}
+    sizes = sorted(count[b] for b in bodies)
+    print(f"whiles {len(whiles)}, scan bodies of {sizes} instructions")
+    assert len(whiles) == len(SFS)
+    assert not [w for w, (inside, _) in whiles.items() if inside in bodies]
+    assert text.count('custom_call_target="tpu_custom_call"') >= len(SFS)
+    assert max(sizes) <= STEP_CEILING
+
+
 def test_record_layout_is_the_references():
     rec = {"channel": 3, "sf": 9, "start": -70000, "end": 123, "cfo_hz": -4321.5,
            "timing": 0.125, "snr_db": 3.5, "share": 0.4375, "length": 5,
