@@ -378,8 +378,10 @@ def _ships_pairs(dtype, device=None) -> bool:
 def wire_part(arr: np.ndarray, device=None) -> np.ndarray:
     """One host array as it crosses the link, for
     :func:`start_device_transfer_parts`: a complex array's float32 pairs (a
-    zero-copy view) where pairs are shipped, else the array itself. The
-    device side of several such parts is :func:`join_parts`."""
+    zero-copy view) where pairs are shipped, else the array itself (a
+    serving wire's ``uint32`` words, a complex sample a word, cross as the
+    words they are). The device side of several such parts is
+    :func:`join_parts`."""
     if _ships_pairs(arr.dtype, device):
         from .wire import _pairs_view
         return _pairs_view(arr)
@@ -390,7 +392,9 @@ def join_parts(parts, dtype, device=None):
     """The device array whose leading axis is cut into ``parts`` (device
     arrays put as :func:`wire_part` of ``dtype`` arrays): ONE jitted program
     concatenates them and forms the complex values from the pairs — the
-    several-part form of :func:`start_device_transfer`'s join. Which parts
+    several-part form of :func:`start_device_transfer`'s join. Parts of a
+    dtype that ships as it is (``uint32`` words) are concatenated and no
+    pairs are formed: whoever decodes them does it in its own program. Which parts
     were just uploaded and which were resident changes no shape, so it
     compiles once per part count and shape."""
     pairs = _ships_pairs(dtype, device)

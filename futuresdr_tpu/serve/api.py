@@ -171,7 +171,9 @@ async def _create_session(request):
         return _serve_full(eng, name, e)
     except ValueError as e:
         return _json_error(name, str(e), 409)
-    return web.json_response(s.view(), status=201)
+    # the answer names the format this engine's submit() takes: one per
+    # engine, so a client knows at admission what to send
+    return web.json_response({**s.view(), **eng.frame_format()}, status=201)
 
 
 async def _session_view(request):

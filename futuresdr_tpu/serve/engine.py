@@ -34,6 +34,10 @@ with a leading session axis:
   streamed kernel — committed carries advance ONLY after a group's D2H
   lands (a failed drain re-queues every uncommitted group's frames:
   PR 10's rollback contract, now over a window);
+* ONE frame format per engine (``wire=``, docs/serving.md "Frames on the
+  radio's wire"): samples of the pipeline's ``in_dtype``, or the radio's
+  own ``sc16`` words, which stay words in the queue, the staging sets and
+  on the link and are decoded first thing inside the step's program;
 * autotuned bucket sizes (``tpu/autotune.autotune_serve``): occupancy
   crossing the current bucket grows the PAGE POOL to the next bucket's
   capacity and compiles THAT capacity once;
@@ -111,6 +115,11 @@ _LANES_SHIPPED = _prom.counter(
     "input lanes uploaded by serving dispatches (lane groups shipped x "
     "lanes per group; the riding lanes are fsdr_serve_frames_total)",
     ("app",))
+_WIRE_BYTES = _prom.counter(
+    "fsdr_serve_wire_bytes_total",
+    "bytes of session frames put on the host-device link by serving "
+    "dispatches, as they crossed (wire: the engine's frame format)",
+    ("app", "wire"))
 _RETIRED = _prom.counter(
     "fsdr_serve_retired_total",
     "sessions retired by a per-session fault (slot-isolated)",
@@ -148,6 +157,11 @@ _RESUMED = _prom.counter(
 #: fastest, 8 is 9 % slower there and a tenth quicker for a few riders
 LANE_GROUP = 16
 
+#: what one count of a 16-bit I/Q sample is worth on a serving wire: 2^-15,
+#: fixed per engine and never worked out from a frame (UHD's convention for
+#: ``sc16``: full scale is 32768 counts)
+FULL_SCALE = 32768.0
+
 
 def default_buckets() -> tuple:
     """The slot-bucket ladder when neither the caller nor the autotune cache
@@ -167,7 +181,24 @@ def default_buckets() -> tuple:
     return (1, 2, 4, 8, 16, 32, 64)
 
 
-def build_slot_program(pipeline, capacity: int, k: int = 1):
+def serve_wire(wire, in_dtype):
+    """The wire an engine's sessions submit frames on, or None (frames of the
+    pipeline's ``in_dtype``, shipped as they are). One format has a form a
+    radio delivers and a program decodes without a relayout: ``sc16`` under a
+    complex ``in_dtype``, a complex sample a 32-bit word (``Wire.pair_words``)."""
+    if wire is None:
+        return None
+    from ..ops.wire import get_wire
+    w = get_wire(wire)
+    if not w.pair_words((1, 2), np.int16, in_dtype):
+        raise ValueError(
+            f"serving takes frames on the sc16 wire under a complex in_dtype "
+            f"(a complex sample a 32-bit word), not {w.name!r} under "
+            f"{np.dtype(in_dtype)}")
+    return w
+
+
+def build_slot_program(pipeline, capacity: int, k: int = 1, wire=None):
     """Compile the pipeline's PAGED slot-batched serving step for one
     page-pool capacity:
 
@@ -210,13 +241,33 @@ def build_slot_program(pipeline, capacity: int, k: int = 1):
     the overlapped step keeps the committed pool alive while speculative
     groups are in flight — donation would invalidate exactly those
     buffers. Shared with ``tpu/autotune.autotune_serve`` so the measured
-    program is exactly the served one."""
+    program is exactly the served one.
+
+    ``wire`` (:func:`serve_wire`): ``x`` is ``uint32`` words of the same
+    shape, a complex sample a word as the radio delivered it (I the low
+    half, Q the high half, int16 each), and the step decodes them under the
+    ``wire_decode`` scope before the first stage: two shifts, two converts
+    and one multiply a component by the fixed count ``1 / FULL_SCALE``, so
+    a sample is exactly its 16 bits times 2^-15 and no array with a minor
+    dimension of 2 exists. Without it the program is the one above, text
+    for text."""
     import jax
     import jax.numpy as jnp
 
     inner = pipeline.fn()
     multi = bool(getattr(pipeline, "n_branches", 0))
     template = pipeline.init_carry()
+
+    def ingest(x):
+        if wire is None:
+            return x
+        # the count as the wire's per-frame scale: (qmax / FULL_SCALE) / qmax
+        # is 2^-15 exactly, worked out in numpy before the trace
+        scale = np.float32(wire.qmax / FULL_SCALE)
+        with jax.named_scope("wire_decode"):
+            return wire.decode_words_jax(
+                (jax.lax.bitcast_convert_type(x, jnp.int32), scale),
+                pipeline.in_dtype)
 
     def gather(pages, page_map, fresh):
         def pick(P, t):
@@ -243,7 +294,7 @@ def build_slot_program(pipeline, capacity: int, k: int = 1):
         def step(pages, page_map, fresh, x, active):
             with jax.named_scope("serve_gather"):
                 carries = gather(pages, page_map, fresh)
-            new_c, y = masked_lane_step(carries, x, active)
+            new_c, y = masked_lane_step(carries, ingest(x), active)
             with jax.named_scope("serve_scatter"):
                 pages = scatter(pages, page_map, new_c)
             return pages, (y if multi else (y,))
@@ -258,7 +309,7 @@ def build_slot_program(pipeline, capacity: int, k: int = 1):
 
             carries, ys = jax.lax.scan(
                 body, carries,
-                (jnp.moveaxis(x, 1, 0), jnp.moveaxis(active, 1, 0)))
+                (jnp.moveaxis(ingest(x), 1, 0), jnp.moveaxis(active, 1, 0)))
             # ys: [k, capacity, out] per sink -> [capacity, k, out]
             if multi:
                 outs = tuple(jnp.moveaxis(yj, 0, 1) for yj in ys)
@@ -281,7 +332,7 @@ class _DispatchGroup:
     __slots__ = ("capacity", "k", "lanes", "n_frames", "active",
                  "fresh", "page_map", "fresh_lanes", "step_tids", "t_step",
                  "seq", "new_pages", "fins", "wire", "staging",
-                 "lanes_shipped", "groups_shipped")
+                 "lanes_shipped", "groups_shipped", "bytes_shipped")
 
     def __init__(self, capacity: int, k: int, lanes: list, active,
                  fresh, page_map, fresh_lanes: frozenset, step_tids: list,
@@ -303,6 +354,7 @@ class _DispatchGroup:
         self.staging = None           # the host staging set it shipped from
         self.lanes_shipped = 0        # set by launch: what crossed the link
         self.groups_shipped = 0
+        self.bytes_shipped = 0
 
 
 class ServeEngine:
@@ -324,10 +376,25 @@ class ServeEngine:
                  persist_every: Optional[int] = None,
                  slo_ms: Optional[float] = None,
                  shard_devices: Optional[int] = None,
-                 inflight: Optional[int] = None):
+                 inflight: Optional[int] = None,
+                 wire: Optional[str] = None):
         from ..config import config
         from ..tpu.instance import instance
         self.pipeline = pipeline
+        #: the format sessions submit frames in, one per engine (a deployment
+        #: setting beside ``frame_size``; docs/serving.md "Frames on the
+        #: radio's wire"): None = samples of ``pipeline.in_dtype``;
+        #: ``"sc16"`` = ``uint32[frame]`` words, a complex sample a word as
+        #: UHD / SoapySDR / IIO deliver it, decoded inside the step's program
+        self.wire = serve_wire(wire, pipeline.in_dtype)
+        #: what a frame is in the session queue, the staging sets, on the
+        #: link and in the resident zero block: the wire's words, else the
+        #: pipeline's samples
+        self.frame_dtype = np.dtype(np.uint32 if self.wire is not None
+                                    else pipeline.in_dtype)
+        #: the format's name in spans, counters and views ("raw": samples
+        #: of the pipeline's dtype, no codec)
+        self.wire_name = self.wire.name if self.wire is not None else "raw"
         self._base_pipeline = pipeline     # pre-brownout program identity
         self.app = str(app)
         # per-lane e2e latency for the serving plane: the SAME
@@ -603,7 +670,8 @@ class ServeEngine:
         key = (capacity, k, self._pipe_tag)
         prog = self._programs.get(key)
         if prog is None:
-            prog = build_slot_program(self.pipeline, capacity, k)
+            prog = build_slot_program(self.pipeline, capacity, k,
+                                      wire=self.wire)
             self._programs[key] = prog
             self.compiles += 1
             log.info("%s: compiled serving program for slot bucket %d "
@@ -617,7 +685,7 @@ class ServeEngine:
     def _cached_buckets(self) -> Optional[tuple]:
         try:
             from ..tpu.autotune import cached_serve_buckets
-            got = cached_serve_buckets(self.pipeline, self.pipeline.in_dtype,
+            got = cached_serve_buckets(self.pipeline, self.frame_dtype,
                                        self.inst.platform)
             return tuple(got) if got else None
         except Exception:                  # noqa: BLE001 — ladder seed only
@@ -630,7 +698,7 @@ class ServeEngine:
         ladder must not invent an uncompilable capacity."""
         try:
             from ..tpu.autotune import cached_serve_pages
-            got = cached_serve_pages(self.pipeline, self.pipeline.in_dtype,
+            got = cached_serve_pages(self.pipeline, self.frame_dtype,
                                      self.inst.platform)
             return int(got) if got and int(got) in self.buckets else None
         except Exception:                  # noqa: BLE001 — pool seed only
@@ -870,17 +938,56 @@ class ServeEngine:
             s = self._session(sid)
             if s.state in ("retired", "closed"):
                 raise ValueError(f"session {sid!r} is {s.state}")
-            frame = np.asarray(frame)
-            if frame.shape != (self.frame_size,):
-                raise ValueError(
-                    f"frame shape {frame.shape} != ({self.frame_size},)")
+            frame = self._queued_form(frame)
             if not self.credits.try_acquire(s.tenant):
                 _REJECTS.inc(app=self.app, tenant=s.tenant)
                 return False
-            s.pending.append((np.ascontiguousarray(
-                frame, dtype=self.pipeline.in_dtype), time.perf_counter_ns()))
+            s.pending.append((frame, time.perf_counter_ns()))
             s.frames_in += 1
             return True
+
+    def _queued_form(self, frame) -> np.ndarray:
+        """One submitted frame as the queue holds it: contiguous
+        ``frame_dtype[frame_size]``. On a wire that is the caller's own
+        bytes, viewed: ``uint32[frame]`` words, or the interleaved
+        little-endian ``int16[frame, 2]`` I/Q pairs they are. Without one,
+        samples are cast to ``in_dtype`` within their kind as before. Raises
+        ``ValueError`` for a frame of the other side of that line (samples
+        into a wire engine, integer words into a sample engine): a cast
+        there would turn one into garbage of the other silently."""
+        frame = np.asarray(frame)
+        want = self.frame_dtype
+        if self.wire is not None and frame.dtype == np.int16 \
+                and frame.shape == (self.frame_size, 2):
+            frame = np.ascontiguousarray(frame).view(want).reshape(-1)
+        if frame.shape != (self.frame_size,):
+            raise ValueError(
+                f"frame shape {frame.shape} != ({self.frame_size},)")
+        have = frame.dtype
+        if have != want and (self.wire is not None
+                             or (have.kind in "biu") != (want.kind in "biu")):
+            raise ValueError(
+                f"frame dtype {have}: this engine takes {self.frame_format()}")
+        return np.ascontiguousarray(frame, dtype=want)
+
+    def frame_format(self) -> dict:
+        """What ``submit`` takes, for a session's admission answer and
+        :meth:`describe`: the wire's name (``"raw"``: samples of the
+        pipeline's dtype, no codec), the frame's dtype and shape, and on a
+        wire what one count is worth."""
+        return {"wire": self.wire_name,
+                "frame_dtype": str(self.frame_dtype),
+                "frame_shape": [self.frame_size],
+                "full_scale": FULL_SCALE if self.wire is not None else None}
+
+    def uplink_word_parts(self) -> int:
+        """Parts of the current bucket's step input that cross as words and
+        are decoded a complex sample a word inside the program (as
+        ``TpuKernel`` reports ``uplink_word_slots``)."""
+        if self.wire is None:
+            return 0
+        C = self.table.capacity
+        return 1 if self._shard_ok(C) else C // self._lane_group(C)
 
     def results(self, sid: str) -> list:
         """Drain the session's decoded results (oldest first)."""
@@ -1129,8 +1236,7 @@ class ServeEngine:
         z = self._zero_parts.get((lanes, k))
         if z is None:
             dev = self.inst.device
-            zeros = np.zeros(self._group_shape(lanes, k),
-                             dtype=self.pipeline.in_dtype)
+            zeros = np.zeros(self._group_shape(lanes, k), self.frame_dtype)
             (z,) = xfer.start_device_transfer_parts(
                 (xfer.wire_part(zeros, dev),), dev)()
             self._zero_parts[(lanes, k)] = z
@@ -1160,13 +1266,11 @@ class ServeEngine:
         G = self._lane_group(C)
         n = C // G
         dev = self.inst.device
-        dtype = self.pipeline.in_dtype
+        dtype = self.frame_dtype
         riders: Dict[int, list] = {}
         for _s, lane, popped, _tids in g.lanes:
             riders.setdefault(lane // G, []).append((lane, popped))
-        g.groups_shipped, g.lanes_shipped = len(riders), len(riders) * G
-        shipped = {"lanes_shipped": g.lanes_shipped,
-                   "groups_shipped": g.groups_shipped}
+        shipped = self._note_shipped(g, len(riders), G)
         zero = self._zero_part(G, K) if n > 1 else None
         free = self._staging.setdefault((C, K), [])
         g.staging = staging = free.pop() if free else [None] * n
@@ -1188,7 +1292,8 @@ class ServeEngine:
         if tracing:
             _trace.complete("tpu", "encode", t_enc, end_ns=t_filled,
                             args={"sessions": len(g.lanes), "capacity": C,
-                                  "seq": g.seq, **shipped})
+                                  "seq": g.seq, "bytes": g.bytes_shipped,
+                                  **shipped})
         wires = [f._wire for f in fins if f is not None]
         g.wire = (wires[0][0], wires[-1][1])
 
@@ -1203,19 +1308,31 @@ class ServeEngine:
         ``device_put`` against the mesh sharding, which owns that layout.
         Returns what :meth:`_start_lane_groups` returns."""
         C = g.capacity
-        g.groups_shipped, g.lanes_shipped = 1, C
+        shipped = self._note_shipped(g, 1, C)
         t_enc = _trace.now() if _trace.enabled else 0
-        batch = np.zeros(self._group_shape(C, g.k),
-                         dtype=self.pipeline.in_dtype)
+        batch = np.zeros(self._group_shape(C, g.k), self.frame_dtype)
         self._fill_group(batch, [(l, p) for _s, l, p, _t in g.lanes], 0)
         grp = None
         if t_enc:
-            shipped = {"lanes_shipped": C, "groups_shipped": 1}
             _trace.complete("tpu", "encode", t_enc,
                             args={"sessions": len(g.lanes), "capacity": C,
-                                  "seq": g.seq, **shipped})
+                                  "seq": g.seq, "bytes": g.bytes_shipped,
+                                  **shipped})
             grp = xfer.H2DGroup(g.seq, **shipped)
         return self._start_h2d(batch, True, grp), grp
+
+    def _note_shipped(self, g: _DispatchGroup, groups: int,
+                      lanes_per_group: int) -> dict:
+        """Set what a launch puts on the link for the group's input frames
+        (lane groups, their lanes, their bytes as they cross); returns the
+        args its ``encode``, ``h2d_put`` and ``H2D`` spans share."""
+        g.groups_shipped = groups
+        g.lanes_shipped = groups * lanes_per_group
+        g.bytes_shipped = (g.lanes_shipped * g.k * self.frame_size
+                           * self.frame_dtype.itemsize)
+        return {"lanes_shipped": g.lanes_shipped,
+                "groups_shipped": g.groups_shipped,
+                "wire": self.wire_name}
 
     def _release_staging(self, g: _DispatchGroup) -> None:
         """Hand a committed or rolled-back group's staging set back."""
@@ -1338,6 +1455,8 @@ class ServeEngine:
             self.frames += dispatched
             _DISPATCHES.inc(app=self.app)
             _LANES_SHIPPED.inc(g.lanes_shipped, app=self.app)
+            _WIRE_BYTES.inc(g.bytes_shipped, app=self.app,
+                            wire=self.wire_name)
             self._step_stamps.append(time.monotonic())
             if self._persist_every and self._store is not None:
                 self._steps_since_persist += 1
@@ -1583,14 +1702,14 @@ class ServeEngine:
             # pay a second, unbilled compile)
             if self._shard_ok(C):
                 x = self._start_h2d(np.zeros(self._group_shape(C, K),
-                                             dtype=self.pipeline.in_dtype),
+                                             self.frame_dtype),
                                     shard=True)()
             else:
                 # the input as a launch forms it, with no lane riding:
                 # warms the lane groups' join and uploads the zero block
                 G = self._lane_group(C)
                 x = xfer.join_parts([self._zero_part(G, K)] * (C // G),
-                                    self.pipeline.in_dtype, self.inst.device)
+                                    self.frame_dtype, self.inst.device)
             _new_p, outs = prog(self._pages,
                                 self._start_h2d(pmap, shard=False)(),
                                 self._start_h2d(no_fresh, shard=False)(),
@@ -1942,6 +2061,11 @@ class ServeEngine:
             return {
                 "app": self.app,
                 "frame_size": self.frame_size,
+                # the format sessions submit in, and how many parts of the
+                # compiled step's input are decoded a complex sample a
+                # 32-bit word (the lane groups on a wire; 0 for samples)
+                **self.frame_format(),
+                "uplink_word_parts": self.uplink_word_parts(),
                 "frames_per_dispatch": self.k_batch,
                 "buckets": list(self.buckets),
                 "capacity": self.table.capacity,

@@ -51,6 +51,10 @@ class FakeEngine:
     def retry_after_s(self) -> int:
         return 1
 
+    def frame_format(self) -> dict:
+        return {"wire": "raw", "frame_dtype": "complex64",
+                "frame_shape": [0], "full_scale": None}
+
     def admit(self, tenant: str = "default", sid=None):
         from futuresdr_tpu.serve.slots import Session
         s = Session(tenant, sid)

@@ -1,5 +1,5 @@
 """References for the serving tests (``tests/test_serve.py``,
-``tests/test_serve_paged.py``).
+``tests/test_serve_paged.py``, ``tests/test_serve_sc16.py``).
 
 What those tests are about is ISOLATION and page bookkeeping: a session's
 stream must not depend on who else rides, joins, leaves or where the pool
@@ -16,7 +16,7 @@ and 4 are not, on jax 0.9 XLA:CPU)."""
 import jax
 import numpy as np
 
-from futuresdr_tpu.serve.engine import build_slot_program
+from futuresdr_tpu.serve.engine import build_slot_program, serve_wire
 
 
 class SoloSlot:
@@ -24,12 +24,15 @@ class SoloSlot:
     other lane masked. ``run`` may be called at several capacities in turn
     (a page-pool growth): the lane's carry page moves with it."""
 
-    def __init__(self, pipeline, frame_size: int, lane: int):
+    def __init__(self, pipeline, frame_size: int, lane: int, wire=None):
         self.pipe, self.frame, self.lane = pipeline, frame_size, lane
         self.carry = None                 # the lane's page after the last run
+        #: an engine built on a wire: frames are its ``uint32`` words
+        self.wire = serve_wire(wire, pipeline.in_dtype)
+        self.dtype = np.uint32 if self.wire is not None else np.complex64
 
     def run(self, capacity: int, frames) -> list:
-        prog = build_slot_program(self.pipe, capacity)
+        prog = build_slot_program(self.pipe, capacity, wire=self.wire)
         template = self.pipe.init_carry()
         pages = jax.tree_util.tree_map(
             lambda l: np.stack([np.asarray(l)] * capacity), template)
@@ -46,7 +49,7 @@ class SoloSlot:
         active[self.lane] = True
         out = []
         for f in frames:
-            x = np.zeros((capacity, self.frame), np.complex64)
+            x = np.zeros((capacity, self.frame), self.dtype)
             x[self.lane] = f
             pages, ys = prog(pages, pmap, fresh, x, active)
             fresh = np.zeros((capacity,), bool)

@@ -415,8 +415,8 @@ def test_observability_answers_during_compile_bearing_step():
     entered = threading.Event()
     release = threading.Event()
 
-    def slow_build(pipeline, capacity, k=1):
-        prog = real_build(pipeline, capacity, k)
+    def slow_build(pipeline, capacity, k=1, **kw):
+        prog = real_build(pipeline, capacity, k, **kw)
 
         def slow(*args):
             entered.set()

@@ -672,17 +672,21 @@ def test_adaptive_kernel_starts_from_cached_pick(tmp_path, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# the serving program never meets the uplink prolog (ISSUE 32, satellite 2)
+# a sample engine's program never meets the uplink prolog (ISSUE 32, satellite
+# 2; since ISSUE 36 an engine built on a wire decodes words, and only it)
 # ---------------------------------------------------------------------------
 
 def test_serving_program_has_no_uplink_prolog():
-    """``ServeEngine`` compiles ``Pipeline.fn()`` under its slot program and
-    ships raw complex64 through ``xfer.wire_part`` / ``join_parts``: what the
-    slot program lowers to for the FM front end at [64, 65536] (the
-    ``fm_serve_*`` cells' shape) names neither ``unpack`` nor
-    ``wire_decode``, and no source file under ``futuresdr_tpu/serve/``
-    reaches for the wired program, its layout or a wire codec — so a change
-    to the coalesced uplink cannot move a serving cell."""
+    """A ``ServeEngine`` built without a wire compiles ``Pipeline.fn()`` under
+    its slot program and ships raw complex64 through ``xfer.wire_part`` /
+    ``join_parts``: what the slot program lowers to for the FM front end at
+    [64, 65536] (the ``fm_serve_sat`` / ``fm_serve_paced`` cells' shape) names
+    neither ``unpack`` nor ``wire_decode``, and no source file under
+    ``futuresdr_tpu/serve/`` reaches for the streamed path's wired program or
+    its layout — so a change to the coalesced uplink cannot move those
+    cells. The one thing serving shares with it since ISSUE 36 is
+    ``Sc16Wire.decode_words_jax``, reached only by ``build_slot_program``'s
+    ``wire`` argument (``tests/test_serve_sc16.py``)."""
     import pathlib
     import re
 
@@ -712,7 +716,10 @@ def test_serving_program_has_no_uplink_prolog():
     files = sorted(serve.glob("*.py"))
     assert files
     for f in files:
+        src = f.read_text()
         hit = re.search(r"compile_wired|wired_fn|PackedLayout|PackedAlloc|"
-                        r"decode_words_jax|decode_jax|ops\.wire|get_wire",
-                        f.read_text())
+                        r"unpack_jax|decode_jax|encode_jax|encode_host", src)
         assert hit is None, (f.name, hit.group(0))
+        # the word decode: one call site, in the slot program's builder
+        assert len(re.findall(r"decode_words_jax\(", src)) == \
+            (1 if f.name == "engine.py" else 0), f.name
