@@ -19,7 +19,8 @@ sample lies, as delayed by the front end's filters). Per frame, by scope:
 ``detect``  per SF, channels as a batch: windows of one symbol at a hop of a
             quarter symbol over the last four symbols of the earlier frames +
             this frame, dechirped (``ops.stages.lora_dechirp_dft``: a DFT of
-            2 * 2^SF points through ``ops/mxu_fft``; bins ``k`` and ``k +
+            2 * 2^SF points through ``ops/mxu_fft``, here thousands of rows a
+            call: its many-row forms; bins ``k`` and ``k +
             2^SF`` hold a symbol's two parts on either side of its wrap and add
             as powers, so no fraction of a chip in the timing costs the peak);
             peak bin, the share of the energy in the peak and its larger
@@ -33,7 +34,12 @@ sample lies, as delayed by the front end's filters). Per frame, by scope:
             (``_window_fetch``: whole tiles by DMA, then a rotation; a
             ``vmap(dynamic_slice)`` is a loop of a trip a lane inside every
             step), and the peak's neighbours are read for all lanes at once,
-            so a step's body holds no loop. The grid moves by ``-2k``
+            so a step's body holds no loop. The step's DFT has one row a
+            channel, 8 rows: ``mxu_fft.form`` gives it the few-row forms (re
+            and im as stacked rows through ONE real matmul a stage, four-step
+            from 512 points = SF8, tables built outside the scan), because on
+            8 rows a DFT costs its tables and its operations, not its
+            arithmetic. The grid moves by ``-2k``
             samples so that the preamble dechirps to bin 0, its first window
             gives the rest ``nu`` (Jacobsen), it walks to the sync word (24,
             32 for 0x34), the two whole down-chirps against the up-chirp give

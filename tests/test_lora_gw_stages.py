@@ -323,21 +323,35 @@ def compiled_for(device, stage, frame):
         compilation_cache.reset_cache()
 
 
+def computations(text):
+    """``{computation: its lines}`` of an optimized HLO module."""
+    import re
+    comps, comp = {}, None
+    for line in text.splitlines():
+        head = re.match(r"\s*(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$", line)
+        if head:
+            comp = head.group(1)
+            comps[comp] = []
+        elif line.strip() == "}":
+            comp = None
+        elif comp:
+            comps[comp].append(line)
+    return comps
+
+
 def while_bodies(text):
     """``{while: (computation it is in, its body)}`` and ``{computation:
     instructions}`` of an optimized HLO module; parameters, tuple plumbing,
     constants and bitcasts are not counted."""
     import re
     free = ("parameter(", "get-tuple-element(", "tuple(", "constant(", "bitcast(")
-    whiles, count, comp = {}, {}, None
-    for line in text.splitlines():
-        head = re.match(r"\s*(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$", line)
-        if head:
-            comp = head.group(1)
-            count[comp] = 0
-        elif line.strip() == "}":
-            comp = None
-        elif comp and (instr := re.match(r"\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s", line)):
+    whiles, count = {}, {}
+    for comp, lines in computations(text).items():
+        count[comp] = 0
+        for line in lines:
+            instr = re.match(r"\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s", line)
+            if not instr:
+                continue
             body = re.search(r"\swhile\(.*body=%?([\w.\-]+)", line)
             if body:
                 whiles[instr.group(1)] = (comp, body.group(1))
@@ -345,16 +359,40 @@ def while_bodies(text):
     return whiles, count
 
 
-#: instructions of a scan body of the small gateway as compiled for a v5e: 49, 49
-#: and 65 as PR 34 leaves it; the parent's bodies held 94, 95 and 114 and each
-#: ran, besides, two loops of one trip a lane and 6 instructions a trip
+def called_from(comps, comp):
+    """``comp`` and every computation its instructions call, downwards."""
+    import re
+    seen, todo = set(), [comp]
+    while todo:
+        c = todo.pop()
+        if c in seen or c not in comps:
+            continue
+        seen.add(c)
+        for line in comps[c]:
+            todo += re.findall(r"(?:calls|to_apply)=%?([\w.\-]+)", line)
+    return seen
+
+
+#: instructions of a scan body of the small gateway as compiled for a v5e: 50, 63
+#: and 66 as PR 37 leaves it (49, 49 and 65 at PR 34, whose SF7 and SF8 steps ran
+#: three convolutions each and SF9's six, every one with its DFT matrix built
+#: inside it, and whose twiddles were a fusion without operands in the step); the
+#: parent of PR 34 held 94, 95 and 114 and each ran, besides, two loops of one trip
+#: a lane and 6 instructions a trip
 STEP_CEILING = 72
 
 
 def test_compiled_for_v5e_one_while_per_sf_and_a_short_step(v5e, monkeypatch):
     """No loop inside a scan step: the program compiled for the chip (Mosaic
     kernel and MXU DFTs as there) holds one ``while`` per SF, the scan, and a
-    step's body stays under the ceiling."""
+    step's body stays under the ceiling. A step's DFT is its few-row form
+    (``mxu_fft.form``): one convolution a stage, and no table of it is built
+    inside the step: no fusion without operands sits in a scan body (XLA sinks
+    what is elementwise from an ``iota`` into the loop, where a DFT matrix was
+    512^2 cosines and sines a step), and no cosine, sine or exponential but
+    those of the step's own rotation of its lanes."""
+    import re
+
     from futuresdr_tpu.models.lora import rx_stages
     from futuresdr_tpu.ops import mxu_fft
     monkeypatch.setattr(rx_stages, "_interpret", lambda: False)
@@ -368,6 +406,47 @@ def test_compiled_for_v5e_one_while_per_sf_and_a_short_step(v5e, monkeypatch):
     assert not [w for w, (inside, _) in whiles.items() if inside in bodies]
     assert text.count('custom_call_target="tpu_custom_call"') >= len(SFS)
     assert max(sizes) <= STEP_CEILING
+    comps = computations(text)
+    stages = []
+    for body in bodies:
+        inside = [line for c in called_from(comps, body) for line in comps[c]]
+        # neither in the body nor inside one of its fusions (the parent's DFT
+        # matrices were fusions without operands INSIDE their convolution's fusion)
+        assert not [line for line in inside if re.search(r"\sfusion\(\), kind=", line)]
+        # the step's own two exp(): its lanes' rotation and the turn by tau, each an
+        # exponential, a cosine and a sine (the parent's bodies held 23 and 40)
+        assert sum(len(re.findall(r"\s(?:cosine|sine|exponential)\(", line))
+                   for line in inside) <= 6
+        stages.append(sum(" convolution(" in line for line in inside))
+    # SF7's 256 points direct, SF8's 512 and SF9's 1024 four-step: a convolution a stage
+    assert sorted(stages) == [1, 2, 2]
+    assert [mxu_fft.form(2 << sf, N_CH) for sf in SFS] == \
+        ["planes_direct", "planes_four_step", "planes_four_step"]
+
+
+def test_dfts_through_the_matmul_forms_give_the_default_runs_records(small, monkeypatch):
+    """The capture of ``test_blocks_equal_reference...`` with every DFT forced
+    through ``ops/mxu_fft``'s matmul forms on the CPU (the few-row forms in the
+    scan steps, the many-row ones in ``detect`` and the bank): the record blocks
+    hold the packets of the default run (``jnp.fft``), packet for packet, and the
+    float fields agree within the limits the reference is held to."""
+    import jax
+
+    from futuresdr_tpu.ops import mxu_fft
+    sent = train(21, 12, duty=0.4)
+    x = air(sent, 12, 121)
+    default = run_frames(small, x)
+    monkeypatch.setattr(mxu_fft, "_impl", "mxu")
+    stage = lora_gw_stages(**SMALL)[0]
+    forced = run_frames((stage, jax.jit(stage.fn)), x)
+    n = 0
+    for a, b in zip(forced, default):
+        (head_a, mine_a), (head_b, mine_b) = parse_records(a), parse_records(b)
+        assert head_a == head_b
+        same_records(mine_a, mine_b)
+        n += len(mine_a)
+    assert n == len(sent) >= 8
+    same_as_reference(forced, x)
 
 
 def test_record_layout_is_the_references():
