@@ -36,6 +36,7 @@ from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
+from harness import costs
 from harness import refs_lora as R
 
 _STATE = {}                     # the capture this process made, and its decode
@@ -301,11 +302,31 @@ def needed_flops(n_channels: int, sfs, frame: int, symbols: dict) -> float:
     return float(ops)
 
 
+def kernel_costs(s: dict, frame: int) -> dict:
+    """What the program's two Pallas kernels need per frame, from the static
+    shapes (``harness/costs.py``), under the names the per-layer metrics ask
+    for (``cost`` in ``layer_metrics/pallas.*.json``):
+
+    * ``pfb``: the bank, one call a frame: ``frame / n_channels`` rows of the
+      channelizer's taps (``taps_per_branch`` a branch) and an 8-point DFT;
+    * ``window_fetch``: per SF one window of ``OS * 2^SF`` samples a channel
+      in every step of the SF's scan, which runs ``ceil(L / S) + 6`` steps
+      over the ``L = frame * 5 / (4 * n_channels)`` samples a channel that
+      the front end yields a frame."""
+    n = s["n_channels"]
+    k = -(-len(R.channelizer_taps(n)) // n)
+    per_channel = frame * 5 // (4 * n)
+    fetch = [costs.window_fetch_cost(n, R.OS << sf, -(-per_channel // (R.OS << sf)) + 6)
+             for sf in s["sfs"]]
+    return {"pfb": costs.pfb_cost(frame // n, n, k),
+            "window_fetch": {key: sum(c[key] for c in fetch) for key in ("flops", "bytes")}}
+
+
 def frame_cost(cfg: dict, frame: int, wire: str) -> dict:
     """Per frame: the operations above with the mix's mean of aligned symbols
     (the schedule of seed 0 stands for every seed: the draws are the same law)
     and the bytes that must cross HBM: the wire's samples in, the record block
-    out."""
+    out; under ``kernels`` what each Pallas kernel needs (``kernel_costs``)."""
     s = _sizes(cfg, frame)
     n_frames = 64
     symbols = {}
@@ -313,4 +334,5 @@ def frame_cost(cfg: dict, frame: int, wire: str) -> dict:
         de = R.ldro(sf, s["ldro_from_sf"])
         symbols[sf] = symbols.get(sf, 0) + (R.n_data_symbols(sf, length, de) + 12) / n_frames
     return {"flops": needed_flops(s["n_channels"], s["sfs"], frame, symbols),
-            "bytes": float(frame * cfg["wire_bytes"][wire] + frame // 8 * 4)}
+            "bytes": float(frame * cfg["wire_bytes"][wire] + frame // 8 * 4),
+            "kernels": kernel_costs(s, frame)}

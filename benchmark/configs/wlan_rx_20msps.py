@@ -27,9 +27,13 @@ import math
 
 import numpy as np
 
+from harness import costs
 from harness import refs_wlan as W
 
 _STAGE_KEYS = ("carry_len", "max_psdu", "cand_slots", "lanes")
+#: operations a trellis step needs: 64 states x 2 candidates x (2 adds + 1
+#: compare), and one traceback step (2)
+TRELLIS_OPS = 64 * 2 * 3 + 2
 
 
 def _sizes(cfg: dict, frame: int) -> dict:
@@ -188,10 +192,10 @@ def needed_flops(n_samples: int, packets: list) -> float:
     for rate, length in packets:
         n_sym = W.n_symbols(rate, length)
         steps = 16 + 8 * length + 6
-        ops += 481 * 64 * 8 + 24 * 386
+        ops += 481 * 64 * 8 + 24 * TRELLIS_OPS
         ops += (n_sym + 3) * (6 * 64 + 5 * 64 * 6 + 52 * 17)
         ops += n_sym * 48 * W.RATES[rate][2] * 6
-        ops += steps * 386
+        ops += steps * TRELLIS_OPS
     return ops
 
 
@@ -199,8 +203,13 @@ def frame_cost(cfg: dict, frame: int, wire: str) -> dict:
     """Per frame: the operations the mix's packets need (the schedule of seed
     0 over 16 frames stands for every seed: the draws are the same law) and
     the bytes that must cross HBM: the wire's samples in, the record block
-    out."""
+    out; under ``kernels`` what the trellis's two Pallas kernels need
+    (``viterbi``: the data steps the packets have, SERVICE and tail
+    included, at ``TRELLIS_OPS`` a step; SIGNAL's 24 steps are decoded
+    elsewhere)."""
     sched = schedule(cfg, 0, 16, frame)
     flops = needed_flops(16 * frame, [(s[1], s[2]) for s in sched]) / 16
+    steps = sum(16 + 8 * s[2] + 6 for s in sched) / 16
     return {"flops": float(flops),
-            "bytes": float(frame * cfg["wire_bytes"][wire] + frame // 8 * 4)}
+            "bytes": float(frame * cfg["wire_bytes"][wire] + frame // 8 * 4),
+            "kernels": {"viterbi": costs.trellis_cost(steps, TRELLIS_OPS)}}

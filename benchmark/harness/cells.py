@@ -75,6 +75,14 @@ def _applies(entry: dict, cell: str) -> bool:
     return "workloads" not in entry or cell in entry["workloads"]
 
 
+def unread(manifest: dict, workload: str, metrics) -> List[str]:
+    """The per-layer metrics whose ``workloads`` name this cell and that read
+    nothing in its traced run: a kernel renamed in the program, or a counter
+    gone, shows here instead of leaving its metric quietly out of the line."""
+    return sorted(m["name"] for m in manifest["per_layer"]
+                  if workload in m.get("workloads", ()) and m["name"] not in metrics)
+
+
 def resolve(workload: str, bench_dir: Path = BENCH_DIR,
             manifest: Optional[Path] = None) -> Cell:
     bench_dir = Path(bench_dir)

@@ -88,6 +88,34 @@ def fm_front_end_frame_cost(frame: int, tuner_taps: int, decim: int,
             "bytes": float(frame * 8 + (n_ch * interp // rs_decim) * 4)}
 
 
+def pfb_cost(rows: int, n_channels: int, taps_per_branch: int) -> Dict[str, float]:
+    """One call of a critically sampled PFB analysis bank that yields ``rows``
+    rows of ``n_channels`` outputs: per row a complex x real MAC for every tap
+    of every branch and an FFT across the branches. Bytes: its rows in once
+    (the ``taps_per_branch - 1`` rows of history with them) and out once,
+    complex as two float32 planes, and its real float32 taps once."""
+    n, k = n_channels, taps_per_branch
+    flops = fir_flops(rows * n, k) + fft_flops(n, rows)
+    return {"flops": float(flops),
+            "bytes": float(8 * n * (rows + k - 1) + 8 * n * rows + 4 * n * k)}
+
+
+def window_fetch_cost(lanes: int, window: int, steps: int) -> Dict[str, float]:
+    """``steps`` calls of a fetch that copies one window of ``window`` complex
+    samples out of each of ``lanes`` lanes: no operation; each window's
+    samples read once and written once, as two float32 planes."""
+    return {"flops": 0.0, "bytes": float(steps * lanes * window * 8 * 2)}
+
+
+def trellis_cost(steps: float, ops_per_step: int) -> Dict[str, float]:
+    """A Viterbi trellis over ``steps`` steps of a rate-1/2 mother code, at
+    ``ops_per_step`` (add-compare-select of every state and one traceback
+    step). Bytes: the step's two float32 LLRs in and its decision out, one
+    byte."""
+    return {"flops": float(steps * ops_per_step),
+            "bytes": float(steps * (2 * 4 + 1))}
+
+
 def roofline(cost: Dict[str, float], peaks: dict) -> Dict[str, object]:
     """The least time the chip could take for ``cost`` and which peak sets
     it: the larger of operations over peak FLOP/s and bytes over peak B/s."""

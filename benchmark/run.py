@@ -174,6 +174,12 @@ def main(argv=None) -> int:
             if red.busy_ns <= 0.0:
                 correct = False
                 out.notes["trace"] = "no operation ran on the device"
+            missing = cells.unread(manifest, cell.name, metrics)
+            if missing:
+                out.notes["unread"] = missing
+                print(f"benchmark/run.py: {cell.name} lists per-layer metrics "
+                      f"that read nothing in this trace: {', '.join(missing)}",
+                      file=sys.stderr)
         else:
             metrics = {}
             values = dict(out.end_to_end)
@@ -196,6 +202,8 @@ def main(argv=None) -> int:
                               rehearse=rehearse)
         if override:
             line["override"] = override
+        if out.notes.get("unread"):
+            line["unread"] = out.notes["unread"]
         sys.stdout.flush()
         print(lastline.dumps(line), flush=True)
         return 0

@@ -1,7 +1,8 @@
 """The README's worked example, executed: a configuration, a traffic mix and a
 per-layer metric are added as NEW files plus one BENCHMARK.json entry each, in
 a throw-away copy of the benchmark, and the new cell runs (CPU rehearsal) with
-both kinds of run. No file that existed is edited."""
+both kinds of run. No file that existed is edited, and the copy's own suite
+stays green: no test counts the benchmark's files."""
 
 import hashlib
 import json
@@ -28,7 +29,7 @@ def _digest(root: Path) -> dict:
 def overlay(tmp_path_factory) -> Path:
     tmp = tmp_path_factory.mktemp("checkout")
     shutil.copytree(BENCH, tmp / "benchmark", ignore=shutil.ignore_patterns(
-        "__pycache__", "tests", "recorded", ".pytest_cache"))
+        "__pycache__", ".pytest_cache"))
     before = _digest(tmp / "benchmark")
     # the program itself is not copied: the throw-away checkout links to it
     for part in ("futuresdr_tpu", "native"):
@@ -96,3 +97,13 @@ def test_new_metric_is_read_in_the_traced_run(overlay):
     assert line["metrics"]["sink.frames_per_s"]["value"] > 0
     assert "gen.late_p95_ms" in line["metrics"]
     assert "breakdown" in line and "busy_s" in line["device"]
+
+
+def test_the_copys_own_suite_stays_green(overlay):
+    tests = overlay / "benchmark" / "tests"
+    p = subprocess.run(
+        [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
+         str(tests), "--ignore", str(tests / Path(__file__).name)],
+        cwd=overlay, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        capture_output=True, text=True, timeout=900)
+    assert p.returncode == 0 and " passed" in p.stdout, p.stdout[-3000:]

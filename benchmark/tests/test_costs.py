@@ -2,7 +2,7 @@
 
 import pytest
 
-from harness import costs, peaks, refs
+from harness import costs, peaks, refs, refs_lora
 
 
 def test_spectrum_frame_at_262144():
@@ -53,3 +53,58 @@ def test_unknown_device_kind_raises():
         peaks.peaks_for("_source")
     with pytest.raises(peaks.UnknownDevice):
         peaks.peaks_for("cpu")
+
+
+def test_kernel_costs_by_hand():
+    # a bank of 4 branches, 3 taps each, 10 rows out: per row 4 x 3 complex x
+    # real MACs (4 ops) and a 4-point FFT (5 * 4 * 2)
+    c = costs.pfb_cost(10, 4, 3)
+    assert c["flops"] == 10 * (4 * 3 * 4 + 40) == 880
+    # rows in with the 2 rows of history, rows out, two float32 planes; taps
+    assert c["bytes"] == (12 * 4 + 10 * 4) * 8 + 4 * 3 * 4 == 752
+    # 7 fetches of a 256-sample window from each of 2 lanes: read + write
+    c = costs.window_fetch_cost(2, 256, 7)
+    assert c == {"flops": 0.0, "bytes": 7 * 2 * 256 * 2 * 4 * 2}
+    # 100 trellis steps at 386 ops; two float32 LLRs in, one byte out
+    assert costs.trellis_cost(100, 386) == {"flops": 38600.0, "bytes": 900.0}
+
+
+def _frame_cost(name, frame):
+    from harness import cells
+
+    cfg = cells.load_json(cells.BENCH_DIR / "configs" / f"{name}.json")
+    mod = cells.load_module(cells.BENCH_DIR / "configs" / f"{name}.py")
+    return mod, cfg, mod.frame_cost(cfg, frame, "sc16")
+
+
+@pytest.mark.parametrize("name,frame,flops,nbytes", [
+    # what frame_cost returned before it gained its "kernels" key: the
+    # numerator of program_roofline may not move
+    ("lora_gw_eu868", 262144, 289511356.0, 1179648.0),
+    ("lora_gw_eu868", 16384, 9462236.0, 73728.0),
+    ("wlan_rx_20msps", 262144, 78622077.75, 1179648.0),
+    ("wlan_rx_20msps", 16384, 4046633.5, 73728.0),
+])
+def test_frame_cost_top_level_as_before(name, frame, flops, nbytes):
+    _, _, c = _frame_cost(name, frame)
+    assert c["flops"] == flops and c["bytes"] == nbytes
+
+
+def test_gateway_kernel_costs_by_hand():
+    _, _, c = _frame_cost("lora_gw_eu868", 16384)
+    # the rehearsal's gateway: 4 channels, 12 taps a branch, 4096 rows a frame
+    assert len(refs_lora.channelizer_taps(4)) == 48
+    assert c["kernels"]["pfb"] == costs.pfb_cost(4096, 4, 12)
+    # 5120 samples a channel a frame; SF7-9: windows of 256, 512, 1024 in
+    # scans of 20 + 6, 10 + 6 and 5 + 6 steps, 4 lanes
+    assert 16384 * 5 // 16 == 5120
+    want = sum(steps * 4 * w * 16 for w, steps in ((256, 26), (512, 16), (1024, 11)))
+    assert c["kernels"]["window_fetch"] == {"flops": 0.0, "bytes": float(want)}
+
+
+def test_trellis_cost_follows_the_packets():
+    mod, cfg, c = _frame_cost("wlan_rx_20msps", 16384)
+    sched = mod.schedule(cfg, 0, 16, 16384)
+    steps = sum(16 + 8 * s[2] + 6 for s in sched) / 16
+    assert mod.TRELLIS_OPS == 386
+    assert c["kernels"]["viterbi"] == costs.trellis_cost(steps, 386)
